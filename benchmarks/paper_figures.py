@@ -101,35 +101,50 @@ def fig6_scale(n_tokens=80_000):
     return out
 
 
+def resources_cell(n_tokens: int, n_dev: int) -> tuple[float, int]:
+    """(wall seconds, n-grams) of the distributed suffix-sigma job over this
+    process's first ``n_dev`` devices, compile excluded."""
+    import jax
+    toks = corpus_mod.zipf_corpus(n_tokens, corpus_mod.NYT, seed=0)
+    mesh = (jax.make_mesh((n_dev,), ("data",),
+                          axis_types=(jax.sharding.AxisType.Auto,),
+                          devices=jax.devices()[:n_dev])
+            if n_dev > 1 else None)
+    cfg = NGramConfig(sigma=5, tau=8, vocab_size=corpus_mod.NYT.vocab_size)
+    run_job(toks, cfg, mesh=mesh)               # warmup incl. compile
+    t0 = time.perf_counter()
+    st = run_job(toks, cfg, mesh=mesh)
+    return time.perf_counter() - t0, len(st)
+
+
 def fig7_resources(n_tokens=50_000):
-    """Computational-resource scaling (Fig. 7): run the REAL distributed job in
-    subprocesses with 1/2/4/8 XLA host devices.  Like the paper's fixed-size
-    cluster with varying slot counts, all workers share one physical machine, so
-    the same diminishing-returns contention the paper reports (SSVII-H) appears."""
-    import subprocess, sys, textwrap, os
+    """Computational-resource scaling (Fig. 7): the REAL distributed job on
+    1/2/4/8 devices.  On the CPU each count runs in a subprocess with that
+    many emulated host devices -- like the paper's fixed-size cluster with
+    varying slot counts, all workers share one physical machine, so the same
+    diminishing-returns contention the paper reports (SSVII-H) appears.  On an
+    accelerator every count runs in this process over the devices it holds
+    (a chip belongs to one process), up to as many as it has, and a failure
+    raises."""
+    import jax
+    if jax.default_backend() != "cpu":
+        counts = [n for n in (1, 2, 4, 8) if n <= jax.device_count()]
+        return [dict(zip(("wall_s", "ngrams"), resources_cell(n_tokens, n)),
+                     method="suffix_sigma", R=n) for n in counts]
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = []
     for n_dev in (1, 2, 4, 8):
-        code = textwrap.dedent(f"""
-            import time, numpy as np, jax
-            from repro.core import run_job
-            from repro.core.stats import NGramConfig
-            from repro.data import corpus as corpus_mod
-            toks = corpus_mod.zipf_corpus({n_tokens}, corpus_mod.NYT, seed=0)
-            mesh = (jax.make_mesh(({n_dev},), ("data",),
-                    axis_types=(jax.sharding.AxisType.Auto,))
-                    if {n_dev} > 1 else None)
-            cfg = NGramConfig(sigma=5, tau=8,
-                              vocab_size=corpus_mod.NYT.vocab_size)
-            st = run_job(toks, cfg, mesh=mesh)   # warmup incl. compile
-            t0 = time.perf_counter()
-            st = run_job(toks, cfg, mesh=mesh)
-            print("RESULT", time.perf_counter() - t0, len(st))
-        """)
+        code = ("from benchmarks.paper_figures import resources_cell\n"
+                f"print('RESULT', *resources_cell({n_tokens}, {n_dev}))")
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
-        env["PYTHONPATH"] = "src"
+        env["JAX_PLATFORMS"] = "cpu"
+        env["PYTHONPATH"] = os.pathsep.join([root, os.path.join(root, "src")])
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, cwd="/root/repo", env=env, timeout=560)
+                           text=True, cwd=root, env=env, timeout=560)
         line = [l for l in r.stdout.splitlines() if l.startswith("RESULT")]
         if not line:
             out.append({"method": "suffix_sigma", "R": n_dev,

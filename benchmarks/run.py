@@ -62,6 +62,8 @@ def main() -> None:
                          "x the monolithic median OR was skipped (--waves "
                          "only; ratio is stamped into BENCH_waves.json)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n = 20_000 if args.quick else 60_000
 
     if args.waves:
@@ -81,8 +83,10 @@ def main() -> None:
                   f"(limit {args.gate:.2f}x) -> {'OK' if ok else 'FAIL'}")
             failed |= not ok
         if args.gate_mesh is not None:
-            name = f"waves_mesh{waves.MESH_DEVICES}_{waves.MESH_DEVICES}"
-            row = by_name.get(name)
+            row = next((r for r in rows if r["name"].startswith("waves_mesh")),
+                       None)
+            name = row["name"] if row else waves.mesh_row_name(
+                waves.MESH_DEVICES)
             if row is None or "skipped" in row:
                 why = row["skipped"] if row else "row missing"
                 print(f"# mesh perf gate: {name} SKIPPED ({why}) -> FAIL")

@@ -13,9 +13,11 @@ wave-count sweep:
     ``tiered`` = the LSM rung stack), recording wall time *and* the measured
     merge work (``fold_rows``: segment rows fed through ``merge_segments``);
   * **streaming cell** -- waves straight into the generational index;
-  * **distributed cell** -- the same job with every wave sharded over an
-    8-way host mesh, run in a subprocess (the device-count XLA flag must
-    precede backend init).
+  * **distributed cell** -- the same job with every wave sharded over a
+    mesh: on the CPU an 8-way emulated host mesh, run in a subprocess (the
+    device-count XLA flag must precede backend init); on an accelerator, in
+    this process, over every device it holds (a chip belongs to one
+    process, so no child could reach it).
 
 Every run appends to ``BENCH_waves.json`` so regressions are diffable in
 review.
@@ -33,30 +35,44 @@ import numpy as np
 BENCH_JSON = "BENCH_waves.json"
 WAVE_COUNTS = (1, 2, 4, 8)
 ACC_WAVES = 16          # >= 16 waves: where the tiered fold-work win shows
-MESH_DEVICES = 8
+MESH_DEVICES = 8        # emulated host devices of the CPU mesh cell
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def mesh_cell(n_tokens: int, n_devices: int, reps: int) -> dict:
+    """Median wall time of the mesh-wave job over this process's first
+    ``n_devices`` devices, one wave per device."""
+    import jax
+    from repro.core import NGramConfig
+    from repro.data import corpus as corpus_mod
+    from repro.pipeline import WaveExecutor
+    mesh = jax.make_mesh((n_devices,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:n_devices])
+    prof = corpus_mod.NYT
+    tokens = corpus_mod.zipf_corpus(n_tokens, prof, seed=0,
+                                    duplicate_frac=0.02)
+    cfg = NGramConfig(sigma=5, tau=4, vocab_size=prof.vocab_size)
+    wave = -(-len(tokens) // n_devices)
+    ex = WaveExecutor(cfg, wave_tokens=wave, mesh=mesh)
+    ex.run(tokens)                               # compile + cache warm
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        ex.run(tokens)
+        ts.append(time.perf_counter() - t0)
+    return {"us": float(np.median(ts) * 1e6), "n_tokens": len(tokens),
+            "devices": n_devices}
+
 
 _MESH_CELL = """
-import json, time
-import numpy as np, jax
-from repro.core import NGramConfig
-from repro.data import corpus as corpus_mod
-from repro.pipeline import WaveExecutor
-mesh = jax.make_mesh(({devices},), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
-prof = corpus_mod.NYT
-tokens = corpus_mod.zipf_corpus({n_tokens}, prof, seed=0, duplicate_frac=0.02)
-cfg = NGramConfig(sigma=5, tau=4, vocab_size=prof.vocab_size)
-wave = -(-len(tokens) // {n_waves})
-ex = WaveExecutor(cfg, wave_tokens=wave, mesh=mesh)
-ex.run(tokens)                                   # compile + cache warm
-ts = []
-for _ in range({reps}):
-    t0 = time.perf_counter(); ex.run(tokens); ts.append(time.perf_counter() - t0)
-print(json.dumps({{"us": float(np.median(ts) * 1e6), "n_tokens": len(tokens)}}))
+import json
+from benchmarks.waves import mesh_cell
+print(json.dumps(mesh_cell({n_tokens}, {devices}, {reps})))
 """
 
 
-def _mesh_cell(n_tokens: int, reps: int) -> dict:
+def _mesh_cell_emulated(n_tokens: int, reps: int) -> dict:
     """Time distributed waves in a subprocess (forced host device count).
 
     Never silently drops the cell: any failure comes back as
@@ -66,13 +82,15 @@ def _mesh_cell(n_tokens: int, reps: int) -> dict:
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={MESH_DEVICES}"
-    env["PYTHONPATH"] = "src" + (os.pathsep + env["PYTHONPATH"]
-                                 if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [ROOT, os.path.join(ROOT, "src")]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     code = _MESH_CELL.format(devices=MESH_DEVICES, n_tokens=n_tokens,
-                             n_waves=MESH_DEVICES, reps=reps)
+                             reps=reps)
     try:
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, timeout=1200, env=env)
+                           text=True, timeout=1200, env=env, cwd=ROOT)
     except subprocess.TimeoutExpired:
         print("mesh wave cell timed out (skipped)", file=sys.stderr)
         return {"skipped": "subprocess timeout (1200s)"}
@@ -82,6 +100,10 @@ def _mesh_cell(n_tokens: int, reps: int) -> dict:
         tail = (r.stderr.strip().splitlines() or ["no stderr"])[-1]
         return {"skipped": f"subprocess exit {r.returncode}: {tail[:300]}"}
     return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def mesh_row_name(n_devices: int) -> str:
+    return f"waves_mesh{n_devices}_{n_devices}"
 
 
 def run(n_tokens: int = 60_000, *, reps: int = 3, mesh: bool = True,
@@ -159,12 +181,24 @@ def run(n_tokens: int = 60_000, *, reps: int = 3, mesh: bool = True,
                  "derived": (f"tok_s={n_tokens / (us / 1e6):.0f};"
                              f"segments={gen.n_segments}")})
 
-    # distributed cell: every wave sharded over the host mesh (subprocess).
-    # A skipped/failed cell still lands as an explicit row -- the record
-    # must say WHY the mesh number is missing, never just omit it.
-    mesh_name = f"waves_mesh{MESH_DEVICES}_{MESH_DEVICES}"
-    mesh_row = _mesh_cell(n_tokens, max(reps - 1, 1)) if mesh \
-        else {"skipped": "disabled (--no-mesh)"}
+    # distributed cell: every wave sharded over a mesh.  On the CPU it runs
+    # on an emulated host mesh in a subprocess, and a skipped/failed cell
+    # still lands as an explicit row -- the record must say WHY the mesh
+    # number is missing, never just omit it.  On an accelerator it runs in
+    # this process over the devices it holds, and a failure raises.
+    import jax
+    emulated = jax.default_backend() == "cpu"
+    n_dev = MESH_DEVICES if emulated else jax.device_count()
+    mesh_name = mesh_row_name(n_dev)
+    if not mesh:
+        mesh_row = {"skipped": "disabled (--no-mesh)"}
+    elif emulated:
+        mesh_row = _mesh_cell_emulated(n_tokens, max(reps - 1, 1))
+    elif n_dev < 2:
+        raise SystemExit(f"the mesh wave cell needs 2 or more devices, this "
+                         f"process holds {n_dev}; pass --no-mesh")
+    else:
+        mesh_row = mesh_cell(n_tokens, n_dev, max(reps - 1, 1))
     gate_mesh_stamp = None
     if "skipped" in mesh_row:
         rows.append({"name": mesh_name, "us": 0.0,

@@ -1,9 +1,6 @@
 """Computing n-Gram Statistics in MapReduce -- jax/pallas reproduction.
 
-Importing the package installs small compatibility shims for older jax
-releases (see ``repro._compat``) so every subpackage can target the modern
-``jax.shard_map`` / ``AxisType`` API unconditionally.
+Importing the package, or any of its subpackages, starts no JAX backend: a
+process picks its platform (and, on the CPU, its device count) before the
+first array is made.
 """
-from . import _compat
-
-_compat.install()

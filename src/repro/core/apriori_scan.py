@@ -93,18 +93,20 @@ def plan(cfg: NGramConfig) -> plan_mod.JobPlan:
 
 
 def run(tokens, cfg: NGramConfig, mesh=None, axis_name: str = "data") -> NGramStats:
-    tokens = jnp.asarray(tokens, jnp.int32)
     if mesh is not None and mesh.size > 1:
-        return _run_distributed(tokens, cfg, mesh, axis_name)
+        return _run_distributed(np.asarray(tokens, np.int32), cfg, mesh,
+                                axis_name)
     from repro.pipeline.executor import run_plan
-    return run_plan(tokens, cfg, plan=plan(cfg))
+    return run_plan(jnp.asarray(tokens, jnp.int32), cfg, plan=plan(cfg))
 
 
 def _run_distributed(tokens, cfg: NGramConfig, mesh, axis_name) -> NGramStats:
     n_parts = mesh.shape[axis_name]
     n = tokens.shape[0]
     n_local = -(-n // n_parts)
-    tokens_p = jnp.pad(tokens, (0, n_local * n_parts - n)).reshape(n_parts, n_local)
+    tokens_p = shf.shard_rows(
+        np.pad(tokens, (0, n_local * n_parts - n)).reshape(n_parts, n_local),
+        mesh, axis_name)
     n_l = packing.n_lanes(cfg.sigma, cfg.vocab_size)
     rec_width = packing.record_bytes(cfg.sigma, cfg.vocab_size)
 

@@ -89,15 +89,17 @@ def _distributed(tokens_p, cfg: NGramConfig, mesh, axis_name, capacity):
 
 
 def run(tokens, cfg: NGramConfig, mesh=None, axis_name: str = "data") -> NGramStats:
-    tokens = jnp.asarray(tokens, jnp.int32)
     if mesh is None or mesh.size == 1:
         from repro.pipeline.executor import run_plan
-        return run_plan(tokens, cfg, plan=plan(cfg))
+        return run_plan(jnp.asarray(tokens, jnp.int32), cfg, plan=plan(cfg))
 
+    tokens = np.asarray(tokens, np.int32)
     n_parts = mesh.shape[axis_name]
     n = tokens.shape[0]
     n_local = -(-n // n_parts)
-    tokens_p = jnp.pad(tokens, (0, n_local * n_parts - n)).reshape(n_parts, n_local)
+    tokens_p = shf.shard_rows(
+        np.pad(tokens, (0, n_local * n_parts - n)).reshape(n_parts, n_local),
+        mesh, axis_name)
     capacity = max(8, int(cfg.capacity_factor * n_local * cfg.sigma / n_parts) + 1)
     for attempt in range(6):
         terms, flags, counts, stats = _distributed(tokens_p, cfg, mesh, axis_name,

@@ -43,8 +43,9 @@ def suffix_windows(tokens: jax.Array, sigma: int) -> tuple[jax.Array, jax.Array]
     """
     n = tokens.shape[0]
     padded = jnp.concatenate([tokens, jnp.zeros((sigma,), tokens.dtype)])
-    idx = jnp.arange(n)[:, None] + jnp.arange(sigma)[None, :]
-    w = padded[idx]
+    # sigma static shifted slices, not an [N, sigma] index gather: on a TPU
+    # the gather's temporaries were ~12x the whole fused wave program's rest
+    w = jnp.stack([padded[j:j + n] for j in range(sigma)], axis=1)
     keep = jnp.cumprod((w != 0).astype(jnp.int32), axis=1)
     return (w * keep).astype(jnp.int32), tokens != 0
 
@@ -256,18 +257,24 @@ def sigma_split(tokens, cfg: NGramConfig, sigma_head: int = 16,
 def run(tokens, cfg: NGramConfig, mesh=None, axis_name: str = "data",
         bucket_ids=None) -> NGramStats:
     """Run a SUFFIX-sigma job.  ``tokens``: 1-D int32, PAD(0)-separated documents."""
-    tokens = jnp.asarray(tokens, jnp.int32)
-    bkt = None if bucket_ids is None else jnp.asarray(bucket_ids, jnp.uint32)
     if mesh is None or mesh.size == 1:
         from repro.pipeline.executor import run_plan
-        return run_plan(tokens, cfg, bucket_ids=bkt, plan=plan(cfg))
+        bkt = None if bucket_ids is None else jnp.asarray(bucket_ids,
+                                                          jnp.uint32)
+        return run_plan(jnp.asarray(tokens, jnp.int32), cfg, bucket_ids=bkt,
+                        plan=plan(cfg))
 
+    # host arrays, placed shard by shard on the mesh
+    tokens = np.asarray(tokens, np.int32)
+    bkt = None if bucket_ids is None else np.asarray(bucket_ids, np.uint32)
     n_parts = mesh.shape[axis_name]
     n = tokens.shape[0]
     n_local = -(-n // n_parts)
     pad = n_local * n_parts - n
-    tokens_p = jnp.pad(tokens, (0, pad)).reshape(n_parts, n_local)
-    bkt_p = (jnp.pad(bkt, (0, pad)).reshape(n_parts, n_local)
+    tokens_p = shuffle.shard_rows(
+        np.pad(tokens, (0, pad)).reshape(n_parts, n_local), mesh, axis_name)
+    bkt_p = (shuffle.shard_rows(np.pad(bkt, (0, pad)).reshape(n_parts, n_local),
+                                mesh, axis_name)
              if bkt is not None else None)
 
     capacity = max(8, int(cfg.capacity_factor * n_local / n_parts) + 1)

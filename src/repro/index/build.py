@@ -6,10 +6,11 @@ the post-job win is a *sorted, compressed, immutable* layout, the build is split
 two along the line the generational (LSM-style) index composes over:
 
   * :func:`segment_from_stats` packs the rows into the shuffle/sort phases' own
-    packed-lane record format (``mapreduce.pack``) and sorts them with the same
-    multi-key lexicographic sort (``mapreduce.sort``) into an
-    :class:`IndexSegment` -- the sorted immutable run of (length | lanes, cf)
-    rows that is the unit of merge (``index/merge.py``);
+    packed-lane record format (``mapreduce.pack``) and sorts them in the same
+    lexicographic order into an :class:`IndexSegment` -- the sorted immutable
+    run of (length | lanes, cf) rows that is the unit of merge
+    (``index/merge.py``).  Builds pack and sort on the host: their row counts
+    differ from build to build, and a device sort compiles anew for each;
   * :func:`index_from_segment` derives the acceleration structures from any
     sorted segment, whether it came from a job or from a k-way merge of older
     segments:
@@ -42,7 +43,6 @@ import numpy as np
 
 from repro.kernels.bsearch import search_steps  # re-export: queries need it
 from repro.mapreduce import pack as packing
-from repro.mapreduce import sort
 from repro.core.stats import NGramStats
 from ._layout import (MAX_FANOUT, SENTINEL, fanout_layout, pad_rows,
                       round_capacity, row_bytes_view, row_offsets)
@@ -175,14 +175,13 @@ def segment_from_stats(stats: NGramStats, *, vocab_size: int,
     if size < r + 1:
         raise ValueError(f"pad_to={size} < n_rows+1={r + 1}")
 
-    lanes = packing.pack_terms(jnp.asarray(grams), vocab_size=vocab_size)
-    keys = jnp.concatenate([jnp.asarray(lengths, jnp.uint32)[:, None], lanes],
-                           axis=1)
-    keys_s, (counts_s,) = sort.sort_with_payload(keys, [jnp.asarray(counts)])
+    # host pack + sort: a device sort would compile anew per row count
+    lanes = packing.pack_terms_np(grams, vocab_size=vocab_size)
+    keys = np.concatenate([lengths.astype(np.uint32)[:, None], lanes], axis=1)
+    order = np.argsort(row_bytes_view(keys), kind="stable")
     return IndexSegment(
-        keys=jnp.asarray(pad_rows(np.asarray(keys_s, np.uint32), size,
-                                  SENTINEL)),
-        counts=jnp.asarray(pad_rows(np.asarray(counts_s, np.uint32), size, 0)),
+        keys=jnp.asarray(pad_rows(keys[order], size, SENTINEL)),
+        counts=jnp.asarray(pad_rows(counts[order], size, 0)),
         sigma=sigma, vocab_size=vocab_size)
 
 
@@ -241,9 +240,8 @@ def index_from_segment(seg: IndexSegment, *,
     if size < r + 1:
         raise ValueError(f"pad_to={size} < n_rows+1={r + 1}")
 
-    grams = np.asarray(packing.unpack_terms(
-        jnp.asarray(lanes_s), vocab_size=vocab_size, sigma=sigma)) \
-        if r else np.zeros((0, sigma), np.int32)
+    grams = packing.unpack_terms_np(lanes_s, vocab_size=vocab_size,
+                                    sigma=sigma)
     lead_s = grams[:, 0].astype(np.uint32)
     # combined (length, bucket) key is monotone: length is the primary sort key
     # and the lead term sits in lane 0's most-significant bits
@@ -259,19 +257,17 @@ def index_from_segment(seg: IndexSegment, *,
     # the view depends only on the row *set* -- merge parity leans on this
     lengths = len_s.astype(np.int32)
     prefix = grams * (np.arange(sigma)[None, :] < (lengths - 1)[:, None])
-    p_lanes = packing.pack_terms(jnp.asarray(prefix), vocab_size=vocab_size)
+    p_lanes = packing.pack_terms_np(prefix, vocab_size=vocab_size)
     last = grams[np.arange(r), np.maximum(lengths - 1, 0)].astype(np.uint32) \
         if r else np.zeros((0,), np.uint32)
     p_lead = prefix[:, 0].astype(np.uint32)
-    ckeys = jnp.concatenate([jnp.asarray(lengths, jnp.uint32)[:, None],
-                             p_lanes,
-                             (~jnp.asarray(counts_s)).astype(jnp.uint32)[:, None],
-                             jnp.asarray(last)[:, None]],
-                            axis=1)
+    ckeys = np.concatenate([lengths.astype(np.uint32)[:, None], p_lanes,
+                            (~counts_s.astype(np.uint32))[:, None],
+                            last[:, None]], axis=1)
     n_l = seg.n_lanes
-    ckeys_s, (c_counts_s, c_lead_s) = sort.sort_with_payload(
-        ckeys, [jnp.asarray(counts_s), jnp.asarray(p_lead)])
-    ckeys_s = np.asarray(ckeys_s)
+    corder = np.argsort(row_bytes_view(ckeys), kind="stable")
+    ckeys_s, c_counts_s, c_lead_s = ckeys[corder], counts_s[corder], \
+        p_lead[corder]
     cp_lanes_s = ckeys_s[:, 1:1 + n_l]
     c_last_s = ckeys_s[:, 2 + n_l]
     c_combined = (ckeys_s[:, 0].astype(np.int64) * n_fanout

@@ -412,19 +412,6 @@ def _pack_head_keys(row_len: np.ndarray, terms: np.ndarray,
     return lanes
 
 
-def _unpack_terms_host(lanes: np.ndarray, *, vocab_size: int,
-                       sigma: int) -> np.ndarray:
-    """Host-side :func:`packing.unpack_terms` -- the build path stays on the
-    host end to end instead of paying two device round-trips per view."""
-    bits = packing.bits_for_vocab(vocab_size)
-    per = packing.terms_per_lane(vocab_size)
-    shifts = np.arange(per - 1, -1, -1, dtype=np.uint32) * np.uint32(bits)
-    mask = np.uint32((1 << bits) - 1) if bits < 32 else np.uint32(0xFFFFFFFF)
-    t = (lanes[..., None] >> shifts) & mask
-    t = t.reshape(t.shape[:-2] + (-1,))
-    return t[..., :sigma].astype(np.int32)
-
-
 def _lcp_host(terms: np.ndarray) -> np.ndarray:
     """lcp[i] = common prefix length of sorted rows i and i-1 (lcp[0] = 0)."""
     lcp = np.zeros(terms.shape[0], np.int32)
@@ -496,14 +483,14 @@ def compress_index(idx: NGramIndex, *, block_size: int = 4,
     cw = count_width if count_width is not None else \
         max(1, int(counts.max()).bit_length() if counts.size else 1)
 
-    terms = _unpack_terms_host(np.asarray(idx.lanes), vocab_size=vocab,
-                               sigma=sigma)
+    terms = packing.unpack_terms_np(np.asarray(idx.lanes), vocab_size=vocab,
+                                    sigma=sigma)
     heads, lcps, payload, block_base = _front_code(
         terms, row_len, len_off=0, block_size=block_size,
         term_bits=tb, lcp_width=lw, payload_words=payload_words)
 
-    c_terms = _unpack_terms_host(np.asarray(idx.cont_prefix),
-                                 vocab_size=vocab, sigma=sigma)
+    c_terms = packing.unpack_terms_np(np.asarray(idx.cont_prefix),
+                                      vocab_size=vocab, sigma=sigma)
     c_heads, c_lcps, c_payload, c_block_base = _front_code(
         c_terms, row_len, len_off=1, block_size=block_size,
         term_bits=tb, lcp_width=lw, payload_words=cont_payload_words)

@@ -430,9 +430,8 @@ def segment_to_stats(seg: IndexSegment, *,
         counts = counts[keep]
         r = int(keys.shape[0])
     lengths = keys[:, 0].astype(np.int32)
-    grams = np.asarray(packing.unpack_terms(
-        jnp.asarray(keys[:, 1:]), vocab_size=seg.vocab_size,
-        sigma=seg.sigma)) if r else np.zeros((0, seg.sigma), np.int32)
+    grams = packing.unpack_terms_np(keys[:, 1:], vocab_size=seg.vocab_size,
+                                    sigma=seg.sigma)
     return NGramStats(grams.astype(np.int32), lengths, counts)
 
 

@@ -1,5 +1,5 @@
-"""Pallas TPU kernels for the SUFFIX-sigma hot spots (validated in interpret mode
-on CPU; see each module's docstring for the VMEM tiling rationale):
+"""Pallas TPU kernels for the SUFFIX-sigma hot spots (see each module's
+docstring for the VMEM tiling rationale):
 
   lcp_boundary   -- reducer inner loop (LCP + per-length boundary flags)
   suffix_pack    -- map emit (windowed gather + bit pack, fused)
@@ -7,16 +7,10 @@ on CPU; see each module's docstring for the VMEM tiling rationale):
   hash_combine   -- sort-free map-side combiner (block-local hash slots)
   bsearch        -- index serving inner loop (batched lexicographic bounds)
   block_decode   -- compressed-index in-block decode + rank
+  block_expand   -- compressed-index batched block decode
   merge_path     -- stable two-way merge of sorted segments (LSM compaction)
-"""
-from . import ops, ref
-from .block_decode import block_decode
-from .bsearch import bsearch
-from .hash_combine import hash_combine
-from .hash_partition import hash_partition
-from .lcp_boundary import lcp_boundary
-from .merge_path import merge_path
-from .suffix_pack import suffix_pack
 
-__all__ = ["ops", "ref", "lcp_boundary", "suffix_pack", "hash_partition",
-           "hash_combine", "bsearch", "block_decode", "merge_path"]
+``ops`` holds the public wrappers (compiled on a TPU, interpreted elsewhere),
+``ref`` the pure-jnp oracles.  The package imports none of them eagerly, so
+``repro.kernels.<name>`` is always the module of that name.
+"""

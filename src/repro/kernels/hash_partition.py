@@ -5,8 +5,9 @@ per-partition record histogram in one pass.  The histogram is what sizes the
 all_to_all capacity check; fusing it with the hash avoids a second HBM pass and a
 one-hot materialization ([N, P] ints in XLA's unfused form).
 
-Each grid block writes its own histogram row; the caller sums rows (a [nb, P]
-reduction -- negligible next to the [N] pass).
+Each grid block writes its own [1, P] histogram row (the full trailing dims of
+a [nb, 1, P] output, as the TPU's (8, 128) block tiling rule requires); the
+caller sums rows (a [nb, P] reduction -- negligible next to the [N] pass).
 """
 from __future__ import annotations
 
@@ -28,8 +29,8 @@ def _make_kernel(n_parts: int):
         p = jnp.where(valid_ref[...], p, n_parts)
         part_ref[...] = p
         # iota, not arange (arange would become a captured constant -- rejected)
-        ids = jax.lax.broadcasted_iota(jnp.int32, (n_parts,), 0)
-        hist_ref[...] = jnp.sum((p[:, None] == ids[None, :]).astype(jnp.int32),
+        ids = jax.lax.broadcasted_iota(jnp.int32, (1, n_parts), 1)
+        hist_ref[...] = jnp.sum((p[:, None] == ids).astype(jnp.int32),
                                 axis=0, keepdims=True)
 
     return kernel
@@ -55,12 +56,12 @@ def hash_partition(keys: jax.Array, valid: jax.Array, *, n_parts: int,
         ],
         out_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1, n_parts), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, n_parts), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((nb, n_parts), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, n_parts), jnp.int32),
         ],
         interpret=interpret,
     )(k, v)
-    return part[:n], jnp.sum(hist, axis=0)
+    return part[:n], jnp.sum(hist, axis=(0, 1))

@@ -25,14 +25,15 @@ from jax.experimental import pallas as pl
 def _kernel(cur_ref, prev_ref, lcp_ref, flags_ref):
     cur = cur_ref[...]
     prev = prev_ref[...]
-    eq = (cur == prev).astype(jnp.int32)
-    lcp = jnp.sum(jnp.cumprod(eq, axis=1), axis=1).astype(jnp.int32)
     length = cur.shape[1]
     # iota, not arange: arange traces to a materialized constant, which
     # pallas_call rejects ("captures constants ... pass them as inputs")
-    lengths = jax.lax.broadcasted_iota(jnp.int32, (length,), 0) + 1
-    lcp_ref[...] = lcp
-    flags_ref[...] = (lcp[:, None] < lengths[None, :]) & (cur != 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, cur.shape, 1)
+    # the LCP is the first column where the rows differ (length if none);
+    # a min over columns, since cumprod has no Mosaic lowering
+    lcp = jnp.min(jnp.where(cur == prev, length, cols), axis=1)
+    lcp_ref[...] = lcp[None, :]
+    flags_ref[...] = (lcp[:, None] <= cols) & (cur != 0)
 
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -55,14 +56,16 @@ def lcp_boundary(sorted_terms: jax.Array, *, block_rows: int = 512,
             pl.BlockSpec((block_rows, length), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, length), lambda i: (i, 0)),
         ],
+        # lcp rides as one [1, block_rows] row per block: a 1-D block must
+        # match XLA's 1-D tiling (1024 on a TPU), a row need not
         out_specs=[
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((None, 1, block_rows), lambda i: (i, 0, 0)),
             pl.BlockSpec((block_rows, length), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, block_rows), jnp.int32),
             jax.ShapeDtypeStruct((n_pad, length), jnp.bool_),
         ],
         interpret=interpret,
     )(st, prev)
-    return lcp[:n], flags[:n]
+    return lcp.reshape(n_pad)[:n], flags[:n]

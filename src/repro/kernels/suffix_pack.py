@@ -10,6 +10,9 @@ window in VREGs and writes only the packed lanes (e.g. sigma=5 packed into 2 lan
 Halo handling: windows starting near the block end read into the next block, so the
 kernel gets the *next* token block as a second ref (index_map i -> i+1, with the
 caller appending one all-PAD block so the clamp at the last block is harmless).
+Both blocks are copied into one VMEM scratch row, and window j is a static
+``pl.ds`` load from it (Mosaic lowers ref slices, not ``dynamic_slice``).  Lanes
+are written as rows of a [n_lanes, block] output block and transposed outside.
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.mapreduce import pack as packing
 
@@ -27,19 +31,19 @@ def _make_kernel(sigma: int, vocab_size: int, block: int):
     per = packing.terms_per_lane(vocab_size)
     lanes = packing.n_lanes(sigma, vocab_size)
 
-    def kernel(cur_ref, nxt_ref, out_ref):
-        cur = cur_ref[...]
-        nxt = nxt_ref[...]
-        both = jnp.concatenate([cur, nxt])
+    def kernel(cur_ref, nxt_ref, out_ref, both_ref):
+        both_ref[pl.ds(0, block)] = cur_ref[...]
+        both_ref[pl.ds(block, block)] = nxt_ref[...]
         alive = jnp.ones((block,), jnp.uint32)
         acc = [jnp.zeros((block,), jnp.uint32) for _ in range(lanes)]
         for j in range(sigma):
-            tok = jax.lax.dynamic_slice(both, (j,), (block,)).astype(jnp.uint32)
+            tok = both_ref[pl.ds(j, block)].astype(jnp.uint32)
             alive = alive * (tok != 0).astype(jnp.uint32)  # mask after first PAD
             tok = tok * alive
             lane, slot = divmod(j, per)
             acc[lane] = acc[lane] + (tok << jnp.uint32(bits * (per - 1 - slot)))
-        out_ref[...] = jnp.stack(acc, axis=1)
+        for lane in range(lanes):
+            out_ref[lane, :] = acc[lane]
 
     return kernel
 
@@ -64,8 +68,9 @@ def suffix_pack(tokens: jax.Array, *, sigma: int, vocab_size: int, block: int = 
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i + 1,)),
         ],
-        out_specs=pl.BlockSpec((block, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, lanes), jnp.uint32),
+        out_specs=pl.BlockSpec((lanes, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((lanes, n_pad), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((2 * block,), jnp.int32)],
         interpret=interpret,
     )(toks, toks)
-    return out[:n]
+    return out.T[:n]

@@ -64,6 +64,8 @@ def main() -> None:
     if args.devices > 1:
         from repro.launch.mesh import pin_host_device_count
         pin_host_device_count(args.devices)   # before the first backend init
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.core import NGramConfig, extensions_filter, run_job
     from repro.data import corpus as corpus_mod

@@ -236,6 +236,8 @@ def main() -> None:
         # so it precedes both serving modes
         from repro.launch.mesh import pin_host_device_count
         pin_host_device_count(args.devices)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.obs import report as obs_report
     finish_obs = obs_report.setup(args.trace, args.metrics)
     if args.serve:

@@ -119,6 +119,21 @@ def unpack_terms(lanes_arr: jax.Array, *, vocab_size: int, sigma: int) -> jax.Ar
     return t[..., :sigma].astype(jnp.int32)
 
 
+def unpack_terms_np(lanes_arr: np.ndarray, *, vocab_size: int,
+                    sigma: int) -> np.ndarray:
+    """Host numpy mirror of :func:`unpack_terms` -- bit-identical terms.
+
+    Index builds unpack on the host: a device unpack would compile anew for
+    every distinct row count."""
+    bits = bits_for_vocab(vocab_size)
+    per = terms_per_lane(vocab_size)
+    shifts = np.arange(per - 1, -1, -1, dtype=np.uint32) * np.uint32(bits)
+    mask = np.uint32((1 << bits) - 1) if bits < 32 else np.uint32(0xFFFFFFFF)
+    t = (np.asarray(lanes_arr, np.uint32)[..., None] >> shifts) & mask
+    t = t.reshape(t.shape[:-2] + (t.shape[-2] * per,))
+    return t[..., :sigma].astype(np.int32)
+
+
 def lead_term(lane0: jax.Array, *, vocab_size: int) -> jax.Array:
     """First (most significant) term id of lane 0 -- the shuffle/serving routing key.
 

@@ -19,9 +19,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-KNUTH = jnp.uint32(2654435761)
-GOLDEN = jnp.uint32(0x9E3779B9)
+# host scalars: a device scalar here would start a backend at import
+KNUTH = np.uint32(2654435761)
+GOLDEN = np.uint32(0x9E3779B9)
 
 
 def hash_u32(x: jax.Array) -> jax.Array:
@@ -87,6 +89,14 @@ def bucketize(records: jax.Array, part: jax.Array, n_parts: int,
     buf = buf.at[slot].set(rec_s, mode="drop")
     overflow = jnp.sum((~ok) & (p_s < n_parts))
     return buf.reshape(n_parts, capacity, w), overflow
+
+
+def shard_rows(x, mesh, axis_name: str) -> jax.Array:
+    """Place a host array [P, ...] on the mesh: row p on the p-th device of
+    ``axis_name``, never the whole array on one device first."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.device_put(np.asarray(x),
+                          NamedSharding(mesh, PartitionSpec(axis_name)))
 
 
 def exchange(buffer: jax.Array, axis_name: str) -> jax.Array:

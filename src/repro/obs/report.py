@@ -70,6 +70,7 @@ def environment_metadata() -> dict:
         import jax
         meta.update(jax_version=jax.__version__,
                     backend=jax.default_backend(),
+                    device_kind=jax.devices()[0].device_kind,
                     device_count=jax.device_count())
     except Exception as e:                      # pragma: no cover - no jax
         meta["jax_error"] = str(e)

@@ -837,7 +837,8 @@ class WaveExecutor:
             if self._mesh_last_launch is not None:
                 jax.block_until_ready(self._mesh_last_launch)
             fn = self._mesh_wave_program(n_local, scale, with_skew)
-            outs = fn(jnp.asarray(tok_p), jnp.int32(n_live))
+            outs = fn(mr_shuffle.shard_rows(tok_p, self.mesh, self.axis_name),
+                      jnp.int32(n_live))
             self._mesh_last_launch = outs[1]
             return outs
 

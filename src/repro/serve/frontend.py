@@ -235,9 +235,11 @@ class QueryFrontend:
         try:
             import jax
             info["devices"] = {"backend": jax.default_backend(),
+                               "kind": jax.devices()[0].device_kind,
                                "count": jax.device_count()}
         except Exception:                            # pragma: no cover
-            info["devices"] = {"backend": "unavailable", "count": 0}
+            info["devices"] = {"backend": "unavailable", "kind": None,
+                               "count": 0}
         return info
 
     def close(self) -> None:

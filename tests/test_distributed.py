@@ -649,3 +649,26 @@ def test_mesh_wave_capacity_retry_counters_not_double_counted():
         print("OK retries=", tight.counters["retries"])
     """)
     assert "OK" in out
+
+
+def test_chip_smoke_mesh_phase_on_cpu():
+    """``chip_smoke.py --chips 4``'s phase -- mesh waves, the four mesh jobs
+    and the sharded index, each against one device -- on a 4-way host mesh
+    at a tiny size: the same code the four-chip run executes."""
+    out = run_with_devices("""
+        import sys
+        sys.path.insert(0, ".")
+        import chip_smoke
+        from repro.launch.mesh import make_data_mesh
+        mesh = make_data_mesh(4)
+        stats, info = chip_smoke.phase_mesh_waves(mesh, wave_corpus=8000,
+                                                  wave_tokens=2000)
+        assert info["waves"] == 4 and info["grams"] == len(stats), info
+        chip_smoke.phase_mesh_index(mesh, stats, n_lookups=64, n_prefixes=32)
+        done = []
+        info = chip_smoke.phase_mesh_jobs(mesh, job_tokens=6000,
+                                          report=done.append)
+        assert done == list(chip_smoke.METHODS), done
+        print("OK", info)
+    """, n=4)
+    assert "OK" in out

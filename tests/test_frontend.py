@@ -470,6 +470,10 @@ def test_http_topology_and_health(served):
         [ix.n_rows for ix in svc.gen.segments]
     assert topo["admission"]["queue_budget"] == fe.admission.queue_budget
     assert topo["batcher"]["buckets"] == list(fe.batcher.buckets)
+    import jax
+    assert topo["devices"] == {"backend": jax.default_backend(),
+                               "kind": jax.devices()[0].device_kind,
+                               "count": jax.device_count()}
     json.dumps(topo)                              # fully serializable
 
 

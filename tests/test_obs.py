@@ -207,9 +207,12 @@ def test_registry_snapshot_roundtrips_through_validator(tmp_path):
     snap = reg.snapshot()
     assert obs_report.validate_metrics(snap) == []
     path = tmp_path / "m.jsonl"
-    obs_report.write_jsonl(str(path), [{"metrics": snap,
-                                        "env": obs_report
-                                        .environment_metadata()}])
+    env = obs_report.environment_metadata()
+    import jax
+    assert (env["backend"], env["device_kind"], env["device_count"]) == (
+        jax.default_backend(), jax.devices()[0].device_kind,
+        jax.device_count())
+    obs_report.write_jsonl(str(path), [{"metrics": snap, "env": env}])
     assert obs_report.main(["--validate-metrics", str(path)]) == 0
     table = obs_report.summary_table(snap)
     assert "job.waves" in table and "lat" in table

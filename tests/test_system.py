@@ -72,3 +72,44 @@ def test_methods_cli_agree():
         line = [l for l in r.stdout.splitlines() if "n-grams in" in l][0]
         counts[m] = int(line.split("n-grams in")[0].split(":")[-1].strip())
     assert len(set(counts.values())) == 1, counts
+
+
+def test_imports_start_no_backend():
+    """Importing the package claims no device: a parent that imports repro
+    must still leave the chip to whoever creates the first array."""
+    import os
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    code = ("import repro, repro.core, repro.pipeline, repro.index, "
+            "repro.serve, repro.kernels.ops, repro.launch.ngram\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "print('OK')")
+    env = dict(os.environ, PYTHONPATH=str(root / "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120, env=env)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-2000:]
+
+
+def test_compile_cache_location(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise one fixed,
+    git-ignored directory inside the checkout."""
+    from pathlib import Path
+
+    import jax
+    from repro.launch import compile_cache
+    root = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path   # fixed
+        ignored = (root / ".gitignore").read_text().split()
+        assert ".jax_cache/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
