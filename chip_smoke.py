@@ -41,7 +41,6 @@ TOPK = 8
 ORACLE_TOKENS = 200_000
 JOB_TOKENS = 1 << 23
 WAVE_TOKENS = 1 << 21
-INGEST_SPLIT = (0.7, 0.1, 0.1, 0.1)
 N_LOOKUPS = 4096
 N_PREFIXES = 1024
 KERNEL_ROWS = 1 << 20
@@ -249,6 +248,18 @@ def phase_job(n_tokens: int, wave_tokens: int):
                          "max_cf": int(np.max(stats.counts))}
 
 
+def ingest_deltas(tokens, wave_tokens: int) -> list:
+    """Four deltas for the service: the bulk of the corpus, then three of a
+    quarter wave each.  The bulk runs in the job's wave shape and the three
+    small deltas share one, so the ingest adds one wave program, not one per
+    delta size; the small deltas compact into a merged (compressed) rung
+    beside the bulk."""
+    import numpy as np
+    small = wave_tokens // 4
+    first = tokens.size - 3 * small
+    return np.split(tokens, [first, first + small, first + 2 * small])
+
+
 def _check_index(name, idx, qg, ql, want, pg, pl, want_topk) -> None:
     import numpy as np
     from repro import index as ix
@@ -280,9 +291,8 @@ def phase_index(tokens, stats, *, wave_tokens: int, n_lookups: int,
     _check_index("compressed", comp, qg, ql, want, pg, pl, want_topk)
 
     svc = StreamingNGramService(cfg, compress=True, wave_tokens=wave_tokens)
-    cuts = np.cumsum([int(f * tokens.size) for f in INGEST_SPLIT[:-1]])
     parts = []
-    for delta in np.split(tokens, cuts):
+    for delta in ingest_deltas(tokens, wave_tokens):
         svc.ingest(delta)
         parts.append(WaveExecutor(cfg, wave_tokens=wave_tokens).run(delta))
     ref = ix.stats_union(*parts)
@@ -537,17 +547,18 @@ def main(argv=None) -> None:
         run("mesh jobs", phase_mesh_jobs, mesh, job_tokens=MESH_JOB_TOKENS,
             report=lambda m: note(f"mesh job {m}: equal to monolithic"))
     else:
-        # the oracle check compiles and runs beside the main path
+        # the oracle check and the kernels compile and run beside the job
         from concurrent.futures import ThreadPoolExecutor
-        with ThreadPoolExecutor(1) as side:
+        with ThreadPoolExecutor(2) as side:
             methods = side.submit(run, "methods", phase_methods,
                                   ORACLE_TOKENS)
-            run("kernels", phase_kernels, KERNEL_ROWS)
+            kernels = side.submit(run, "kernels", phase_kernels, KERNEL_ROWS)
             toks, stats, _ = run("job", phase_job, JOB_TOKENS, WAVE_TOKENS)
             svc, ref, _ = run("index", phase_index, toks, stats,
                               wave_tokens=WAVE_TOKENS, n_lookups=N_LOOKUPS,
                               n_prefixes=N_PREFIXES)
             run("frontend", phase_frontend, svc, ref)
+            kernels.result()
             methods.result()
     note(f"all phases ok; {compiles['n']} compiles took {compiles['s']:.1f} "
          "s summed over threads")
