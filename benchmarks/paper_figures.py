@@ -104,12 +104,9 @@ def fig6_scale(n_tokens=80_000):
 def resources_cell(n_tokens: int, n_dev: int) -> tuple[float, int]:
     """(wall seconds, n-grams) of the distributed suffix-sigma job over this
     process's first ``n_dev`` devices, compile excluded."""
-    import jax
+    from repro.launch.mesh import make_data_mesh
     toks = corpus_mod.zipf_corpus(n_tokens, corpus_mod.NYT, seed=0)
-    mesh = (jax.make_mesh((n_dev,), ("data",),
-                          axis_types=(jax.sharding.AxisType.Auto,),
-                          devices=jax.devices()[:n_dev])
-            if n_dev > 1 else None)
+    mesh = make_data_mesh(n_dev) if n_dev > 1 else None
     cfg = NGramConfig(sigma=5, tau=8, vocab_size=corpus_mod.NYT.vocab_size)
     run_job(toks, cfg, mesh=mesh)               # warmup incl. compile
     t0 = time.perf_counter()

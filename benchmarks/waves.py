@@ -42,13 +42,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def mesh_cell(n_tokens: int, n_devices: int, reps: int) -> dict:
     """Median wall time of the mesh-wave job over this process's first
     ``n_devices`` devices, one wave per device."""
-    import jax
     from repro.core import NGramConfig
     from repro.data import corpus as corpus_mod
+    from repro.launch.mesh import make_data_mesh
     from repro.pipeline import WaveExecutor
-    mesh = jax.make_mesh((n_devices,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,),
-                         devices=jax.devices()[:n_devices])
+    mesh = make_data_mesh(n_devices)
     prof = corpus_mod.NYT
     tokens = corpus_mod.zipf_corpus(n_tokens, prof, seed=0,
                                     duplicate_frac=0.02)

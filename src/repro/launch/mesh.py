@@ -30,7 +30,8 @@ def pin_host_device_count(n: int) -> None:
 
 
 def make_data_mesh(n: int):
-    """1-D ``n``-way data mesh -- the shape every ``--devices N`` driver uses."""
+    """1-D ``n``-way data mesh over the process's first ``n`` devices -- the
+    shape every ``--devices N`` launcher uses."""
     return jax.make_mesh((n,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))
 
