@@ -148,21 +148,28 @@ def _sorted_unique(segs: list[IndexSegment]):
     concatenation is a galloping k-way merge (numpy's timsort detects the k
     presorted runs) -- measured ~5-8x cheaper than a blind lexsort at the
     wave engine's row counts.  Duplicate counts fold exactly in int64 via
-    ``reduceat``.
+    ``reduceat``.  Spans: ``merge.kway.concat``, ``merge.kway.sort``,
+    ``merge.kway.fold``.
     """
-    keys = np.concatenate(
-        [np.asarray(s.keys, np.uint32)[:s.n_rows] for s in segs], axis=0)
-    counts = np.concatenate(
-        [np.asarray(s.counts, np.uint32)[:s.n_rows] for s in segs], axis=0)
-    row_bytes = row_bytes_view(keys)
-    order = np.argsort(row_bytes, kind="stable")
-    starts = _run_starts(row_bytes[order])
-    if not starts.size:
-        return (np.zeros((0, keys.shape[1]), np.uint32),
-                np.zeros((0,), np.int64), np.zeros((0,), row_bytes.dtype))
-    picked = order[starts]
-    totals = np.add.reduceat(counts[order].astype(np.int64), starts)
-    return keys[picked], totals, row_bytes[picked]
+    with obs_trace.span("merge.kway.concat"):
+        keys = np.concatenate(
+            [np.asarray(s.keys, np.uint32)[:s.n_rows] for s in segs], axis=0)
+        counts = np.concatenate(
+            [np.asarray(s.counts, np.uint32)[:s.n_rows] for s in segs],
+            axis=0)
+    with obs_trace.span("merge.kway.sort") as sp:
+        if sp:
+            sp.set(rows=int(keys.shape[0]))
+        row_bytes = row_bytes_view(keys)
+        order = np.argsort(row_bytes, kind="stable")
+    with obs_trace.span("merge.kway.fold"):
+        starts = _run_starts(row_bytes[order])
+        if not starts.size:
+            return (np.zeros((0, keys.shape[1]), np.uint32),
+                    np.zeros((0,), np.int64), np.zeros((0,), row_bytes.dtype))
+        picked = order[starts]
+        totals = np.add.reduceat(counts[order].astype(np.int64), starts)
+        return keys[picked], totals, row_bytes[picked]
 
 
 def _kway_fold_host(segs: list[IndexSegment], *,
@@ -189,15 +196,21 @@ def _kway_fold_host(segs: list[IndexSegment], *,
                 np.asarray(base.counts, np.uint32)[:nb])
     if nb < nd:
         keys, totals, _ = _sorted_unique(segs)
-        return keys, _check_u32(totals)
+        with obs_trace.span("merge.kway.fold"):
+            return keys, _check_u32(totals)
 
     # skewed fast path: sort/dedup only the small side ...
     d_keys, d_tot, d_bytes = _sorted_unique(segs[:b] + segs[b + 1:])
-    base = segs[b]
+    with obs_trace.span("merge.kway.splice"):
+        return _splice(segs[b], nb, d_keys, d_tot, d_bytes)
+
+
+def _splice(base: IndexSegment, nb: int, d_keys, d_tot, d_bytes):
+    """Fold the sorted unique delta rows into the ``nb`` base rows."""
     b_keys = np.asarray(base.keys, np.uint32)[:nb]
     b_tot = np.asarray(base.counts, np.uint32)[:nb].astype(np.int64)
     b_bytes = row_bytes_view(b_keys)
-    # ... then splice: delta rows already in the base fold their counts in
+    # splice: delta rows already in the base fold their counts in
     # place (unique x unique -- no index collides), the rest interleave at
     # their searchsorted insertion points via one shift-and-scatter
     pos = np.searchsorted(b_bytes, d_bytes, side="left")
@@ -354,8 +367,9 @@ def _merge_segments_body(segs, sigma, vocab, *, route, use_kernels, pad_to):
     size = pad_to if pad_to is not None else round_capacity(r)
     if size < r + 1:
         raise ValueError(f"pad_to={size} < n_rows+1={r + 1}")
-    keys_p = pad_rows(r_keys, size, SENTINEL)
-    cnts_p = pad_rows(r_tot, size, 0)
+    with obs_trace.span("merge.pad"):
+        keys_p = pad_rows(r_keys, size, SENTINEL)
+        cnts_p = pad_rows(r_tot, size, 0)
     if not host:
         # device routes hand device arrays back; the host folds stay
         # host-resident end to end -- an LSM cascade of kway merges would
@@ -422,17 +436,21 @@ def segment_to_stats(seg: IndexSegment, *,
     full view after the fact.
     """
     r = seg.n_rows
-    keys = np.asarray(seg.keys)[:r]
-    counts = np.asarray(seg.counts)[:r].astype(np.int64)
-    if min_count is not None and min_count > 1:
-        keep = counts >= min_count
-        keys = keys[keep]
-        counts = counts[keep]
-        r = int(keys.shape[0])
-    lengths = keys[:, 0].astype(np.int32)
-    grams = packing.unpack_terms_np(keys[:, 1:], vocab_size=seg.vocab_size,
-                                    sigma=seg.sigma)
-    return NGramStats(grams.astype(np.int32), lengths, counts)
+    with obs_trace.span("stats.filter"):
+        keys = np.asarray(seg.keys)[:r]
+        counts = np.asarray(seg.counts)[:r].astype(np.int64)
+        if min_count is not None and min_count > 1:
+            keep = counts >= min_count
+            keys = keys[keep]
+            counts = counts[keep]
+    with obs_trace.span("stats.unpack") as sp:
+        if sp:
+            sp.set(rows=int(keys.shape[0]))
+        lengths = keys[:, 0].astype(np.int32)
+        grams = packing.unpack_terms_np(keys[:, 1:],
+                                        vocab_size=seg.vocab_size,
+                                        sigma=seg.sigma)
+        return NGramStats(grams.astype(np.int32), lengths, counts)
 
 
 def stats_union(*stats: NGramStats) -> NGramStats:

@@ -7,8 +7,9 @@ when disabled**: the hot paths (wave dispatch, serving batches) see a shared
 null singleton, no added host syncs, no allocations.
 
   * :mod:`repro.obs.trace`   -- nested span tracer (context-manager API, host
-    wall clock, opt-in device-time scoping via ``block_until_ready`` only at
-    span close) exporting Chrome/Perfetto ``trace_event`` JSON;
+    wall clock, each span also a ``jax.profiler.TraceAnnotation`` on the
+    profiler's clock, never a device sync) exporting Chrome/Perfetto
+    ``trace_event`` JSON;
   * :mod:`repro.obs.metrics` -- typed registry of counters, gauges and
     fixed-boundary histograms (p50/p95/p99 without sample storage), plus the
     canonical job-counter glossary and merge/normalization policy that the

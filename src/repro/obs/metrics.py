@@ -61,6 +61,9 @@ COUNTER_DOC: dict[str, str] = {
     "waves": "token waves executed (wave-only)",
     "fold_rows": "segment rows fed through merge_segments by the wave "
                  "accumulator -- the measured fold work (wave-only)",
+    "d2h_bytes": "bytes materialized device to host when the waves are "
+                 "collected: the reducer outputs, key lanes, skew histogram "
+                 "and counter scalars (wave-only)",
     "phase_b_records": "SUFFIX-sigma phase-B survivor records (method-only)",
     "post_filter_jobs": "maximality/closedness post-filter jobs (method-only)",
     # ---- serving-frontend instruments (repro.serve; registry names, not job

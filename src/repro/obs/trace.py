@@ -5,18 +5,20 @@ Usage (instrumented code never checks whether tracing is on):
     from repro.obs import trace
 
     with trace.span("wave.submit") as sp:
-        if sp:                       # real span: attach args / device sync
+        if sp:                       # real span: attach args
             sp.set(wave=i)
-            sp.sync(device_arrays)   # block_until_ready at span CLOSE only
         ...
 
 ``trace.span`` returns the shared :data:`NULL_SPAN` singleton while tracing is
-disabled -- no allocation, no clock read, no device sync -- so the disabled
-path is a true no-op (regression-tested by ``tests/test_obs.py``).  Enabled,
-spans nest through a plain stack, record host ``perf_counter_ns`` intervals,
-and optionally scope *device* time: arrays registered via ``sp.sync(...)`` are
-``jax.block_until_ready``-ed at span close, so the span's duration covers the
-device work it dispatched instead of just the async-dispatch call.
+disabled -- no allocation, no clock read, no profiler annotation, no device
+sync -- so the disabled path is a true no-op (regression-tested by
+``tests/test_obs.py``).  Enabled, spans nest through a plain stack and record
+host ``perf_counter_ns`` intervals.  Each enabled span also enters a
+``jax.profiler.TraceAnnotation`` of the same name for its lifetime, so while
+the JAX profiler runs every span sits in its ``.xplane.pb`` on the
+profiler's own clock, beside the device ops.  A span never blocks on the
+device: where the device wait matters, the instrumented code wraps the wait
+in a span of its own (``wave.collect.wait``).
 
 Export is the Chrome ``trace_event`` "complete event" (``ph: "X"``) format,
 loadable directly in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``;
@@ -49,17 +51,14 @@ class _NullSpan:
     def set(self, **args) -> None:
         pass
 
-    def sync(self, _x) -> None:
-        pass
-
 
 NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """One live span; closes (and optionally device-syncs) on ``__exit__``."""
+    """One live span, inside a profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "name", "args", "t0", "t1", "tid", "_sync")
+    __slots__ = ("_tracer", "name", "args", "t0", "t1", "tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None):
         self._tracer = tracer
@@ -68,7 +67,7 @@ class Span:
         self.t0 = 0
         self.t1 = 0
         self.tid = threading.get_ident() & 0xFFFF
-        self._sync = None
+        self._ann = None
 
     def __bool__(self) -> bool:
         return True
@@ -79,25 +78,15 @@ class Span:
             self.args = {}
         self.args.update(args)
 
-    def sync(self, x) -> None:
-        """Register device values to ``block_until_ready`` at span close.
-
-        This is the *opt-in* device-time scoping: without it a span around an
-        async jax dispatch measures only the dispatch; with it the span close
-        waits for the registered arrays, so the duration covers the device
-        work.  The sync happens once, at ``__exit__`` -- never mid-span.
-        """
-        self._sync = x if self._sync is None else (self._sync, x)
-
     def __enter__(self) -> "Span":
+        self._ann = self._tracer._profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        if self._sync is not None:
-            import jax
-            jax.block_until_ready(self._sync)
         self.t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         self._tracer._finish(self)
         return False
 
@@ -106,6 +95,8 @@ class Tracer:
     """Collects finished spans; exports Chrome ``trace_event`` JSON."""
 
     def __init__(self):
+        from jax import profiler   # the annotations' home; enabled path only
+        self._profiler = profiler
         self.events: list[dict] = []
         self._t_origin = time.perf_counter_ns()
 

@@ -141,24 +141,31 @@ def _stage_core_impl(records, valid, *, n_lanes: int, has_bucket: bool,
     """
     map_rec = jnp.sum(valid)
     if combine_route is not None:
-        records = stages.combine(records, n_lanes, has_bucket,
-                                 route=combine_route, use_kernels=use_kernels)
+        with jax.named_scope("combine"):
+            records = stages.combine(records, n_lanes, has_bucket,
+                                     route=combine_route,
+                                     use_kernels=use_kernels)
     live = records[:, n_lanes] > 0
     shuffled = jnp.sum(live)
-    key = stages.partition_keys(records, n_lanes, kind=shuffle_key,
-                                vocab_size=lane_vocab)
-    # the real partitioner's bucketing (hash_u32 % P, invalid -> P), so the
-    # skew counter measures realized reducer load, not raw-key spread
-    bucket = mr_shuffle.partition_ids(key, live, _SKEW_BUCKETS)
-    hist = jnp.bincount(bucket, length=_SKEW_BUCKETS + 1)[:_SKEW_BUCKETS]
-    rec = stages.sort_stage(records, n_keys=n_lanes)
-    if reduce_kind == "suffix":
-        dense = stages.reduce_suffix(rec, sigma=sigma, vocab_size=lane_vocab,
-                                     n_buckets=n_buckets,
-                                     use_kernels=use_kernels)
-    else:
-        dense = stages.reduce_exact(rec, sigma=sigma, vocab_size=lane_vocab,
-                                    with_positions=with_positions)
+    with jax.named_scope("partition"):
+        key = stages.partition_keys(records, n_lanes, kind=shuffle_key,
+                                    vocab_size=lane_vocab)
+        # the real partitioner's bucketing (hash_u32 % P, invalid -> P), so
+        # the skew counter measures realized reducer load, not raw-key spread
+        bucket = mr_shuffle.partition_ids(key, live, _SKEW_BUCKETS)
+        hist = jnp.bincount(bucket, length=_SKEW_BUCKETS + 1)[:_SKEW_BUCKETS]
+    with jax.named_scope("sort"):
+        rec = stages.sort_stage(records, n_keys=n_lanes)
+    with jax.named_scope("reduce"):
+        if reduce_kind == "suffix":
+            dense = stages.reduce_suffix(rec, sigma=sigma,
+                                         vocab_size=lane_vocab,
+                                         n_buckets=n_buckets,
+                                         use_kernels=use_kernels)
+        else:
+            dense = stages.reduce_exact(rec, sigma=sigma,
+                                        vocab_size=lane_vocab,
+                                        with_positions=with_positions)
     return dense, map_rec, shuffled, hist, rec[:, :n_lanes]
 
 
@@ -173,6 +180,11 @@ def _build_wave_program(cfg, plan: JobPlan):
     the partial final wave reuses the same executable, and position payloads
     are skipped (``with_positions=False``): only tau>1 carries consume them,
     which the wave regime never takes.
+
+    Each stage traces under a ``jax.named_scope`` (emit, combine, partition,
+    sort, reduce), so a profile's op metadata names the stage of every
+    device op.  The function keeps its name: the benchmark's readers find
+    the program as the XLA module ``jit_wave_fn``.
     """
     lane_vocab = plan.effective_lane_vocab(cfg)
     n_l = packing.n_lanes(cfg.sigma, lane_vocab)
@@ -182,8 +194,9 @@ def _build_wave_program(cfg, plan: JobPlan):
         carry = None
         rounds = []
         for k in range(1, plan.rounds + 1):
-            records, valid, emit_extras = plan.map.emit(
-                tok_ext, None, n_live, cfg, carry, k)
+            with jax.named_scope("emit"):
+                records, valid, emit_extras = plan.map.emit(
+                    tok_ext, None, n_live, cfg, carry, k)
             dense, map_rec, shuffled, hist, lanes = _stage_core_impl(
                 records, valid, n_lanes=n_l, has_bucket=False,
                 combine_route=combine_route, use_kernels=cfg.use_kernels,
@@ -355,6 +368,36 @@ def _merge_wave_counters(dst: dict, src: dict) -> None:
     obs_metrics.merge_counter_dicts(dst, src)
 
 
+def _to_host(*xs):
+    """``np.asarray`` of each device value, and the bytes they brought over."""
+    host = tuple(np.asarray(x) for x in xs)
+    return host, sum(h.nbytes for h in host)
+
+
+def _add_round_counters(counters: dict, map_rec, shuffled, hist,
+                        rec_bytes: int, d2h_bytes: int) -> None:
+    """Fold one collected round's host scalars and skew histogram into a
+    wave's counters."""
+    from repro.core.stats import add_counters
+    shuffled = int(shuffled)
+    add_counters(counters, jobs=1, map_records=int(map_rec),
+                 shuffle_records=shuffled, shuffle_bytes=shuffled * rec_bytes,
+                 d2h_bytes=d2h_bytes)
+    if shuffled:
+        skew = float(hist.max() * _SKEW_BUCKETS / max(hist.sum(), 1))
+        counters["shuffle_skew"] = max(counters.get("shuffle_skew", 0.0),
+                                       skew)
+
+
+def _wait_for_device(outs, wave: int) -> None:
+    """Traced runs only: block on a wave's outputs inside a span of their
+    own, so a collect's wait for the device is apart from its host work (the
+    untraced collect blocks in its first ``np.asarray`` instead)."""
+    with obs_trace.span("wave.collect.wait") as sp:
+        sp.set(wave=wave)
+        jax.block_until_ready(outs)
+
+
 class WavePartial:
     """One collected wave: its host-frozen sorted segment + job counters.
 
@@ -499,7 +542,7 @@ class WaveExecutor:
 
     # --- single-device async wave dispatch ----------------------------------- #
 
-    def _submit_wave(self, tok_ext, n_live: int) -> dict:
+    def _submit_wave(self, tok_ext, n_live: int, wave: int = 0) -> dict:
         """Dispatch one wave as ONE fused program; nothing materializes here.
 
         The wave regime always runs at ``tau_eff = 1``, where carries are a
@@ -510,14 +553,15 @@ class WaveExecutor:
         :meth:`_collect_wave`.  ``stop_on_empty`` is skipped: an exhausted
         round chain emits empty partials that fold to nothing.  With a mesh,
         the wave dispatches through the fused sharded program instead
-        (:meth:`_submit_wave_mesh`) -- same async contract.
+        (:meth:`_submit_wave_mesh`) -- same async contract.  ``wave`` is the
+        wave's index, carried to the collect's spans.
         """
         if self._use_mesh:
-            return self._submit_wave_mesh(tok_ext, n_live)
+            return self._submit_wave_mesh(tok_ext, n_live, wave)
         cfg, plan = self.cfg, self.plan
         with obs_trace.span("wave.submit") as sp:
             if sp:
-                sp.set(n_live=n_live, rounds=plan.rounds)
+                sp.set(wave=wave, n_live=n_live, rounds=plan.rounds)
             # one span == one dispatch: the fused-wave regression tests count
             # exactly one round.stages span per wave, any number of rounds
             with obs_trace.span("round.stages") as sp_s:
@@ -527,37 +571,41 @@ class WaveExecutor:
             rec_bytes = packing.record_bytes(
                 cfg.sigma, plan.effective_lane_vocab(cfg),
                 n_meta=plan.map.n_meta)
-            return {"rounds": list(rounds), "rec_bytes": rec_bytes}
+            return {"rounds": list(rounds), "rec_bytes": rec_bytes,
+                    "wave": wave}
 
     def _collect_wave(self, pend: dict):
         """Materialize a submitted wave -> exact ``NGramStats`` partial.
 
         The ``np.asarray`` materializations here are the wave's one device
-        sync: the collect span's duration is host-visible device+transfer
-        time (the double-buffer's occupancy signal -- a collect much shorter
-        than its submit-to-submit gap means the device was idle).
+        sync (``wave.collect.d2h``); traced, the wait for the device comes
+        first, in ``wave.collect.wait``, and the host work on the rows in
+        ``wave.collect.rows``.
         """
         if pend.get("mesh"):
             return self._collect_wave_mesh(pend)
-        from repro.core.stats import NGramStats, add_counters
+        from repro.core.stats import NGramStats
 
+        w = pend["wave"]
         with obs_trace.span("wave.collect") as sp:
+            if sp:
+                sp.set(wave=w)
+                _wait_for_device(pend["rounds"], w)
             counters: dict = {}
             out = None
             for dense, map_rec, shuffled, hist, _lanes in pend["rounds"]:
-                terms, flags, counts = (np.asarray(x) for x in dense)
-                stats_k = NGramStats.from_dense(terms, flags, counts, 1)
-                shuffled = int(shuffled)
-                hist = np.asarray(hist)
-                add_counters(counters, jobs=1, map_records=int(map_rec),
-                             shuffle_records=shuffled,
-                             shuffle_bytes=shuffled * pend["rec_bytes"])
-                if shuffled:
-                    skew = float(hist.max() * _SKEW_BUCKETS
-                                 / max(hist.sum(), 1))
-                    counters["shuffle_skew"] = max(
-                        counters.get("shuffle_skew", 0.0), skew)
-                out = stats_k if out is None else out.merged_with(stats_k)
+                with obs_trace.span("wave.collect.d2h") as sp_d:
+                    (terms, flags, counts, hist, map_rec, shuffled), nbytes = \
+                        _to_host(*dense, hist, map_rec, shuffled)
+                    if sp_d:
+                        sp_d.set(wave=w, bytes=nbytes)
+                with obs_trace.span("wave.collect.rows") as sp_r:
+                    if sp_r:
+                        sp_r.set(wave=w)
+                    stats_k = NGramStats.from_dense(terms, flags, counts, 1)
+                    _add_round_counters(counters, map_rec, shuffled, hist,
+                                        pend["rec_bytes"], nbytes)
+                    out = stats_k if out is None else out.merged_with(stats_k)
             out.counters = counters
             if sp:
                 sp.set(rows=len(out), shuffle_records=counters.get(
@@ -600,42 +648,49 @@ class WaveExecutor:
             return self._collect_wave_segment_mesh(pend)
         if not self._direct:
             return self._partial_from_stats(self._collect_wave(pend))
-        from repro.core.stats import add_counters
         from repro.index._layout import row_bytes_view
         from repro.index.build import IndexSegment
 
         cfg = self.cfg
+        w = pend["wave"]
         with obs_trace.span("wave.collect") as sp:
+            if sp:
+                sp.set(wave=w)
+                _wait_for_device(pend["rounds"], w)
             counters: dict = {}
             masks = self._prefix_masks()
             key_parts, cnt_parts = [], []
             for dense, map_rec, shuffled, hist, lanes in pend["rounds"]:
-                flags = np.asarray(dense[1])
-                counts = np.asarray(dense[2])
-                lanes = np.asarray(lanes)
-                shuffled = int(shuffled)
-                hist = np.asarray(hist)
-                add_counters(counters, jobs=1, map_records=int(map_rec),
-                             shuffle_records=shuffled,
-                             shuffle_bytes=shuffled * pend["rec_bytes"])
-                if shuffled:
-                    skew = float(hist.max() * _SKEW_BUCKETS
-                                 / max(hist.sum(), 1))
-                    counters["shuffle_skew"] = max(
-                        counters.get("shuffle_skew", 0.0), skew)
-                # from_dense's keep at the wave regime's tau = 1
-                keep = (flags != 0) & (counts >= 1)
-                lens0, rows = np.nonzero(keep.T)
-                lengths = (lens0 + 1).astype(np.uint32)
-                pref = lanes[rows] & masks[lengths]
-                key_parts.append(np.concatenate(
-                    [lengths[:, None], pref], axis=1).astype(np.uint32))
-                cnt_parts.append(counts[rows, lens0].astype(np.uint32))
-            keys = np.concatenate(key_parts, axis=0)
-            cnts = np.concatenate(cnt_parts, axis=0)
-            order = np.argsort(row_bytes_view(keys), kind="stable")
-            seg = IndexSegment(keys=keys[order], counts=cnts[order],
-                               sigma=cfg.sigma, vocab_size=cfg.vocab_size)
+                with obs_trace.span("wave.collect.d2h") as sp_d:
+                    (flags, counts, lanes, hist, map_rec, shuffled), nbytes = \
+                        _to_host(dense[1], dense[2], lanes, hist, map_rec,
+                                 shuffled)
+                    if sp_d:
+                        sp_d.set(wave=w, bytes=nbytes)
+                with obs_trace.span("wave.collect.rows") as sp_r:
+                    if sp_r:
+                        sp_r.set(wave=w)
+                    _add_round_counters(counters, map_rec, shuffled, hist,
+                                        pend["rec_bytes"], nbytes)
+                    # from_dense's keep at the wave regime's tau = 1
+                    keep = (flags != 0) & (counts >= 1)
+                    lens0, rows = np.nonzero(keep.T)
+                    lengths = (lens0 + 1).astype(np.uint32)
+                    pref = lanes[rows] & masks[lengths]
+                    key_parts.append(np.concatenate(
+                        [lengths[:, None], pref], axis=1).astype(np.uint32))
+                    cnt_parts.append(counts[rows, lens0].astype(np.uint32))
+            with obs_trace.span("wave.collect.rows") as sp_r:
+                if sp_r:
+                    sp_r.set(wave=w)
+                keys = np.concatenate(key_parts, axis=0)
+                cnts = np.concatenate(cnt_parts, axis=0)
+            with obs_trace.span("wave.collect.sort") as sp_s:
+                if sp_s:
+                    sp_s.set(wave=w, rows=int(keys.shape[0]))
+                order = np.argsort(row_bytes_view(keys), kind="stable")
+                seg = IndexSegment(keys=keys[order], counts=cnts[order],
+                                   sigma=cfg.sigma, vocab_size=cfg.vocab_size)
             if sp:
                 sp.set(rows=int(keys.shape[0]), shuffle_records=counters.get(
                     "shuffle_records", 0))
@@ -728,42 +783,52 @@ class WaveExecutor:
             cnt_rows = []
             hists = []
             for k in range(1, plan.rounds + 1):
-                records, valid, emit_extras = plan.map.emit(
-                    tok_ext, None, n_live_local, cfg, carry, k)
+                with jax.named_scope("emit"):
+                    records, valid, emit_extras = plan.map.emit(
+                        tok_ext, None, n_live_local, cfg, carry, k)
                 map_rec = jnp.sum(valid.astype(jnp.int32))
                 if combine_route is not None:
-                    records = stages.combine(records, n_l, False,
-                                             route=combine_route,
-                                             use_kernels=cfg.use_kernels)
+                    with jax.named_scope("combine"):
+                        records = stages.combine(records, n_l, False,
+                                                 route=combine_route,
+                                                 use_kernels=cfg.use_kernels)
                 live = records[:, n_l] > 0
-                key = stages.partition_keys(records, n_l,
-                                            kind=plan.shuffle.key,
-                                            vocab_size=lane_vocab)
-                if with_skew:
-                    skew = mr_shuffle.partition_ids(key, live, _SKEW_BUCKETS)
-                    hists.append(jnp.bincount(
-                        skew, length=_SKEW_BUCKETS + 1)[:_SKEW_BUCKETS])
-                local, overflow = mr_shuffle.shuffle(
-                    records, key, live, axis_name=axis_name, n_parts=n_parts,
-                    capacity=caps[k], reduce_overflow=False)
+                with jax.named_scope("partition"):
+                    key = stages.partition_keys(records, n_l,
+                                                kind=plan.shuffle.key,
+                                                vocab_size=lane_vocab)
+                    if with_skew:
+                        skew = mr_shuffle.partition_ids(key, live,
+                                                        _SKEW_BUCKETS)
+                        hists.append(jnp.bincount(
+                            skew, length=_SKEW_BUCKETS + 1)[:_SKEW_BUCKETS])
+                with jax.named_scope("shuffle"):
+                    local, overflow = mr_shuffle.shuffle(
+                        records, key, live, axis_name=axis_name,
+                        n_parts=n_parts, capacity=caps[k],
+                        reduce_overflow=False)
                 shuf = jnp.sum(local[:, n_l] > 0)
                 cnt_rows.append(jnp.stack([map_rec, shuf,
                                            overflow.astype(jnp.int32)]))
-                rec = stages.sort_stage(local, n_keys=n_l)
-                if plan.reduce.kind == "suffix":
-                    terms, flags, counts = stages.reduce_suffix(
-                        rec, sigma=cfg.sigma, vocab_size=lane_vocab,
-                        n_buckets=0, use_kernels=cfg.use_kernels)
-                else:
-                    # position payloads are only consumed by tau>1 carries,
-                    # which the wave regime never takes -- skip the scatter
-                    terms, flags, counts = stages.reduce_exact(
-                        rec, sigma=cfg.sigma, vocab_size=lane_vocab,
-                        with_positions=False)
+                with jax.named_scope("sort"):
+                    rec = stages.sort_stage(local, n_keys=n_l)
+                with jax.named_scope("reduce"):
+                    if plan.reduce.kind == "suffix":
+                        terms, flags, counts = stages.reduce_suffix(
+                            rec, sigma=cfg.sigma, vocab_size=lane_vocab,
+                            n_buckets=0, use_kernels=cfg.use_kernels)
+                    else:
+                        # position payloads are only consumed by tau>1
+                        # carries, which the wave regime never takes --
+                        # skip the scatter
+                        terms, flags, counts = stages.reduce_exact(
+                            rec, sigma=cfg.sigma, vocab_size=lane_vocab,
+                            with_positions=False)
                 if direct:
-                    rounds_out.append(stages.segment_candidates(
-                        flags, counts, rec[:, :n_l], masks, sigma=cfg.sigma,
-                        reduce_kind=plan.reduce.kind))
+                    with jax.named_scope("segment_candidates"):
+                        rounds_out.append(stages.segment_candidates(
+                            flags, counts, rec[:, :n_l], masks,
+                            sigma=cfg.sigma, reduce_kind=plan.reduce.kind))
                 else:
                     rounds_out.append((terms[None], flags[None],
                                        counts[None]))
@@ -788,7 +853,8 @@ class WaveExecutor:
             job, mesh=mesh, in_specs=(P(axis_name, None), P()),
             out_specs=tuple(out_specs), check_vma=False))
 
-    def _submit_wave_mesh(self, tok_host: np.ndarray, n_live: int) -> dict:
+    def _submit_wave_mesh(self, tok_host: np.ndarray, n_live: int,
+                          wave: int = 0) -> dict:
         """Dispatch one mesh wave as ONE sharded program; nothing syncs here.
 
         ``tok_host`` stays a host array until the padded [n_parts, n_local]
@@ -810,15 +876,15 @@ class WaveExecutor:
         scale = self._mesh_scale
         with obs_trace.span("wave.mesh.dispatch") as sp:
             if sp:
-                sp.set(n_live=n_live, rounds=plan.rounds, n_local=n_local,
-                       scale=scale)
+                sp.set(wave=wave, n_live=n_live, rounds=plan.rounds,
+                       n_local=n_local, scale=scale)
             outs = self._launch_mesh_wave(n_local, scale, with_skew, tok_p,
                                           n_live)
         rec_bytes = packing.record_bytes(
             cfg.sigma, plan.effective_lane_vocab(cfg), n_meta=plan.map.n_meta)
         return {"mesh": True, "outs": outs, "tok_p": tok_p, "n_live": n_live,
                 "n_local": n_local, "scale": scale, "with_skew": with_skew,
-                "rec_bytes": rec_bytes}
+                "rec_bytes": rec_bytes, "wave": wave}
 
     def _launch_mesh_wave(self, n_local: int, scale: int, with_skew: bool,
                           tok_p: np.ndarray, n_live: int):
@@ -859,7 +925,10 @@ class WaveExecutor:
         outs = pend["outs"]
         retries = 0
         while True:
-            cnt = np.asarray(outs[1])[0]                     # [rounds, 3]
+            with obs_trace.span("wave.collect.d2h") as sp:
+                cnt = np.asarray(outs[1])[0]                 # [rounds, 3]
+                if sp:
+                    sp.set(wave=pend["wave"], bytes=cnt.nbytes)
             if int(cnt[:, 2].sum()) == 0:
                 return outs, cnt, retries
             if retries >= 5:
@@ -876,15 +945,15 @@ class WaveExecutor:
                                               pend["with_skew"],
                                               pend["tok_p"], pend["n_live"])
 
-    def _mesh_counters(self, cnt: np.ndarray, outs, pend: dict,
+    def _mesh_counters(self, cnt: np.ndarray, hist, pend: dict,
                        retries: int) -> dict:
-        """Wave counters from the successful attempt's psum'd ``cnt`` block."""
+        """Wave counters from the successful attempt's psum'd ``cnt`` block
+        and, when metrics are on, its skew histogram ``hist`` (host)."""
         from repro.core.stats import add_counters
 
         counters: dict = {}
         if retries:   # capacity-doubling reruns, visible like the jobs'
             add_counters(counters, retries=retries)
-        hist = np.asarray(outs[2])[0] if pend["with_skew"] else None
         for k in range(cnt.shape[0]):
             shuf = int(cnt[k, 1])
             add_counters(counters, jobs=1, map_records=int(cnt[k, 0]),
@@ -899,14 +968,12 @@ class WaveExecutor:
 
     def _mesh_wave_stats(self, rounds_out, counters: dict):
         """Stats-route fallback fold (``pack_vocab`` overrides): from_dense
-        per shard per round, merged on host -- only configs whose lane
-        layout is not the segment layout pay this."""
+        per shard per round over the host ``rounds_out``, merged on host --
+        only configs whose lane layout is not the segment layout pay this."""
         from repro.core.stats import NGramStats
 
         out = None
         for terms, flags, counts in rounds_out:
-            terms, flags, counts = (np.asarray(terms), np.asarray(flags),
-                                    np.asarray(counts))
             for p in range(terms.shape[0]):
                 part = NGramStats.from_dense(terms[p], flags[p], counts[p], 1)
                 out = part if out is None else out.merged_with(part)
@@ -929,20 +996,43 @@ class WaveExecutor:
         from repro.index._layout import row_bytes_view
         from repro.index.build import IndexSegment
 
+        from repro.core.stats import add_counters
+
+        w = pend["wave"]
         with obs_trace.span("wave.mesh.collect") as sp:
+            if sp:
+                sp.set(wave=w)
+                _wait_for_device(pend["outs"], w)
             outs, cnt, retries = self._collect_wave_mesh_outs(pend)
-            counters = self._mesh_counters(cnt, outs, pend, retries)
+            with obs_trace.span("wave.collect.d2h") as sp_d:
+                hist = np.asarray(outs[2])[0] if pend["with_skew"] else None
+                rounds, nbytes = zip(*(_to_host(*r) for r in outs[0]))
+                nbytes = cnt.nbytes + sum(nbytes) + (
+                    hist.nbytes if hist is not None else 0)
+                if sp_d:
+                    sp_d.set(wave=w, bytes=nbytes)
+            counters = self._mesh_counters(cnt, hist, pend, retries)
+            add_counters(counters, d2h_bytes=nbytes)
             if not self._direct:
-                return self._partial_from_stats(
-                    self._mesh_wave_stats(outs[0], counters))
-            keys = np.concatenate([np.asarray(k) for k, _ in outs[0]], axis=0)
-            cnts = np.concatenate([np.asarray(c) for _, c in outs[0]], axis=0)
-            live = cnts > 0
-            keys, cnts = keys[live], cnts[live]
-            order = np.argsort(row_bytes_view(keys), kind="stable")
-            seg = IndexSegment(keys=keys[order], counts=cnts[order],
-                               sigma=self.cfg.sigma,
-                               vocab_size=self.cfg.vocab_size)
+                with obs_trace.span("wave.collect.rows") as sp_r:
+                    if sp_r:
+                        sp_r.set(wave=w)
+                    stats = self._mesh_wave_stats(rounds, counters)
+                return self._partial_from_stats(stats)
+            with obs_trace.span("wave.collect.rows") as sp_r:
+                if sp_r:
+                    sp_r.set(wave=w)
+                keys = np.concatenate([k for k, _ in rounds], axis=0)
+                cnts = np.concatenate([c for _, c in rounds], axis=0)
+                live = cnts > 0
+                keys, cnts = keys[live], cnts[live]
+            with obs_trace.span("wave.collect.sort") as sp_s:
+                if sp_s:
+                    sp_s.set(wave=w, rows=int(keys.shape[0]))
+                order = np.argsort(row_bytes_view(keys), kind="stable")
+                seg = IndexSegment(keys=keys[order], counts=cnts[order],
+                                   sigma=self.cfg.sigma,
+                                   vocab_size=self.cfg.vocab_size)
             if sp:
                 sp.set(rows=int(keys.shape[0]), retries=retries,
                        shuffle_records=counters.get("shuffle_records", 0))
@@ -972,9 +1062,9 @@ class WaveExecutor:
         self.cfg.validate_tokens(tokens)
         drv = DoubleBufferedDriver(self._submit_wave,
                                    collect=self._collect_wave)
-        for tok_ext, n_live in self._windows(tokens,
-                                             to_device=not self._use_mesh):
-            res, _ = drv.submit(tok_ext, n_live)
+        for w, (tok_ext, n_live) in enumerate(
+                self._windows(tokens, to_device=not self._use_mesh)):
+            res, _ = drv.submit(tok_ext, n_live, w)
             if res is not None:
                 yield res
         res, _ = drv.drain()
@@ -996,7 +1086,8 @@ class WaveExecutor:
         of :meth:`run`, the generational ingest of :meth:`run_streaming`).
         Host-side fold work therefore overlaps the next waves' device work
         instead of serializing with it; a bounded queue
-        (``_WAVES_IN_FLIGHT``) backpressures the feeder so at most a small
+        (``_WAVES_IN_FLIGHT``) backpressures the feeder (the
+        ``wave.feed.wait`` span) so at most a small
         constant number of waves is ever in flight, preserving the
         O(wave * sigma) memory model.  The single FIFO fold thread keeps
         wave order, so the fold sequence -- and with it the bit-identity
@@ -1009,9 +1100,9 @@ class WaveExecutor:
         self.cfg.validate_tokens(tokens)
         to_device = not self._use_mesh
         if not self.overlap:
-            for tok_ext, n_live in self._windows(tokens,
-                                                 to_device=to_device):
-                consume(collect(self._submit_wave(tok_ext, n_live)))
+            for w, (tok_ext, n_live) in enumerate(
+                    self._windows(tokens, to_device=to_device)):
+                consume(collect(self._submit_wave(tok_ext, n_live, w)))
             return
         import queue
         import threading
@@ -1036,11 +1127,16 @@ class WaveExecutor:
                                   daemon=True)
         folder.start()
         try:
-            for tok_ext, n_live in self._windows(tokens,
-                                                 to_device=to_device):
+            for w, (tok_ext, n_live) in enumerate(
+                    self._windows(tokens, to_device=to_device)):
                 if failure:
                     break
-                work.put(self._submit_wave(tok_ext, n_live))
+                pend = self._submit_wave(tok_ext, n_live, w)
+                # waits only while the fold thread is behind
+                with obs_trace.span("wave.feed.wait") as sp:
+                    if sp:
+                        sp.set(wave=w)
+                    work.put(pend)
         finally:
             work.put(None)
             folder.join()
@@ -1068,10 +1164,11 @@ class WaveExecutor:
                          accumulator=self.accumulator)
             # full canonical counter set (obs.metrics.COUNTER_DOC): identical
             # keys to the monolithic run_plan, plus the wave-only
-            # waves/fold_rows
+            # waves/fold_rows/d2h_bytes
             counters = dict.fromkeys(
                 ("jobs", "map_records", "shuffle_records", "shuffle_bytes",
-                 "retries", "overflow", "waves", "fold_rows"), 0)
+                 "retries", "overflow", "waves", "fold_rows", "d2h_bytes"),
+                0)
             counters["shuffle_skew"] = 0.0
             acc_cls = {"defer": DeferredSegmentAccumulator,
                        "tiered": TieredSegmentAccumulator,

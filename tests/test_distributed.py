@@ -481,6 +481,11 @@ def test_fused_mesh_one_dispatch_per_wave():
         assert names.count("wave.mesh.retry") == 0
         assert names.count("wave.fold") == n_waves
         assert names.count("wave.run") == 1
+        for name in ("wave.mesh.dispatch", "wave.mesh.collect",
+                     "wave.collect.wait", "wave.collect.sort",
+                     "wave.feed.wait"):
+            assert sorted(e["args"]["wave"] for e in tracer.events
+                          if e["name"] == name) == list(range(n_waves)), name
         print("OK")
     """)
     assert "OK" in out

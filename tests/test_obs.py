@@ -16,7 +16,11 @@ Four guarantees under test:
     weakens at tau=1.)
   * **Disabled == free** -- with tracing off, ``trace.span`` returns the
     shared null singleton and a full wave run performs zero
-    ``jax.block_until_ready`` calls attributable to the tracer.
+    ``jax.block_until_ready`` calls, creates no profiler annotation and
+    allocates nothing in ``obs/trace.py``.
+  * **One clock with the profiler** -- enabled, every span enters exactly one
+    ``jax.profiler.TraceAnnotation`` of its name, so it lands in the
+    profiler's ``.xplane.pb`` beside the device ops.
 """
 import json
 
@@ -116,6 +120,80 @@ def test_traced_eight_wave_run_schema_and_coverage(tmp_path):
     assert obs_trace.span_coverage(obj, "wave.run") >= 0.90
 
 
+COLLECT_SPANS = ("wave.collect.wait", "wave.collect.d2h", "wave.collect.rows",
+                 "wave.collect.sort")
+FINALIZE_SPANS = ("merge.kway.concat", "merge.kway.sort", "merge.kway.fold",
+                  "merge.pad", "stats.filter", "stats.unpack")
+
+
+@pytest.fixture(scope="module")
+def eight_wave_trace():
+    """One traced 8-wave job: (exported trace, its stats)."""
+    toks = make_corpus(40000, 60, "zipf", 10)
+    cfg = NGramConfig(sigma=3, tau=3, vocab_size=60)
+    wave = -(-len(toks) // 8)
+    WaveExecutor(cfg, wave_tokens=wave).run(toks)     # compile untraced
+    tracer = obs_trace.enable_tracing()
+    try:
+        stats = WaveExecutor(cfg, wave_tokens=wave).run(toks)
+    finally:
+        obs_trace.disable_tracing()
+    return tracer.export(), stats
+
+
+def _inside(inner, outer) -> bool:
+    return (inner["tid"] == outer["tid"] and inner["ts"] >= outer["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+def test_collect_and_finalize_spans_nest_and_join_by_wave(eight_wave_trace):
+    obj, stats = eight_wave_trace
+    evs = obj["traceEvents"]
+    assert obs_report.validate_trace(obj) == []
+    by_name = {}
+    for e in evs:
+        by_name.setdefault(e["name"], []).append(e)
+    assert set(COLLECT_SPANS + FINALIZE_SPANS + ("wave.feed.wait",)) \
+        <= set(by_name)
+    for name in COLLECT_SPANS:
+        for e in by_name[name]:
+            parent = [c for c in by_name["wave.collect"] if _inside(e, c)]
+            assert len(parent) == 1, name
+            assert e["args"]["wave"] == parent[0]["args"]["wave"]
+    for name in FINALIZE_SPANS:
+        for e in by_name[name]:
+            assert any(_inside(e, f) for f in by_name["wave.finalize"]), name
+    for name in FINALIZE_SPANS[:3]:
+        for e in by_name[name]:
+            assert any(_inside(e, m) for m in by_name["merge.segments"]), name
+    # one wave's spans join across the feeder and fold threads by ``wave``
+    submits = {e["args"]["wave"]: e for e in by_name["wave.submit"]}
+    assert sorted(submits) == list(range(8))
+    for name in ("wave.collect",) + COLLECT_SPANS + ("wave.feed.wait",):
+        assert {e["args"]["wave"] for e in by_name[name]} == set(range(8))
+    for c in by_name["wave.collect"]:
+        sub = submits[c["args"]["wave"]]
+        assert sub["tid"] != c["tid"] and sub["ts"] <= c["ts"]
+    # the counter and the spans agree on the bytes brought to the host
+    d2h = sum(e["args"]["bytes"] for e in by_name["wave.collect.d2h"])
+    assert stats.counters["d2h_bytes"] == d2h > 0
+
+
+def test_leaf_spans_cover_the_wave_run(eight_wave_trace):
+    """Spans with no child on their thread, unioned over both threads,
+    account for at least 80% of the job: little host time is unnamed."""
+    obj, _ = eight_wave_trace
+    evs = obj["traceEvents"]
+    leaves = [e for e in evs
+              if not any(o is not e and _inside(o, e) and o["dur"] < e["dur"]
+                         for o in evs)]
+    assert {"wave.collect.sort", "merge.kway.sort", "stats.unpack"} \
+        <= {e["name"] for e in leaves}
+    leaf_trace = {"traceEvents": leaves + [
+        e for e in evs if e["name"] == "wave.run"]}
+    assert obs_trace.span_coverage(leaf_trace, "wave.run") >= 0.80
+
+
 def test_monolithic_trace_has_per_round_spans():
     toks = make_corpus(1500, 40, "zipf", 1)
     cfg = NGramConfig(sigma=3, tau=2, vocab_size=40)
@@ -129,6 +207,24 @@ def test_monolithic_trace_has_per_round_spans():
             "round.materialize"} <= names
 
 
+def test_wave_program_keeps_its_module_name_and_names_its_stages():
+    """The benchmark's readers find the wave program as the XLA module
+    ``jit_wave_fn``; its stages carry their names into the op metadata."""
+    import jax
+    import jax.numpy as jnp
+    from repro.pipeline.executor import _build_wave_program
+    from repro.pipeline.plan import plan_for
+    cfg = NGramConfig(sigma=3, tau=2, vocab_size=40)
+    fn = _build_wave_program(cfg, plan_for(cfg))
+    lowered = fn.lower(jax.ShapeDtypeStruct((258,), jnp.int32),
+                       jnp.int32(256))
+    assert lowered.as_text().startswith("module @jit_wave_fn")
+    hlo = lowered.compile().as_text()
+    assert "HloModule jit_wave_fn" in hlo
+    for stage in ("emit", "sort", "reduce", "partition"):
+        assert f"/{stage}/" in hlo, stage
+
+
 # ------------------------------------------------------------ counter parity
 
 @pytest.mark.parametrize("method", sorted(METHODS))
@@ -137,8 +233,11 @@ def test_counters_parity_monolithic_vs_wave(method):
     cfg = NGramConfig(sigma=3, tau=3, vocab_size=50, method=method)
     mono = run_job(toks, cfg)
     wavy = WaveExecutor(cfg, wave_tokens=-(-len(toks) // 4)).run(toks)
-    wave_only = {"waves", "fold_rows"}
+    wave_only = {k for k, doc in obs_metrics.COUNTER_DOC.items()
+                 if doc.endswith("(wave-only)")}
+    assert wave_only == {"waves", "fold_rows", "d2h_bytes"}
     assert set(wavy.counters) - wave_only == set(mono.counters)
+    assert wave_only <= set(wavy.counters)
     # every emitted key is documented in the one canonical glossary
     for k in set(mono.counters) | set(wavy.counters):
         assert k in obs_metrics.COUNTER_DOC, f"undocumented counter {k!r}"
@@ -163,11 +262,38 @@ def test_merge_policy_sums_except_skew():
 
 # ------------------------------------------------------------ disabled == free
 
-def test_disabled_tracer_is_noop_and_sync_free(monkeypatch):
+class _CountingAnnotation:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` that records its use."""
+    made: list = []
+
+    def __init__(self, name, **kw):
+        self.name = name
+        self.entered = self.exited = 0
+        _CountingAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.exited += 1
+        return False
+
+
+@pytest.fixture
+def counting_annotations(monkeypatch):
+    import jax.profiler
+    _CountingAnnotation.made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    return _CountingAnnotation.made
+
+
+def test_disabled_tracer_is_noop_and_sync_free(monkeypatch,
+                                               counting_annotations):
     import jax
     assert obs_trace.span("anything") is obs_trace.NULL_SPAN
     sp = obs_trace.span("x")
-    assert not sp and sp.set(a=1) is None and sp.sync(object()) is None
+    assert not sp and sp.set(a=1) is None
 
     calls = {"n": 0}
     real = jax.block_until_ready
@@ -182,6 +308,75 @@ def test_disabled_tracer_is_noop_and_sync_free(monkeypatch):
     WaveExecutor(cfg, wave_tokens=300).run(toks)
     assert calls["n"] == 0, \
         "disabled observability must not add block_until_ready syncs"
+    assert counting_annotations == [], \
+        "disabled observability must not create profiler annotations"
+
+
+def test_disabled_tracer_allocates_nothing():
+    import tracemalloc
+    toks = make_corpus(1200, 40, "zipf", 2)
+    cfg = NGramConfig(sigma=3, tau=2, vocab_size=40)
+    WaveExecutor(cfg, wave_tokens=300).run(toks)      # compile first
+    tracemalloc.start()
+    try:
+        WaveExecutor(cfg, wave_tokens=300).run(toks)
+        for _ in range(1000):
+            with obs_trace.span("x") as sp:
+                if sp:
+                    sp.set(a=1)
+        snap = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    mine = snap.filter_traces(
+        [tracemalloc.Filter(True, obs_trace.__file__)])
+    assert mine.statistics("filename") == []
+
+
+def test_enabled_span_enters_one_annotation(counting_annotations):
+    toks = make_corpus(1200, 40, "zipf", 2)
+    cfg = NGramConfig(sigma=3, tau=2, vocab_size=40)
+    tracer = obs_trace.enable_tracing()
+    try:
+        WaveExecutor(cfg, wave_tokens=300).run(toks)
+    finally:
+        obs_trace.disable_tracing()
+    names = sorted(e["name"] for e in tracer.events)
+    assert len(names) > 8
+    assert sorted(a.name for a in counting_annotations) == names
+    assert all(a.entered == a.exited == 1 for a in counting_annotations)
+
+
+def test_spans_sit_in_the_profiler_trace(tmp_path):
+    """Under the JAX profiler every span is also a host event of the same
+    name in the ``.xplane.pb``, at a fixed offset from its own clock."""
+    import glob
+    import jax
+    toks = make_corpus(1200, 40, "zipf", 3)
+    cfg = NGramConfig(sigma=3, tau=2, vocab_size=40)
+    WaveExecutor(cfg, wave_tokens=300).run(toks)      # compile first
+    tracer = obs_trace.enable_tracing()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        WaveExecutor(cfg, wave_tokens=300).run(toks)
+    finally:
+        jax.profiler.stop_trace()
+        obs_trace.disable_tracing()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e["name"] for e in tracer.events}
+    ann = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    ann.setdefault(ev.name, []).append(ev.start_ns)
+    offsets = []
+    for name in names:
+        mine = sorted(e["ts"] * 1e3 + tracer._t_origin
+                      for e in tracer.events if e["name"] == name)
+        theirs = sorted(ann.get(name, []))
+        assert len(theirs) == len(mine), name
+        offsets += [a - m for a, m in zip(theirs, mine)]
+    assert max(offsets) - min(offsets) < 50e6       # ns: one clock offset
 
 
 def test_null_registry_instruments_are_noops():
