@@ -35,6 +35,7 @@ the int64 path stays on the host-side ``NGramStats``).
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 
 import jax
@@ -84,13 +85,17 @@ class IndexSegment:
     @property
     def n_rows(self) -> int:
         """Real (non-sentinel) rows; the length column is the primary sort key,
-        so one host-side searchsorted recovers the boundary.  Cached on first
-        read (segments are immutable; compaction polls row counts per ingest,
-        which would otherwise re-sync the device per poll)."""
+        so one host-side binary search recovers the boundary -- on a host
+        segment straight on the strided column view, O(log n) row reads and
+        no copy of the column.  Cached on first read (segments are
+        immutable; compaction polls row counts per ingest, which would
+        otherwise re-sync the device per poll)."""
         cached = self.__dict__.get("_n_rows")
         if cached is None:
-            lens = np.asarray(self.keys[..., 0])
-            cached = int(np.searchsorted(lens, self.sigma, side="right"))
+            lens = self.keys[..., 0]
+            if not isinstance(lens, np.ndarray):
+                lens = np.asarray(lens)
+            cached = bisect.bisect_right(lens, self.sigma)
             object.__setattr__(self, "_n_rows", cached)
         return cached
 

@@ -7,27 +7,33 @@ composition (Pibiri & Venturini's layout observation), so freshness becomes the
 classic log-structured-merge discipline instead:
 
   * :func:`merge_segments` -- k-way merge of sorted segments into one new
-    segment with duplicate grams' counts *summed*.  Three routes produce the
-    sorted run: ``"kway"`` (the default fold of the wave engine) exploits the
-    inputs' sortedness on the host -- a stable sort of the concatenated
-    big-endian row bytes is a galloping k-way merge (timsort detects the k
-    presorted runs), an order of magnitude cheaper than re-sorting blind --
-    and folds duplicate counts exactly in int64 via ``np.add.reduceat``;
-    ``"merge"`` runs the jitted pairwise merge-path (``kernels/merge_path.py``
-    Pallas kernel, or its jnp ref) over a balanced pairing tree;
-    ``"device"`` is the same merge-path tree with an automatic host-kway
-    fallback above ``DEVICE_MERGE_MAX_ROWS`` total rows (oversized tau=1
-    gram sets would thrash device memory); ``"sort"`` re-sorts the
-    concatenation through ``mapreduce.sort``.  On the device routes, run
-    boundaries come
-    from ``mapreduce.segment``'s lcp primitive and the dedup-summed count
-    fold runs through the reducer's segmented-sum path in two uint32 limbs
-    (exact below ``_MAX_DEVICE_RUN`` duplicates per gram; longer runs replay
-    on the host in int64).  Every route refuses loudly if a merged cf
-    overflows the uint32 device lanes (mirroring the continuation-mass guard
-    in ``build.py``), and all three produce bit-identical segments: the
-    output order is ascending (length | packed lanes), a pure function of
-    the row set.
+    segment with duplicate grams' counts *summed*, optionally dropping rows
+    whose summed count is under ``min_count``.  The routes:
+    ``"kway"`` exploits the inputs' sortedness on the host -- a stable sort
+    of the concatenated big-endian row bytes is a galloping k-way merge
+    (timsort detects the k presorted runs), an order of magnitude cheaper
+    than re-sorting blind -- and folds duplicate counts exactly in int64 via
+    ``np.add.reduceat``; ``"device"`` is the blocked fold on the chip: the
+    host cuts the sorted inputs into key-range blocks of at most
+    ``DEVICE_BLOCK_ROWS`` rows (a gram never straddles two; on an
+    accelerator every block is padded to that one shape, whose programs
+    :func:`load_block_programs` readies up front), and one jitted
+    program of fixed shape sorts each block, sums its duplicate counts
+    exactly in 8-bit limbs, drops rows under ``min_count`` and compacts the
+    survivors to the front, so only they come back to the host; blocks are
+    dispatched asynchronously, the next one assembled while the device
+    folds the last, and there is no size cap; ``"merge"`` runs the jitted
+    pairwise merge-path (``kernels/merge_path.py`` Pallas kernel, or its jnp
+    ref) over a balanced pairing tree; ``"sort"`` re-sorts the concatenation
+    through ``mapreduce.sort``.  On the ``"merge"`` and ``"sort"`` routes,
+    run boundaries come from ``mapreduce.segment``'s lcp primitive and the
+    dedup-summed count fold runs through the reducer's segmented-sum path in
+    two uint32 limbs.  A device fold is exact below ``_MAX_DEVICE_RUN``
+    duplicates per gram; longer runs replay on the host in int64.  Every
+    route refuses loudly if a merged cf overflows the uint32 device lanes
+    (mirroring the continuation-mass guard in ``build.py``), and all routes
+    produce bit-identical segments: the output order is ascending
+    (length | packed lanes), a pure function of the row set.
   * :func:`merge_indexes` -- segments in, finished artifact out:
     ``index_from_segment`` rebuilds fanout/continuation/cumsum structures from
     the merged rows *without re-running the job*, and re-compresses when the
@@ -45,10 +51,13 @@ classic log-structured-merge discipline instead:
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+import threading
+from concurrent.futures import Future, ThreadPoolExecutor
+from functools import partial, reduce
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 from repro.core.stats import NGramStats
@@ -76,7 +85,7 @@ def _merged_run(segs: list[IndexSegment], *, route: str,
         keys = jnp.concatenate([s.keys for s in segs], axis=0)
         counts = jnp.concatenate([s.counts for s in segs], axis=0)
         keys, (counts,) = mr_sort.sort_with_payload(keys, [counts])
-    elif route in ("merge", "device"):
+    elif route == "merge":
         if use_kernels:
             from repro.kernels import ops as kops
             merge2 = kops.merge_path
@@ -105,17 +114,20 @@ def _merged_run(segs: list[IndexSegment], *, route: str,
     return jnp.asarray(keys, jnp.uint32), jnp.asarray(counts, jnp.uint32)
 
 
-# Two-limb uint32 segment sums stay exact while every run is shorter than
-# this; a merge of k segments with distinct rows each has runs of length <= k,
-# so the device fold covers everything but adversarial duplicate floods.
+# The device folds' limbed uint32 segment sums stay exact while every run is
+# shorter than this; a merge of k segments with distinct rows each has runs
+# of length <= k, so the device folds cover everything but adversarial
+# duplicate floods.
 _MAX_DEVICE_RUN = 1 << 16
 
-# the "device" route's size ceiling: above this many total input rows
-# (sentinel pads included -- that is what the merge tree actually moves) the
-# fold falls back to the host k-way path, which streams in numpy instead of
-# holding every intermediate merge run in device memory.  Oversized tau=1
-# gram sets (huge corpora at tiny tau) are exactly the shape that trips this.
-DEVICE_MERGE_MAX_ROWS = 1 << 22
+# Rows per block of the "device" route's blocked fold on an accelerator,
+# whatever the input's size, so every fold there runs one program shape; at
+# most 2**24, so the 8-bit count limbs' prefix sums over a block stay below
+# 2**32.  Two blocks in flight hold about 2 GB of a v5e's HBM at 2**24 rows
+# of six lanes.
+DEVICE_BLOCK_ROWS = 1 << 24
+# survivors come back in chunks of this many rows through one slice program
+_SURVIVOR_CHUNK = 1 << 20
 
 
 def _run_starts(sorted_bytes: np.ndarray) -> np.ndarray:
@@ -291,26 +303,266 @@ def _fold_runs_host(keys: np.ndarray, counts: np.ndarray, *,
     return r_keys, r_tot.astype(np.uint32)
 
 
+@jax.jit
+def _merge_block(flat_keys: jax.Array, counts: jax.Array,
+                 min_count: jax.Array):
+    """Fold one key-range block of the ``"device"`` route on the chip.
+
+    ``flat_keys`` [B * C] (the row-major key rows, flat, so the host-to-device
+    copy needs no relayout on the host) and ``counts`` [B] hold the block's
+    rows from every input segment, sentinel-padded with count 0.  The lanes
+    are split out on the device by strided slices of 128-row groups, which
+    never pads a [B, C] array out to the chip's 128-wide tiles.  Sorts the
+    rows in segment order,
+    sums each gram's duplicate counts exactly, drops sentinels and grams
+    whose sum is under ``min_count``, and compacts the survivors, in
+    order, to the front.  The sums run in four 8-bit limbs, whose prefix
+    sums stay below 2**32 over B <= 2**24 rows: a run's limb sum is its
+    end's prefix less the prefix before its start, carried forward by a
+    running max, so no gather or scatter is needed.  Returns
+    (keys [B, C], totals [B], n_keep, overflow?, max_run_len); the limbs are
+    exact while runs stay under ``_MAX_DEVICE_RUN`` rows.  The module is
+    named ``jit__merge_block`` in device traces.
+    """
+    n = counts.shape[0]
+    n_cols = flat_keys.shape[0] // n
+    group = min(128, n)
+    rows = flat_keys.reshape(n // group, group * n_cols)
+    lanes = [rows[:, j::n_cols].reshape(n) for j in range(n_cols)]
+    *lanes, counts = lax.sort(lanes + [counts], num_keys=n_cols,
+                              is_stable=False)
+    differs = reduce(jnp.logical_or, [l[1:] != l[:-1] for l in lanes])
+    first = jnp.ones((1,), bool)
+    new_run = jnp.concatenate([first, differs])
+    run_end = jnp.concatenate([differs, first])
+    idx = jnp.arange(n, dtype=jnp.int32)
+    run_len = idx + 1 - lax.cummax(jnp.where(new_run, idx, 0))
+    limbs = []
+    for b in range(4):
+        x = (counts >> (8 * b)) & jnp.uint32(0xFF)
+        c = jnp.cumsum(x, dtype=jnp.uint32)
+        limbs.append(c - lax.cummax(jnp.where(new_run, c - x, 0)))
+    lo = limbs[0] + (limbs[1] << 8)
+    hi = limbs[2] + (limbs[3] << 8) + (lo >> 16)
+    totals = (hi << 16) | (lo & jnp.uint32(0xFFFF))
+    real = run_end & (lanes[0] != SENTINEL)
+    keep = real & (totals >= min_count)
+    out = lax.sort([jnp.where(keep, idx, n)] + lanes + [totals],
+                   num_keys=1, is_stable=False)
+    return (jnp.stack(out[1:-1], axis=1), out[-1],
+            jnp.sum(keep, dtype=jnp.int32), jnp.any(real & (hi > 0xFFFF)),
+            jnp.max(jnp.where(real, run_len, 0)))
+
+
+@partial(jax.jit, static_argnames=("size",))
+def _survivor_chunk(keys: jax.Array, totals: jax.Array, start: jax.Array, *,
+                    size: int):
+    """``size`` rows of a folded block from ``start``: one program for
+    every chunk, whatever a block keeps."""
+    return (lax.dynamic_slice_in_dim(keys, start, size),
+            lax.dynamic_slice_in_dim(totals, start, size))
+
+
+def _lower_bound(keys: np.ndarray, lo: int, hi: int, key: tuple) -> int:
+    """First row of sorted ``keys[lo:hi]`` not below ``key``: a binary
+    search of O(log n) row comparisons, with no byte view of the rows."""
+    while lo < hi:
+        mid = (lo + hi) // 2
+        if tuple(keys[mid].tolist()) < key:
+            lo = mid + 1
+        else:
+            hi = mid
+    return lo
+
+
+def _block_cuts(views: list[np.ndarray], rows: int) -> list[list[tuple]]:
+    """Cut sorted unique key arrays into key-range blocks of <= ``rows`` rows.
+
+    Splitters are rows of the largest input at evenly spaced ranks; every
+    input is cut at each splitter's lower bound, so all copies of a gram
+    land in one block (``rows`` >= the number of inputs then guarantees
+    progress).  A block still over ``rows`` is cut again at the middle row
+    of its largest piece.  Returns, per block, one (lo, hi) row range per
+    input, in key order.
+    """
+    total = sum(len(v) for v in views)
+    big = views[int(np.argmax([len(v) for v in views]))]
+    n_blocks = -(-total // (rows - rows // 16))     # aim 15/16 full
+    cuts = [[0] * len(views)]
+    for j in range(1, n_blocks):
+        key = tuple(big[j * len(big) // n_blocks].tolist())
+        cuts.append([_lower_bound(v, c, len(v), key)
+                     for v, c in zip(views, cuts[-1])])
+    cuts.append([len(v) for v in views])
+    todo = [list(zip(a, b)) for a, b in zip(cuts, cuts[1:])][::-1]
+    blocks = []
+    while todo:
+        blk = todo.pop()
+        n = sum(hi - lo for lo, hi in blk)
+        if n <= rows:
+            if n:
+                blocks.append(blk)
+            continue
+        p = int(np.argmax([hi - lo for lo, hi in blk]))
+        key = tuple(views[p][(blk[p][0] + blk[p][1]) // 2].tolist())
+        mid = [_lower_bound(v, lo, hi, key) for v, (lo, hi) in zip(views, blk)]
+        todo.append([(m, hi) for m, (_, hi) in zip(mid, blk)])
+        todo.append([(lo, m) for m, (lo, _) in zip(mid, blk)])
+    return blocks
+
+
+def _block_rows(total: int, k: int) -> int:
+    """Rows of every block of a fold of ``total`` rows from ``k`` inputs.
+
+    On an accelerator always ``DEVICE_BLOCK_ROWS``: one compiled program
+    serves every fold, which :func:`load_block_programs` readies up front.
+    The CPU backend sizes the block to the input instead (the next power of
+    two >= ``total`` and >= ``k``, at most ``DEVICE_BLOCK_ROWS``), so small
+    folds stay small there.
+    """
+    if jax.default_backend() != "cpu":
+        return DEVICE_BLOCK_ROWS
+    return max(min(DEVICE_BLOCK_ROWS, 1 << (total - 1).bit_length()),
+               1 << (k - 1).bit_length())
+
+
+def _compile_block_programs(rows: int, n_cols: int) -> None:
+    u32 = partial(jax.ShapeDtypeStruct, dtype=jnp.uint32)
+    _merge_block.lower(u32((rows * n_cols,)), u32((rows,)), u32(())).compile()
+    _survivor_chunk.lower(u32((rows, n_cols)), u32((rows,)),
+                          jax.ShapeDtypeStruct((), jnp.int32),
+                          size=min(_SURVIVOR_CHUNK, rows)).compile()
+
+
+_block_loads: dict[tuple[int, int], Future] = {}
+_block_loads_lock = threading.Lock()
+_block_loader = ThreadPoolExecutor(1, thread_name_prefix="block-programs")
+
+
+def load_block_programs(n_cols: int) -> Future | None:
+    """Compile, or load from the compile cache, the accelerator's block
+    programs for keys of ``n_cols`` columns, once a process, on a side
+    thread: no data moves and nothing runs on the device.  Returns the
+    load's future (its ``result()`` raises what the compile raised), or
+    ``None`` on the CPU backend, whose block shape follows each input.
+    Later folds of that width reuse the compiled programs.
+    """
+    if jax.default_backend() == "cpu":
+        return None
+    key = (DEVICE_BLOCK_ROWS, n_cols)
+    with _block_loads_lock:
+        if key not in _block_loads:
+            _block_loads[key] = _block_loader.submit(
+                _compile_block_programs, *key)
+        return _block_loads[key]
+
+
+def _fold_blocks_device(segs: list[IndexSegment], *, min_count: int | None):
+    """The ``"device"`` route: blocked fold on the chip, survivors only back.
+
+    Returns host (keys, uint32 totals, blocks run).  Each block's pieces are
+    copied into one of two reused host buffers, sentinel-filled past the
+    rows; block i + 1 is assembled and copied to the device while block i
+    folds, and block i's survivors are sliced before block i + 1 is queued
+    behind it.  A buffer is refilled only after the block that last used it
+    has finished.  Span ``merge.device.block`` (rows, kept) runs from the
+    block's dispatch until its survivors are on the host.
+    """
+    sigma, vocab = segs[0].sigma, segs[0].vocab_size
+    views = [np.asarray(s.keys, np.uint32)[:s.n_rows] for s in segs]
+    cnts = [np.asarray(s.counts, np.uint32)[:s.n_rows] for s in segs]
+    n_cols = views[0].shape[1]
+    total = sum(len(v) for v in views)
+    if not total:
+        return np.zeros((0, n_cols), np.uint32), np.zeros((0,), np.uint32), 0
+    rows = _block_rows(total, len(segs))
+    if rows > 1 << 24:
+        raise ValueError(f"device blocks of {rows} rows overflow the 8-bit "
+                         "count limbs' prefix sums (at most 2**24 rows)")
+    blocks = _block_cuts(views, rows)
+    bufs = [(np.empty((rows, n_cols), np.uint32), np.empty((rows,), np.uint32))
+            for _ in range(min(2, len(blocks)))]
+    thr = np.uint32(min(max(min_count or 0, 0), _U32_MAX))
+    chunk = min(_SURVIVOR_CHUNK, rows)
+
+    def stage(i):
+        k_buf, c_buf = bufs[i % 2]
+        off = 0
+        for (lo, hi), v, c in zip(blocks[i], views, cnts):
+            k_buf[off:off + hi - lo] = v[lo:hi]
+            c_buf[off:off + hi - lo] = c[lo:hi]
+            off += hi - lo
+        k_buf[off:] = SENTINEL
+        c_buf[off:] = 0
+        return jnp.asarray(k_buf.reshape(-1)), jnp.asarray(c_buf)
+
+    out_keys, out_tot = [], []
+    running = _merge_block(*stage(0), thr)
+    for i, blk in enumerate(blocks):
+        with obs_trace.span("merge.device.block") as sp:
+            staged = stage(i + 1) if i + 1 < len(blocks) else None
+            b_keys, b_tot, n_keep, overflow, max_run = running
+            n_keep = int(n_keep)
+            bad = bool(overflow) or int(max_run) >= _MAX_DEVICE_RUN
+            if not bad:
+                parts = [_survivor_chunk(b_keys, b_tot, np.int32(s), size=chunk)
+                         for s in range(0, n_keep, chunk)]
+            running = _merge_block(*staged, thr) if staged else None
+            if bad:
+                # a run past the limbs or a total past uint32: replay the
+                # block on the host in int64 (its overflow guard raises)
+                k, t = _kway_fold_host(
+                    [IndexSegment(keys=v[lo:hi], counts=c[lo:hi], sigma=sigma,
+                                  vocab_size=vocab)
+                     for (lo, hi), v, c in zip(blk, views, cnts)], sigma=sigma)
+                keep = t >= thr
+                k, t = k[keep], t[keep]
+            else:
+                k = np.concatenate([np.zeros((0, n_cols), np.uint32)] + [
+                    np.asarray(pk) for pk, _ in parts])[:n_keep]
+                t = np.concatenate([np.zeros((0,), np.uint32)] + [
+                    np.asarray(pt) for _, pt in parts])[:n_keep]
+            out_keys.append(k)
+            out_tot.append(t)
+            if sp:
+                sp.set(rows=sum(hi - lo for lo, hi in blk), kept=len(t))
+    return np.concatenate(out_keys), np.concatenate(out_tot), len(blocks)
+
+
 def merge_segments(segments, *, route: str = "merge", use_kernels: bool = False,
-                   pad_to: int | None = None,
+                   pad_to: int | None = None, min_count: int | None = None,
                    n_compressed: int | None = None) -> IndexSegment:
     """Merge sorted segments into one, summing counts of duplicate grams.
 
     ``route="kway"`` folds on the host exploiting the inputs' sortedness
     (stable sort of concatenated big-endian row bytes == galloping k-way
-    merge; int64 ``reduceat`` count fold); ``route="merge"`` runs the jitted
-    pairwise merge-path (Pallas kernel when ``use_kernels``, jnp ref
-    otherwise) over a balanced pairing tree; ``route="device"`` is the
-    merge-path tree as the wave fold's on-device k-way sort, falling back to
-    the host kway fold when the inputs exceed ``DEVICE_MERGE_MAX_ROWS``
-    total rows; ``route="sort"`` re-sorts the concatenation (the
+    merge; int64 ``reduceat`` count fold); ``route="device"`` is the
+    blocked fold on the chip (key-range blocks of ``DEVICE_BLOCK_ROWS``
+    rows on an accelerator, survivors only back to the host, no size
+    cap); ``route="merge"`` runs the jitted pairwise merge-path (Pallas
+    kernel when ``use_kernels``, jnp ref otherwise) over a balanced pairing
+    tree; ``route="sort"`` re-sorts the concatenation (the
     ``mapreduce.sort`` fallback).  All routes are bit-identical.  Raises
     ``ValueError`` if any merged count overflows the uint32 device lanes.
+
+    ``min_count`` drops merged rows whose summed count is below it before
+    the result is padded: on the ``"device"`` route before they leave the
+    chip.  ``None`` keeps every row.  ``"kway"`` and ``"device"`` return a
+    host-resident segment, the other routes a device-resident one.
 
     ``n_compressed`` is purely observational: callers that decoded some
     inputs from the compressed layout record the flat/compressed mix on the
     ``merge.segments`` span.
     """
+    return _merge(segments, route=route, use_kernels=use_kernels,
+                  pad_to=pad_to, min_count=min_count,
+                  n_compressed=n_compressed)[0]
+
+
+def _merge(segments, *, route: str, use_kernels: bool = False,
+           pad_to: int | None = None, min_count: int | None = None,
+           n_compressed: int | None = None) -> tuple[IndexSegment, int]:
+    """:func:`merge_segments`, and the number of device blocks it ran."""
     segs = list(segments)
     if not segs:
         raise ValueError("cannot merge zero segments")
@@ -320,30 +572,25 @@ def merge_segments(segments, *, route: str = "merge", use_kernels: bool = False,
             raise ValueError(
                 f"segment meta mismatch: ({s.sigma}, {s.vocab_size}) vs "
                 f"({sigma}, {vocab})")
-    sp = obs_trace.span("merge.segments")
-    if sp:
-        sp.set(n_segments=len(segs),
-               rows_in=sum(int(s.keys.shape[0]) for s in segs))
-        if n_compressed is not None:
-            sp.set(n_compressed=n_compressed,
-                   n_flat=len(segs) - n_compressed)
-    sp.__enter__()
-    try:
+    with obs_trace.span("merge.segments") as sp:
+        if sp:
+            sp.set(n_segments=len(segs),
+                   rows_in=sum(int(s.keys.shape[0]) for s in segs))
+            if n_compressed is not None:
+                sp.set(n_compressed=n_compressed,
+                       n_flat=len(segs) - n_compressed)
         return _merge_segments_body(segs, sigma, vocab, route=route,
-                                    use_kernels=use_kernels, pad_to=pad_to)
-    finally:
-        sp.__exit__(None, None, None)
+                                    use_kernels=use_kernels, pad_to=pad_to,
+                                    min_count=min_count)
 
 
-def _merge_segments_body(segs, sigma, vocab, *, route, use_kernels, pad_to):
-    host = route == "kway"
-    if route == "device" and sum(
-            int(s.keys.shape[0]) for s in segs) > DEVICE_MERGE_MAX_ROWS:
-        # oversized tau=1 gram set: the device tree would hold O(total) rows
-        # per merge level -- take the streaming host fold instead
-        host = True
-    if host:
+def _merge_segments_body(segs, sigma, vocab, *, route, use_kernels, pad_to,
+                         min_count):
+    blocks = 0
+    if route == "kway":
         r_keys, r_tot = _kway_fold_host(segs, sigma=sigma)
+    elif route == "device":
+        r_keys, r_tot, blocks = _fold_blocks_device(segs, min_count=min_count)
     else:
         keys, counts = _merged_run(segs, route=route, use_kernels=use_kernels)
 
@@ -363,6 +610,10 @@ def _merge_segments_body(segs, sigma, vocab, *, route, use_kernels, pad_to):
         else:
             r_keys = np.asarray(out_keys[:n_runs], np.uint32)
             r_tot = np.asarray(out_counts[:n_runs], np.uint32)
+    if min_count is not None and route != "device":   # the chip filtered
+        with obs_trace.span("merge.filter"):
+            keep = r_tot >= min_count
+            r_keys, r_tot = r_keys[keep], r_tot[keep]
     r = int(r_keys.shape[0])
     size = pad_to if pad_to is not None else round_capacity(r)
     if size < r + 1:
@@ -370,14 +621,14 @@ def _merge_segments_body(segs, sigma, vocab, *, route, use_kernels, pad_to):
     with obs_trace.span("merge.pad"):
         keys_p = pad_rows(r_keys, size, SENTINEL)
         cnts_p = pad_rows(r_tot, size, 0)
-    if not host:
-        # device routes hand device arrays back; the host folds stay
-        # host-resident end to end -- an LSM cascade of kway merges would
-        # otherwise pay an h2d/d2h round trip per compaction for data the
-        # next merge reads right back on the host
+    if route not in ("kway", "device"):
+        # the tree and re-sort routes hand device arrays back; the host and
+        # blocked folds stay host-resident end to end -- an LSM cascade of
+        # kway merges would otherwise pay an h2d/d2h round trip per
+        # compaction for data the next merge reads right back on the host
         keys_p, cnts_p = jnp.asarray(keys_p), jnp.asarray(cnts_p)
     return IndexSegment(keys=keys_p, counts=cnts_p, sigma=sigma,
-                        vocab_size=vocab)
+                        vocab_size=vocab), blocks
 
 
 def _merge_input_segment(entry, *, route: str) -> IndexSegment:
@@ -387,11 +638,13 @@ def _merge_input_segment(entry, *, route: str) -> IndexSegment:
     field read); compressed entries stream-decode block chunks through
     :func:`~repro.index.compress.decode_segment` -- O(chunk) peak decoded
     working set, never a whole decoded table.  The host ``"kway"`` route (the
-    LSM default) takes the unpadded host segment straight in; device routes
+    LSM default) and the blocked ``"device"`` fold, which cuts host rows,
+    take the unpadded host segment straight in; the tree and re-sort routes
     get the capacity-padded device form their search kernels expect.
     """
     if isinstance(entry, CompressedNGramIndex):
-        return decode_segment(entry) if route == "kway" else entry.to_segment()
+        return (decode_segment(entry) if route in ("kway", "device")
+                else entry.to_segment())
     return entry if isinstance(entry, IndexSegment) else entry.to_segment()
 
 
@@ -539,7 +792,8 @@ class TieredSegmentAccumulator:
     bit-identical to the pairwise fold's.
 
     ``fold_rows`` counts every input row fed through :func:`merge_segments`
-    -- the measured merge work the benchmarks compare across strategies.
+    -- the measured merge work the benchmarks compare across strategies --
+    and ``finalize_blocks`` the blocks the ``"device"`` route folded.
     """
 
     def __init__(self, *, size_ratio: int = DEFAULT_SIZE_RATIO,
@@ -551,12 +805,14 @@ class TieredSegmentAccumulator:
         self.use_kernels = use_kernels
         self.rungs: list[tuple[IndexSegment, int]] = []   # newest first
         self.fold_rows = 0
+        self.finalize_blocks = 0
 
     def _merge_front(self, n: int) -> None:
         segs = [s for s, _ in reversed(self.rungs[:n])]   # elder first
         self.fold_rows += sum(r for _, r in self.rungs[:n])
-        merged = merge_segments(segs, route=self.route,
+        merged, blocks = _merge(segs, route=self.route,
                                 use_kernels=self.use_kernels)
+        self.finalize_blocks += blocks
         self.rungs[:n] = [(merged, merged.n_rows)]
 
     def push(self, seg: IndexSegment, *, n_rows: int | None = None) -> None:
@@ -588,7 +844,8 @@ class DeferredSegmentAccumulator:
     need intermediate merged state at all: only ``result`` is ever read.
     Deferring makes the total fold work exactly *one* k-way merge over the
     raw wave partials (O(total) rows through :func:`merge_segments`, which
-    the ``"kway"`` route turns into a single galloping host merge).
+    the ``"kway"`` route turns into a single galloping host merge and the
+    ``"device"`` route into fixed-shape blocks folded on the chip).
 
     Memory: all wave partials stay live until ``result`` -- O(total tau=1
     rows), the same order as the merged segment every accumulator must
@@ -606,21 +863,32 @@ class DeferredSegmentAccumulator:
         self.segs: list[IndexSegment] = []
         self._rows: list[int] = []
         self.fold_rows = 0
+        self.finalize_blocks = 0
 
     def push(self, seg: IndexSegment, *, n_rows: int | None = None) -> None:
         self.segs.append(seg)
         self._rows.append(seg.n_rows if n_rows is None else n_rows)
 
-    def result(self) -> IndexSegment:
+    def result(self, *, min_count: int | None = None) -> IndexSegment:
+        """The one k-way fold of every pushed segment.
+
+        ``min_count`` drops merged rows under it inside the merge (on the
+        ``"device"`` route, before they leave the chip); such a filtered
+        result is not kept as the accumulator's state.  A lone segment comes
+        back as it was pushed, unfiltered.
+        """
         if not self.segs:
             raise ValueError("no segments accumulated")
         if len(self.segs) == 1:
             return self.segs[0]
         self.fold_rows += sum(self._rows)
-        merged = merge_segments(self.segs, route=self.route,
-                                use_kernels=self.use_kernels)
-        self.segs = [merged]
-        self._rows = [merged.n_rows]
+        merged, blocks = _merge(self.segs, route=self.route,
+                                use_kernels=self.use_kernels,
+                                min_count=min_count)
+        self.finalize_blocks += blocks
+        if min_count is None:
+            self.segs = [merged]
+            self._rows = [merged.n_rows]
         return merged
 
 
@@ -639,6 +907,7 @@ class PairwiseSegmentAccumulator:
         self._seg: IndexSegment | None = None
         self._rows = 0
         self.fold_rows = 0
+        self.finalize_blocks = 0
 
     def push(self, seg: IndexSegment, *, n_rows: int | None = None) -> None:
         rows = seg.n_rows if n_rows is None else n_rows
@@ -646,8 +915,9 @@ class PairwiseSegmentAccumulator:
             self._seg, self._rows = seg, rows
             return
         self.fold_rows += self._rows + rows
-        self._seg = merge_segments([self._seg, seg], route=self.route,
+        self._seg, blocks = _merge([self._seg, seg], route=self.route,
                                    use_kernels=self.use_kernels)
+        self.finalize_blocks += blocks
         self._rows = self._seg.n_rows
 
     def result(self) -> IndexSegment:

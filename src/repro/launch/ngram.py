@@ -41,13 +41,14 @@ def main() -> None:
                          "merge rows, the default); tiered = size-tiered LSM "
                          "rungs (bounded live memory, amortized O(total log "
                          "waves)); pairwise = the one-segment baseline")
-    ap.add_argument("--merge-route", default="kway",
+    ap.add_argument("--merge-route", default=None,
                     choices=["kway", "merge", "sort", "device"],
-                    help="segment-fold sort route: kway = galloping host "
-                         "merge (default); merge = balanced-tree pairwise "
-                         "merge-path; device = merge-path tree on device "
-                         "with host-kway fallback for oversized tau=1 gram "
-                         "sets; sort = fused re-sort")
+                    help="segment-fold route (default: device for the "
+                         "defer accumulator on an accelerator, else kway): "
+                         "device = blocked merge, fold and tau filter on "
+                         "the chip; kway = galloping host merge; merge = "
+                         "balanced-tree pairwise merge-path; sort = fused "
+                         "re-sort")
     ap.add_argument("--no-overlap", action="store_true",
                     help="serialize the per-wave fold with wave dispatch "
                          "instead of overlapping it on the fold thread "

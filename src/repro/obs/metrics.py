@@ -61,6 +61,9 @@ COUNTER_DOC: dict[str, str] = {
     "waves": "token waves executed (wave-only)",
     "fold_rows": "segment rows fed through merge_segments by the wave "
                  "accumulator -- the measured fold work (wave-only)",
+    "finalize_blocks": "key-range blocks the wave accumulator's merges ran "
+                       "through the device fold program (merge_route "
+                       "\"device\"); 0 on the host routes (wave-only)",
     "d2h_bytes": "bytes materialized device to host when the waves are "
                  "collected: the reducer outputs, key lanes, skew histogram "
                  "and counter scalars (wave-only)",

@@ -428,14 +428,21 @@ class WaveExecutor:
     size-tiered LSM rung stack, amortized O(total log waves) merge work with
     log-many live rungs; ``"pairwise"`` = the legacy
     fold-every-wave-into-one-segment baseline, O(waves x total));
-    ``merge_route``: ``"kway"`` = galloping host merge of the presorted
-    segments; ``"sort"`` = one fused re-sort per fold; ``"merge"`` =
-    balanced-tree pairwise merge-path; ``"device"`` = the merge-path tree
-    as an on-device k-way sort, with the host kway fold as automatic
-    fallback for oversized tau=1 gram sets
-    (``index.merge.DEVICE_MERGE_MAX_ROWS``).  :meth:`run` applies the
-    global tau
-    once at the end, so for any wave size (and any accumulator/route) the
+    ``merge_route``: ``"device"`` = the blocked fold on the chip: the
+    sorted wave segments are cut into fixed-shape key-range blocks that one
+    jitted program merges, dedup-folds and tau-filters, so only rows with
+    cf >= tau come back to the host; ``"kway"`` = galloping host merge of
+    the presorted segments; ``"sort"`` = one fused re-sort per fold;
+    ``"merge"`` = balanced-tree pairwise merge-path.  ``None`` (the
+    default) picks ``"device"`` for the ``"defer"`` accumulator where the
+    default backend is an accelerator, and ``"kway"`` otherwise: on the CPU
+    backend, and for the tiered and pairwise accumulators, whose per-wave
+    merges must bring every row back.  On the ``"device"`` route the first
+    run readies the one block program (``index.merge.load_block_programs``)
+    beside its waves and returns only when it is ready, so no later run
+    compiles it, even if this one folded nothing.  :meth:`run` applies the
+    global tau once at the end (pushed into the ``"defer"`` fold as its
+    ``min_count``), so for any wave size (and any accumulator/route) the
     output is bit-identical to the monolithic job.
 
     With a ``mesh`` (size > 1), each wave runs as ONE fused ``shard_map``
@@ -458,7 +465,7 @@ class WaveExecutor:
     """
 
     def __init__(self, cfg, *, wave_tokens: int | None = None,
-                 plan: JobPlan | None = None, merge_route: str = "kway",
+                 plan: JobPlan | None = None, merge_route: str | None = None,
                  accumulator: str = "defer", mesh=None,
                  axis_name: str = "data", overlap: bool = True):
         if wave_tokens is not None and wave_tokens < 1:
@@ -473,7 +480,12 @@ class WaveExecutor:
         self.cfg = cfg
         self.wave_tokens = wave_tokens
         self.plan = plan or plan_for(cfg)
-        self.merge_route = merge_route
+        # the chip folds the deferred merge in blocks on the device, which is
+        # idle by then; the CPU backend's "device" would only be slower host
+        # code
+        self.merge_route = merge_route or (
+            "device" if accumulator == "defer"
+            and jax.default_backend() != "cpu" else "kway")
         self.accumulator = accumulator
         self.mesh = mesh
         self.axis_name = axis_name
@@ -1154,7 +1166,7 @@ class WaveExecutor:
         from repro.index.merge import (DeferredSegmentAccumulator,
                                        PairwiseSegmentAccumulator,
                                        TieredSegmentAccumulator,
-                                       segment_to_stats)
+                                       load_block_programs, segment_to_stats)
 
         with obs_trace.span("wave.run") as root:
             tokens = np.asarray(tokens, np.int32)
@@ -1164,17 +1176,21 @@ class WaveExecutor:
                          accumulator=self.accumulator)
             # full canonical counter set (obs.metrics.COUNTER_DOC): identical
             # keys to the monolithic run_plan, plus the wave-only
-            # waves/fold_rows/d2h_bytes
+            # waves/fold_rows/finalize_blocks/d2h_bytes
             counters = dict.fromkeys(
                 ("jobs", "map_records", "shuffle_records", "shuffle_bytes",
-                 "retries", "overflow", "waves", "fold_rows", "d2h_bytes"),
-                0)
+                 "retries", "overflow", "waves", "fold_rows",
+                 "finalize_blocks", "d2h_bytes"), 0)
             counters["shuffle_skew"] = 0.0
             acc_cls = {"defer": DeferredSegmentAccumulator,
                        "tiered": TieredSegmentAccumulator,
                        "pairwise": PairwiseSegmentAccumulator}[self.accumulator]
             acc = acc_cls(route=self.merge_route,
                           use_kernels=self.cfg.use_kernels)
+            loading = None
+            if self.merge_route == "device":
+                loading = load_block_programs(
+                    1 + packing.n_lanes(self.cfg.sigma, self.cfg.vocab_size))
 
             def fold(part: WavePartial):
                 # runs on the fold thread: overlaps the next wave's dispatch
@@ -1188,10 +1204,16 @@ class WaveExecutor:
             self._for_each_wave(tokens, fold,
                                 collect=self._collect_wave_segment)
             with obs_trace.span("wave.finalize") as sp:
-                # tau filters inside segment_to_stats, *before* the term
-                # unpack, so only the monolithic-sized survivor set pays it
-                out = segment_to_stats(acc.result(), min_count=self.cfg.tau)
+                if loading is not None:
+                    loading.result()
+                # tau filters inside the deferred fold (on the "device" route
+                # before rows leave the chip) and again, idempotently, before
+                # the term unpack, so only the survivor set pays the unpack
+                merged = (acc.result(min_count=self.cfg.tau)
+                          if self.accumulator == "defer" else acc.result())
+                out = segment_to_stats(merged, min_count=self.cfg.tau)
                 counters["fold_rows"] = acc.fold_rows
+                counters["finalize_blocks"] = acc.finalize_blocks
                 out = NGramStats(out.grams, out.lengths, out.counts,
                                  obs_metrics.normalize_counters(counters))
                 if sp:

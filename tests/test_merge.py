@@ -89,7 +89,7 @@ def test_kway_merge_and_edge_segments():
     ixs = [build_index(s, vocab_size=vocab) for s in stats]
     ixs.append(build_index(empty, vocab_size=vocab))
     want = build_index(stats_union(*stats), vocab_size=vocab)
-    for kw in (dict(route="merge"), dict(route="sort")):
+    for kw in (dict(route="merge"), dict(route="sort"), dict(route="device")):
         assert_trees_equal(merge_indexes(ixs, **kw), want)
 
 
@@ -120,7 +120,7 @@ def test_merged_count_overflow_guard_trips():
                             np.array([1], np.int32),
                             np.array([big], np.int64))
     segs = [segment_from_stats(mk(), vocab_size=9) for _ in range(2)]
-    for kw in (dict(route="merge"), dict(route="sort")):
+    for kw in (dict(route="merge"), dict(route="sort"), dict(route="device")):
         with pytest.raises(ValueError, match="overflow"):
             merge_segments(segs, **kw)
     # just-below-the-edge sums must still merge exactly
@@ -130,43 +130,74 @@ def test_merged_count_overflow_guard_trips():
     assert np.asarray(seg.counts)[0] == np.uint32(big + 10)
 
 
-def test_device_fold_host_fallback_parity(monkeypatch):
-    """Runs longer than the two-limb device budget must replay on the host
+@pytest.mark.parametrize("route", ["merge", "device"])
+def test_device_fold_host_fallback_parity(monkeypatch, route):
+    """Runs longer than the limbed device budget must replay on the host
     with identical output: force the fallback by shrinking the threshold and
-    compare whole segments against the device fold."""
+    compare whole segments against the device fold (the merge-path tree's,
+    and every block's of the blocked fold)."""
     from repro.index import merge as merge_mod
 
     sa, sb = job_pair(40, "zipf", 4, 2, seed=3, n=1500)
     segs = [segment_from_stats(s, vocab_size=40) for s in (sa, sb)]
-    want = merge_segments(segs)                        # device fold
+    want = merge_segments(segs, route=route)           # device fold
     monkeypatch.setattr(merge_mod, "_MAX_DEVICE_RUN", 1)
-    got = merge_segments(segs)                         # host replay
+    got = merge_segments(segs, route=route)            # host replay
     np.testing.assert_array_equal(np.asarray(got.keys), np.asarray(want.keys))
     np.testing.assert_array_equal(np.asarray(got.counts),
                                   np.asarray(want.counts))
 
 
-def test_device_merge_route_oversized_falls_back_to_kway(monkeypatch):
-    """The ``device`` route's size guard: above ``DEVICE_MERGE_MAX_ROWS``
-    total input rows the fold must silently reroute to the galloping host
-    k-way merge with identical output (the oversized tau=1 gram-set case the
-    mesh wave accumulator hits)."""
+def test_device_merge_route_many_blocks(monkeypatch):
+    """The ``device`` route's blocked fold: with ``DEVICE_BLOCK_ROWS`` shrunk,
+    a merge spans many blocks, one of them cut again (a segment dense in one
+    key range overfills the block its splitters gave it), every copy of a
+    gram lands in one block, and the result equals the host k-way fold at
+    every ``min_count``; the accumulator counts the blocks it ran."""
     from repro.index import merge as merge_mod
+    from repro.index.merge import DeferredSegmentAccumulator
 
     vocab = 30
     cfg = NGramConfig(sigma=3, tau=1, vocab_size=vocab)
     stats = [run_job(make_corpus(900, vocab, "zipf", s), cfg)
              for s in range(3)]
     segs = [segment_from_stats(s, vocab_size=vocab) for s in stats]
-    want = merge_segments(segs, route="kway")
-    on_device = merge_segments(segs, route="device")
-    monkeypatch.setattr(merge_mod, "DEVICE_MERGE_MAX_ROWS", 1)
-    fell_back = merge_segments(segs, route="device")
-    for got in (on_device, fell_back):
+    # the grams of the densest length-1 range, repeated in a fourth segment
+    # with counts of its own: its rows all fall into the first blocks
+    dense = NGramStats(stats[0].grams[stats[0].lengths == 1],
+                       stats[0].lengths[stats[0].lengths == 1],
+                       stats[0].counts[stats[0].lengths == 1] + 7)
+    segs.append(segment_from_stats(dense, vocab_size=vocab))
+    rows = 16
+    monkeypatch.setattr(merge_mod, "DEVICE_BLOCK_ROWS", rows)
+    views = [np.asarray(s.keys)[:s.n_rows] for s in segs]
+    blocks = merge_mod._block_cuts(views, rows)
+    total = sum(len(v) for v in views)
+    assert len(blocks) > -(-total // (rows - rows // 16))   # some cut again
+    seen, last = 0, None
+    for blk in blocks:
+        n = sum(hi - lo for lo, hi in blk)
+        assert 0 < n <= rows
+        seen += n
+        keys = [tuple(r) for v, (lo, hi) in zip(views, blk)
+                for r in v[lo:hi].tolist()]
+        # blocks are disjoint key ranges in order: a gram never straddles
+        assert last is None or min(keys) > last
+        last = max(keys)
+    assert seen == total
+    for min_count in (None, 2, 10):
+        want = merge_segments(segs, route="kway", min_count=min_count)
+        got = merge_segments(segs, route="device", min_count=min_count)
         np.testing.assert_array_equal(np.asarray(got.keys),
                                       np.asarray(want.keys))
         np.testing.assert_array_equal(np.asarray(got.counts),
                                       np.asarray(want.counts))
+    acc = DeferredSegmentAccumulator(route="device")
+    for seg in segs:
+        acc.push(seg)
+    acc.result(min_count=10)
+    assert acc.finalize_blocks == len(blocks)
+    assert acc.fold_rows == total
 
 
 def test_generational_query_overflow_guard_trips():

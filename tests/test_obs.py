@@ -146,6 +146,32 @@ def _inside(inner, outer) -> bool:
             and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
 
 
+def test_device_finalize_block_spans_nest_in_finalize(monkeypatch):
+    """The blocked device finalize: one ``merge.device.block`` span per
+    counted block, inside ``merge.segments`` inside ``wave.finalize``;
+    their ``rows`` add up to ``fold_rows`` and their ``kept`` to the job's
+    output rows (the fold already dropped the rows under tau)."""
+    from repro.index import merge as merge_mod
+    monkeypatch.setattr(merge_mod, "DEVICE_BLOCK_ROWS", 256)
+    toks = make_corpus(6000, 60, "zipf", 11)
+    cfg = NGramConfig(sigma=3, tau=3, vocab_size=60)
+    tracer = obs_trace.enable_tracing()
+    try:
+        stats = WaveExecutor(cfg, wave_tokens=-(-len(toks) // 4),
+                             merge_route="device").run(toks)
+    finally:
+        obs_trace.disable_tracing()
+    evs = tracer.export()["traceEvents"]
+    blocks = [e for e in evs if e["name"] == "merge.device.block"]
+    assert len(blocks) == stats.counters["finalize_blocks"] > 1
+    for b in blocks:
+        assert any(_inside(b, m) for m in evs if m["name"] == "merge.segments")
+        assert any(_inside(b, f) for f in evs if f["name"] == "wave.finalize")
+    assert sum(b["args"]["rows"] for b in blocks) == \
+        stats.counters["fold_rows"]
+    assert sum(b["args"]["kept"] for b in blocks) == len(stats)
+
+
 def test_collect_and_finalize_spans_nest_and_join_by_wave(eight_wave_trace):
     obj, stats = eight_wave_trace
     evs = obj["traceEvents"]
@@ -235,7 +261,7 @@ def test_counters_parity_monolithic_vs_wave(method):
     wavy = WaveExecutor(cfg, wave_tokens=-(-len(toks) // 4)).run(toks)
     wave_only = {k for k, doc in obs_metrics.COUNTER_DOC.items()
                  if doc.endswith("(wave-only)")}
-    assert wave_only == {"waves", "fold_rows", "d2h_bytes"}
+    assert wave_only == {"waves", "fold_rows", "finalize_blocks", "d2h_bytes"}
     assert set(wavy.counters) - wave_only == set(mono.counters)
     assert wave_only <= set(wavy.counters)
     # every emitted key is documented in the one canonical glossary

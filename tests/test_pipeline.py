@@ -149,9 +149,9 @@ def test_accumulator_rejects_unknown_policy():
 
 
 def test_merge_route_device_matches_monolithic():
-    """The on-device k-way fold route (``merge_route="device"``, the mesh
-    accumulator's default lever) is bit-identical to the monolithic job and
-    to the host k-way default, across both fold policies."""
+    """The blocked device fold route (``merge_route="device"``, the
+    accelerator's default) is bit-identical to the monolithic job across
+    both fold policies."""
     toks = make_corpus(2000, 40, "zipf", seed=41)
     cfg = NGramConfig(sigma=4, tau=2, vocab_size=40)
     wave = -(-len(toks) // 8)
@@ -160,6 +160,91 @@ def test_merge_route_device_matches_monolithic():
         got = WaveExecutor(cfg, wave_tokens=wave, accumulator=acc,
                            merge_route="device").run(toks)
         assert_stats_equal(got, mono)
+
+
+@pytest.mark.parametrize("tau", [1, 2, 10])
+@pytest.mark.parametrize("n_waves", [1, 3, 8])
+def test_device_finalize_matches_kway_and_monolithic(monkeypatch, tau,
+                                                      n_waves):
+    """The blocked device finalize (``merge_route="device"``, the chip's
+    default), with blocks shrunk so each fold spans many: bit-identical to
+    the monolithic job and to the host k-way route at every tau, for one
+    segment as for many; ``finalize_blocks`` counts its blocks, and stays 0
+    on the host route and where a lone segment needs no fold."""
+    from repro.index import merge as merge_mod
+    monkeypatch.setattr(merge_mod, "DEVICE_BLOCK_ROWS", 64)
+    toks = make_corpus(1500, 30, "zipf", seed=43 + n_waves)
+    cfg = NGramConfig(sigma=3, tau=tau, vocab_size=30)
+    wave = -(-len(toks) // n_waves)
+    mono = run_job(toks, cfg)
+    kway = WaveExecutor(cfg, wave_tokens=wave, merge_route="kway").run(toks)
+    dev = WaveExecutor(cfg, wave_tokens=wave, merge_route="device").run(toks)
+    assert_stats_equal(dev, mono)
+    assert_stats_equal(kway, mono)
+    assert dev.counters["waves"] == n_waves
+    assert kway.counters["finalize_blocks"] == 0
+    if n_waves > 1:
+        assert dev.counters["finalize_blocks"] > 1
+    else:
+        assert dev.counters["finalize_blocks"] == 0
+    assert dev.counters["fold_rows"] >= kway.counters["fold_rows"]
+
+
+def test_device_finalize_of_an_empty_job():
+    """An empty corpus folds to empty statistics on both routes."""
+    cfg = NGramConfig(sigma=3, tau=2, vocab_size=9)
+    empty = np.zeros((0,), np.int32)
+    for route in ("device", "kway"):
+        got = WaveExecutor(cfg, wave_tokens=8, merge_route=route).run(empty)
+        assert len(got) == 0
+        assert got.counters["finalize_blocks"] == 0
+
+
+def test_default_merge_route_follows_backend(monkeypatch):
+    """``merge_route=None`` picks the host k-way fold on the CPU backend and
+    the blocked device fold for the deferred merge on an accelerator; the
+    tiered and pairwise accumulators keep the host fold; an explicit route
+    wins."""
+    import jax
+    cfg = NGramConfig(sigma=3, tau=2, vocab_size=9)
+    assert WaveExecutor(cfg, wave_tokens=8).merge_route == "kway"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert WaveExecutor(cfg, wave_tokens=8).merge_route == "device"
+    for acc in ("tiered", "pairwise"):
+        assert WaveExecutor(cfg, wave_tokens=8,
+                            accumulator=acc).merge_route == "kway"
+    assert WaveExecutor(cfg, wave_tokens=8,
+                        merge_route="kway").merge_route == "kway"
+
+
+def test_device_route_readies_one_block_program_on_first_run(monkeypatch,
+                                                             caplog):
+    """On an accelerator every device fold runs blocks of one shape.  A
+    one-wave first run folds nothing, yet returns with that shape's block
+    programs compiled; a later many-wave run of another size folds in
+    several blocks and compiles neither program again."""
+    import logging
+
+    import jax
+    from repro.index import merge as merge_mod
+    monkeypatch.setattr(merge_mod, "DEVICE_BLOCK_ROWS", 512)
+    monkeypatch.setattr(merge_mod, "_SURVIVOR_CHUNK", 64)
+    monkeypatch.setattr(merge_mod, "_block_loads", {})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = NGramConfig(sigma=3, tau=2, vocab_size=12)
+    toks = make_corpus(300, 12, "zipf", seed=1)
+    more = np.concatenate([toks, make_corpus(900, 12, "zipf", seed=2)])
+    ex = WaveExecutor(cfg, wave_tokens=toks.size)
+    assert ex.merge_route == "device"
+    one = ex.run(toks)
+    assert one.counters["finalize_blocks"] == 0
+    assert [f.done() for f in merge_mod._block_loads.values()] == [True]
+    with jax.log_compiles(True), caplog.at_level(logging.WARNING):
+        many = ex.run(more)
+    assert "Compiling jit(_merge_block)" not in caplog.text
+    assert "Compiling jit(_survivor_chunk)" not in caplog.text
+    assert many.counters["finalize_blocks"] > 1
+    assert_stats_equal(many, run_job(more, cfg))
 
 
 def test_segment_accumulators_match_merge_oracle():
