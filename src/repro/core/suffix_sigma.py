@@ -21,7 +21,6 @@ emits everything at the end of the block, which is the natural TPU formulation
 """
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
 
 import jax
@@ -32,7 +31,7 @@ from repro.mapreduce import pack as packing
 from repro.mapreduce import shuffle
 from repro.pipeline import plan as plan_mod
 from repro.pipeline import stages
-from .stats import NGramConfig, NGramStats, add_counters
+from .stats import NGramConfig, NGramStats
 
 # --------------------------------------------------------------------------- map
 @partial(jax.jit, static_argnames=("sigma",))
@@ -167,91 +166,6 @@ def _distributed(tokens_sharded: jax.Array, cfg: NGramConfig, mesh, axis_name: s
     fn = jax.jit(build_distributed_job(cfg, mesh, axis_name, capacity, has_bucket))
     bkt_arg = bucket_sharded if has_bucket else jnp.zeros((1, 1), jnp.uint32)
     return fn(tokens_sharded, bkt_arg)
-
-
-# --------------------------------------------------------- two-phase sigma split
-def sigma_split(tokens, cfg: NGramConfig, sigma_head: int = 16,
-                survivor_frac: float = 1 / 64) -> "NGramStats":
-    """Beyond-paper optimization (EXPERIMENTS.md SSPerf H3): split a large-sigma
-    job into
-
-      phase A: plain SUFFIX-sigma at sigma_head -- handles every gram of length
-               <= sigma_head with (sigma_head+1)-lane records instead of
-               (sigma+1)-lane ones (the sort bytes scale with the lane count);
-      phase B: only positions whose length-sigma_head head gram is frequent
-               (APRIORI: any frequent longer gram's occurrences all pass this
-               filter) emit full sigma-truncated suffixes; their count is tiny at
-               analytics-scale tau (the paper's Fig. 2 tail), so the wide-record
-               sort shrinks by ~1/survivor rate.
-
-    Exact: phase A counts lengths <= sigma_head; phase B counts lengths in
-    (sigma_head, sigma] -- every occurrence of a frequent long gram survives the
-    head filter, and partition-by-first-term still routes all evidence of a gram
-    to one reducer.  survivor_frac only sizes buffers (validated by an overflow
-    counter upstream).
-    """
-    tokens = jnp.asarray(tokens, jnp.int32)
-    if sigma_head >= cfg.sigma:
-        return run(tokens, cfg)
-    cfg_a = dataclasses.replace(cfg, sigma=sigma_head)
-    stats_a = run(tokens, cfg_a)
-
-    # frequent head set (the APRIORI dictionary, as in apriori_scan)
-    from .common import gram_hash, member, membership_hashes
-    full_len = stats_a.lengths == sigma_head
-    heads = jnp.asarray(stats_a.grams[full_len])
-    if heads.shape[0] == 0:
-        return stats_a
-    head_pad = jnp.zeros((heads.shape[0], cfg.sigma), jnp.int32
-                         ).at[:, :sigma_head].set(heads[:, :sigma_head])
-    dict_hashes = membership_hashes(
-        packing.pack_terms(head_pad, vocab_size=cfg.vocab_size),
-        jnp.ones((heads.shape[0],), bool))
-
-    # phase B: mask positions by head membership, count lengths > sigma_head
-    windows, valid = suffix_windows(tokens, cfg.sigma)
-    head_mask = jnp.arange(cfg.sigma) < sigma_head
-    head_grams = windows * head_mask[None, :].astype(windows.dtype)
-    has_full_head = windows[:, sigma_head - 1] != 0 if sigma_head > 1 \
-        else windows[:, 0] != 0
-    h = gram_hash(packing.pack_terms(head_grams, vocab_size=cfg.vocab_size))
-    eligible = valid & has_full_head & member(dict_hashes, h)
-
-    # compact survivor POSITIONS first (single-lane sort), then build the wide
-    # records only for them -- the wide-record sort shrinks by 1/survivor_frac,
-    # which is the whole point (EXPERIMENTS.md SSPerf H3 napkin math).
-    n_b = max(64, int(tokens.shape[0] * survivor_frac))
-    pos = jnp.argsort(~eligible, stable=True)[:n_b]
-    ok = eligible[pos]
-    padded = jnp.concatenate([tokens, jnp.zeros((cfg.sigma,), tokens.dtype)])
-    win_b = padded[pos[:, None] + jnp.arange(cfg.sigma)[None, :]]
-    keep = jnp.cumprod((win_b != 0).astype(jnp.int32), axis=1)
-    win_b = (win_b * keep) * ok[:, None].astype(win_b.dtype)
-    lanes_b = packing.pack_terms(win_b.astype(jnp.int32), vocab_size=cfg.vocab_size)
-    records = jnp.concatenate([lanes_b, ok.astype(jnp.uint32)[:, None]], axis=1)
-    terms, flags, counts = reduce_block(
-        records, sigma=cfg.sigma, vocab_size=cfg.vocab_size,
-        use_kernels=cfg.use_kernels)
-    # keep only lengths > sigma_head (phase A owns the rest)
-    flags = np.array(flags)
-    flags[:, :sigma_head] = False
-    stats_b = NGramStats.from_dense(np.asarray(terms), flags, np.asarray(counts),
-                                    cfg.tau)
-    # one blocking device round trip for the survivor counter, reused for
-    # both the overflow check and the counter bookkeeping below
-    n_eligible = int(jnp.sum(eligible))
-    dropped = n_eligible - n_b
-    stats_a = NGramStats(
-        np.pad(stats_a.grams, ((0, 0), (0, cfg.sigma - sigma_head))),
-        stats_a.lengths, stats_a.counts, stats_a.counters)
-    out = stats_a.merged_with(stats_b)
-    add_counters(out.counters, phase_b_records=n_eligible,
-                 phase_b_overflow=max(0, dropped))
-    if dropped > 0:
-        # survivor buffer too small -- rerun exact (counters expose the retry)
-        return sigma_split(tokens, cfg, sigma_head,
-                           survivor_frac=min(1.0, survivor_frac * 4))
-    return out
 
 
 def run(tokens, cfg: NGramConfig, mesh=None, axis_name: str = "data",

@@ -15,8 +15,9 @@ classic log-structured-merge discipline instead:
     than re-sorting blind -- and folds duplicate counts exactly in int64 via
     ``np.add.reduceat``; ``"device"`` is the blocked fold on the chip: the
     host cuts the sorted inputs into key-range blocks of at most
-    ``DEVICE_BLOCK_ROWS`` rows (a gram never straddles two; on an
-    accelerator every block is padded to that one shape, whose programs
+    :func:`device_block_rows` rows, fewer the wider the keys (a gram never
+    straddles two; on an accelerator every block of one key width is
+    padded to that one shape, whose programs
     :func:`load_block_programs` readies up front), and one jitted
     program of fixed shape sorts each block, sums its duplicate counts
     exactly in 8-bit limbs, drops rows under ``min_count`` and compacts the
@@ -120,12 +121,14 @@ def _merged_run(segs: list[IndexSegment], *, route: str,
 # duplicate floods.
 _MAX_DEVICE_RUN = 1 << 16
 
-# Rows per block of the "device" route's blocked fold on an accelerator,
-# whatever the input's size, so every fold there runs one program shape; at
-# most 2**24, so the 8-bit count limbs' prefix sums over a block stay below
-# 2**32.  Two blocks in flight hold about 2 GB of a v5e's HBM at 2**24 rows
-# of six lanes.
+# Most rows in a block of the "device" route's blocked fold: at most 2**24,
+# so the 8-bit count limbs' prefix sums over a block stay below 2**32.
 DEVICE_BLOCK_ROWS = 1 << 24
+# Key bytes in a block on an accelerator, whatever the input's size, so every
+# fold of one key width runs one program shape: 2**24 rows of six columns
+# (the sigma-5 job's keys), whose two blocks in flight hold about 2 GB of a
+# v5e's HBM.  Wider keys get fewer rows (:func:`device_block_rows`).
+DEVICE_BLOCK_BYTES = 4 * 6 << 24
 # survivors come back in chunks of this many rows through one slice program
 _SURVIVOR_CHUNK = 1 << 20
 
@@ -329,8 +332,7 @@ def _merge_block(flat_keys: jax.Array, counts: jax.Array,
     group = min(128, n)
     rows = flat_keys.reshape(n // group, group * n_cols)
     lanes = [rows[:, j::n_cols].reshape(n) for j in range(n_cols)]
-    *lanes, counts = lax.sort(lanes + [counts], num_keys=n_cols,
-                              is_stable=False)
+    *lanes, counts = mr_sort.sort_columns(lanes + [counts], num_keys=n_cols)
     differs = reduce(jnp.logical_or, [l[1:] != l[:-1] for l in lanes])
     first = jnp.ones((1,), bool)
     new_run = jnp.concatenate([first, differs])
@@ -347,8 +349,8 @@ def _merge_block(flat_keys: jax.Array, counts: jax.Array,
     totals = (hi << 16) | (lo & jnp.uint32(0xFFFF))
     real = run_end & (lanes[0] != SENTINEL)
     keep = real & (totals >= min_count)
-    out = lax.sort([jnp.where(keep, idx, n)] + lanes + [totals],
-                   num_keys=1, is_stable=False)
+    out = mr_sort.sort_columns([jnp.where(keep, idx, n)] + lanes + [totals],
+                               num_keys=1)
     return (jnp.stack(out[1:-1], axis=1), out[-1],
             jnp.sum(keep, dtype=jnp.int32), jnp.any(real & (hi > 0xFFFF)),
             jnp.max(jnp.where(real, run_len, 0)))
@@ -411,18 +413,27 @@ def _block_cuts(views: list[np.ndarray], rows: int) -> list[list[tuple]]:
     return blocks
 
 
-def _block_rows(total: int, k: int) -> int:
+def device_block_rows(n_cols: int) -> int:
+    """Rows of a block of keys ``n_cols`` columns wide on an accelerator:
+    the largest power of two whose key rows fit ``DEVICE_BLOCK_BYTES``, at
+    most ``DEVICE_BLOCK_ROWS`` (2**24 rows at six columns, 2**19 at 101)."""
+    rows = DEVICE_BLOCK_BYTES // (4 * n_cols)
+    return min(DEVICE_BLOCK_ROWS, 1 << (rows.bit_length() - 1))
+
+
+def _block_rows(total: int, k: int, n_cols: int) -> int:
     """Rows of every block of a fold of ``total`` rows from ``k`` inputs.
 
-    On an accelerator always ``DEVICE_BLOCK_ROWS``: one compiled program
-    serves every fold, which :func:`load_block_programs` readies up front.
-    The CPU backend sizes the block to the input instead (the next power of
-    two >= ``total`` and >= ``k``, at most ``DEVICE_BLOCK_ROWS``), so small
-    folds stay small there.
+    On an accelerator always :func:`device_block_rows` of the key width: one
+    compiled program serves every fold of that width, which
+    :func:`load_block_programs` readies up front.  The CPU backend sizes the
+    block to the input instead (the next power of two >= ``total`` and
+    >= ``k``, at most that), so small folds stay small there.
     """
+    cap = device_block_rows(n_cols)
     if jax.default_backend() != "cpu":
-        return DEVICE_BLOCK_ROWS
-    return max(min(DEVICE_BLOCK_ROWS, 1 << (total - 1).bit_length()),
+        return cap
+    return max(min(cap, 1 << (total - 1).bit_length()),
                1 << (k - 1).bit_length())
 
 
@@ -449,7 +460,7 @@ def load_block_programs(n_cols: int) -> Future | None:
     """
     if jax.default_backend() == "cpu":
         return None
-    key = (DEVICE_BLOCK_ROWS, n_cols)
+    key = (device_block_rows(n_cols), n_cols)
     with _block_loads_lock:
         if key not in _block_loads:
             _block_loads[key] = _block_loader.submit(
@@ -475,7 +486,7 @@ def _fold_blocks_device(segs: list[IndexSegment], *, min_count: int | None):
     total = sum(len(v) for v in views)
     if not total:
         return np.zeros((0, n_cols), np.uint32), np.zeros((0,), np.uint32), 0
-    rows = _block_rows(total, len(segs))
+    rows = _block_rows(total, len(segs), n_cols)
     if rows > 1 << 24:
         raise ValueError(f"device blocks of {rows} rows overflow the 8-bit "
                          "count limbs' prefix sums (at most 2**24 rows)")
@@ -537,8 +548,8 @@ def merge_segments(segments, *, route: str = "merge", use_kernels: bool = False,
     ``route="kway"`` folds on the host exploiting the inputs' sortedness
     (stable sort of concatenated big-endian row bytes == galloping k-way
     merge; int64 ``reduceat`` count fold); ``route="device"`` is the
-    blocked fold on the chip (key-range blocks of ``DEVICE_BLOCK_ROWS``
-    rows on an accelerator, survivors only back to the host, no size
+    blocked fold on the chip (key-range blocks of a fixed byte budget on
+    an accelerator, survivors only back to the host, no size
     cap); ``route="merge"`` runs the jitted pairwise merge-path (Pallas
     kernel when ``use_kernels``, jnp ref otherwise) over a balanced pairing
     tree; ``route="sort"`` re-sorts the concatenation (the

@@ -67,7 +67,14 @@ COUNTER_DOC: dict[str, str] = {
     "d2h_bytes": "bytes materialized device to host when the waves are "
                  "collected: the reducer outputs, key lanes, skew histogram "
                  "and counter scalars (wave-only)",
-    "phase_b_records": "SUFFIX-sigma phase-B survivor records (method-only)",
+    "head_dict_rows": "frequent full-length heads in the device hash table "
+                      "of a head/tail split job; 0 without the split "
+                      "(wave-only)",
+    "tail_positions": "positions whose head was in that table: the tail "
+                      "pass built sigma-wide records for these alone "
+                      "(wave-only)",
+    "tail_retries": "tail-pass waves rerun with a larger survivor buffer "
+                    "(wave-only)",
     "post_filter_jobs": "maximality/closedness post-filter jobs (method-only)",
     # ---- serving-frontend instruments (repro.serve; registry names, not job
     # counters -- they never ride NGramStats.counters or the merge policy).

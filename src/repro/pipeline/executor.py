@@ -52,6 +52,13 @@ map -> combine -> shuffle -> sort -> reduce *across machines*.
     lets mesh waves ride the same double-buffered dispatch + overlapped fold
     thread as the single-device path.  Bit-identical to the monolithic job.
 
+A single-device job whose records are wider than ``SPLIT_HEAD_LANES``
+lanes (sigma 100 at a web vocabulary) runs as a **head/tail split**
+(``WaveExecutor._run_split``): an ordinary wave pass at the head width, a
+hash table of its frequent full-length heads on the device, and a second
+pass whose one program per wave (``_build_tail_program``) builds
+sigma-wide records only at the positions that start a frequent head.
+
 ``run_streaming`` closes the loop with serving: each wave's partial goes
 straight into :class:`~repro.index.merge.GenerationalIndex` ingest, so a
 corpus that never fits on the device streams end to end into a queryable,
@@ -64,6 +71,7 @@ monolithic code -- just one shared implementation of the stage plumbing.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 from functools import partial
 
@@ -98,6 +106,18 @@ _WAVE_PROGRAMS: dict[tuple, object] = {}
 # device/host footprint of the overlapped fold at O(wave * sigma) times a
 # small constant while still keeping the device fed during host-side folds
 _WAVES_IN_FLIGHT = 2
+
+# A single-device job whose records are wider than this many lanes runs as a
+# head/tail split (``WaveExecutor._run_split``): the head pass counts every
+# gram of at most this many lanes' worth of terms, the tail pass the longer
+# grams, at the positions whose head is frequent over the whole job.
+SPLIT_HEAD_LANES = 8
+# the tail program's survivor buffer holds a wave's positions over this; a
+# wave with more survivors reruns with the buffer doubled
+_TAIL_SHARE = 8
+# the frequent-head hash table is padded to a power of two at least this
+# long, so jobs of one size run one compiled tail program
+_HEAD_TABLE_MIN = 1 << 16
 
 
 def reset_stage_cache() -> None:
@@ -220,6 +240,91 @@ def _wave_core(cfg, plan: JobPlan, tok_ext, n_live: int):
     if fn is None:
         fn = _WAVE_PROGRAMS[key] = _build_wave_program(cfg, plan)
     return fn(tok_ext, n_live)
+
+
+def _build_tail_program(cfg, head: int):
+    """Trace the tail pass of one split wave into a single jitted program.
+
+    A live position of the window (``sigma - 1`` halo) survives when its
+    first ``head`` terms are all real and their packed lanes hash into
+    ``table``, the sorted hashes of the job's frequent heads
+    (``core.common.membership_hashes``).  The survivors' positions are
+    compacted into a buffer of ``capacity`` rows first; only for them are
+    sigma-wide suffix records built, sorted and reduced.  Returns the
+    counts of the lengths past ``head`` [capacity, sigma - head], the
+    sorted key lanes [capacity, n_lanes] and the number of survivors, of
+    which the buffer holds the first ``capacity``.
+
+    A hash collision only lets through a position whose head is not
+    frequent: every gram it starts is then infrequent, and the job's tau
+    drops it.  The module is ``jit_tail_fn`` in device traces.
+    """
+    from repro.core.common import gram_hash, member
+    from repro.core.suffix_sigma import suffix_windows
+    sigma, vocab = cfg.sigma, cfg.vocab_size
+    n_l = packing.n_lanes(sigma, vocab)
+
+    def tail_fn(tok_ext, n_live, table, *, capacity):
+        n = tok_ext.shape[0]
+        with jax.named_scope("heads"):
+            heads, _ = suffix_windows(tok_ext, head)
+            ok = (heads[:, head - 1] != 0) & (jnp.arange(n) < n_live)
+            ok = ok & member(table, gram_hash(
+                packing.pack_terms(heads, vocab_size=vocab)))
+            n_ok = jnp.sum(ok, dtype=jnp.int32)
+        with jax.named_scope("compact"):
+            pos = jnp.nonzero(ok, size=capacity, fill_value=n)[0]
+        with jax.named_scope("emit"):
+            # the fill position n reads only the zeros past the window
+            padded = jnp.concatenate([tok_ext,
+                                      jnp.zeros((sigma,), tok_ext.dtype)])
+            win = jnp.stack([padded[pos + j] for j in range(sigma)], axis=1)
+            win = win * jnp.cumprod((win != 0).astype(win.dtype), axis=1)
+            records = jnp.concatenate(
+                [packing.pack_terms(win, vocab_size=vocab),
+                 (win[:, :1] != 0).astype(jnp.uint32)], axis=1)
+        with jax.named_scope("sort"):
+            rec = stages.sort_stage(records, n_keys=n_l)
+        with jax.named_scope("reduce"):
+            _, _, counts = stages.reduce_suffix(rec, sigma=sigma,
+                                                vocab_size=vocab)
+        return counts[:, head:], rec[:, :n_l], n_ok
+
+    return jax.jit(tail_fn, static_argnames=("capacity",))
+
+
+def _tail_core(cfg, head: int, tok_ext, n_live: int, table, capacity: int):
+    """Dispatch one wave's tail pass through its cached program."""
+    key = ("tail", jax.default_backend(), cfg, head)
+    fn = _WAVE_PROGRAMS.get(key)
+    if fn is None:
+        fn = _WAVE_PROGRAMS[key] = _build_tail_program(cfg, head)
+    return fn(tok_ext, n_live, table, capacity=capacity)
+
+
+def _head_table(lanes: np.ndarray):
+    """Sorted hashes of the frequent heads' packed lanes, padded to a
+    power of two of at least ``_HEAD_TABLE_MIN`` rows (the padding hashes to
+    the largest value, which at worst lets a few positions through)."""
+    from repro.core.common import membership_hashes
+    size = max(_HEAD_TABLE_MIN, 1 << max(len(lanes) - 1, 0).bit_length())
+    padded = np.zeros((size, lanes.shape[1]), np.uint32)
+    padded[:len(lanes)] = lanes
+    return membership_hashes(jnp.asarray(padded),
+                             jnp.arange(size) < len(lanes))
+
+
+def _prefix_rows(keep, counts, lanes, masks, first_len: int = 1):
+    """Segment rows (length | prefix lanes) and counts of the kept cells of a
+    reducer's [rows, lengths] grid, whose column j holds length
+    ``first_len + j``; a kept row of length l has key lanes ``lanes &
+    masks[l]``.  Rows come out of ``nonzero(keep.T)`` in (length, lane rank)
+    order, which is segment order."""
+    lens0, rows = np.nonzero(keep.T)
+    lengths = (lens0 + first_len).astype(np.uint32)
+    pref = lanes[rows] & masks[lengths]
+    return (np.concatenate([lengths[:, None], pref], axis=1).astype(np.uint32),
+            counts[rows, lens0].astype(np.uint32))
 
 
 def _run_rounds(tok_ext, aux_ext, n_live: int, cfg, plan: JobPlan,
@@ -455,6 +560,10 @@ class WaveExecutor:
     waves and fold through the same segment path, so the distributed run
     stays bit-identical to the single-device one.
 
+    A single-device job whose records are wider than ``SPLIT_HEAD_LANES``
+    lanes runs as the head/tail split of :meth:`_run_split`, with the same
+    output; with a mesh, every sigma keeps one full-width pass.
+
     Memory model: device footprint is O(wave * sigma) records per stage (per
     shard when distributed); the running segments live wherever
     ``index/merge.py`` keeps them and together hold the *exact* (tau=1) gram
@@ -514,6 +623,18 @@ class WaveExecutor:
         # (pack ablations / pack_vocab overrides take the stats route)
         self._direct = (self.plan.effective_lane_vocab(cfg) == cfg.vocab_size)
         self._masks = None               # prefix_lane_masks, built lazily
+        # the head/tail split (:meth:`_run_split`), decided by the record
+        # width that sigma and the vocabulary give; mesh waves keep one
+        # full-width pass
+        self._head_ex = None
+        self._tail_scale = 1             # the tail buffer's sticky doubling
+        if (self._direct and not self._use_mesh and packing.n_lanes(
+                cfg.sigma, cfg.vocab_size) > SPLIT_HEAD_LANES):
+            head = SPLIT_HEAD_LANES * packing.terms_per_lane(cfg.vocab_size)
+            self._head_ex = WaveExecutor(
+                dataclasses.replace(cfg, sigma=head), wave_tokens=wave_tokens,
+                merge_route=self.merge_route, accumulator=accumulator,
+                overlap=overlap)
 
     # --- wave iteration ------------------------------------------------------ #
 
@@ -529,8 +650,7 @@ class WaveExecutor:
         mesh path re-pads to the shard layout before its own h2d).
         """
         n = int(tokens.shape[0])
-        wave = self.wave_tokens if self.wave_tokens is not None else n
-        wave = max(1, min(wave, n) if n else 1)
+        wave = self._wave_len(n)
         n_waves = max(1, -(-n // wave))
         halo = self.cfg.sigma - 1
         with obs_trace.span("wave.window.pad") as sp:
@@ -547,6 +667,11 @@ class WaveExecutor:
                         sp.set(wave=w)
                     tok_ext = jnp.asarray(tok_ext)
             yield tok_ext, n_live
+
+    def _wave_len(self, n: int) -> int:
+        """Tokens a wave of an ``n``-token job holds."""
+        wave = self.wave_tokens if self.wave_tokens is not None else n
+        return max(1, min(wave, n) if n else 1)
 
     @property
     def _use_mesh(self) -> bool:
@@ -685,13 +810,10 @@ class WaveExecutor:
                     _add_round_counters(counters, map_rec, shuffled, hist,
                                         pend["rec_bytes"], nbytes)
                     # from_dense's keep at the wave regime's tau = 1
-                    keep = (flags != 0) & (counts >= 1)
-                    lens0, rows = np.nonzero(keep.T)
-                    lengths = (lens0 + 1).astype(np.uint32)
-                    pref = lanes[rows] & masks[lengths]
-                    key_parts.append(np.concatenate(
-                        [lengths[:, None], pref], axis=1).astype(np.uint32))
-                    cnt_parts.append(counts[rows, lens0].astype(np.uint32))
+                    keys, cnts = _prefix_rows((flags != 0) & (counts >= 1),
+                                              counts, lanes, masks)
+                    key_parts.append(keys)
+                    cnt_parts.append(cnts)
             with obs_trace.span("wave.collect.rows") as sp_r:
                 if sp_r:
                     sp_r.set(wave=w)
@@ -1083,13 +1205,16 @@ class WaveExecutor:
         if res is not None:
             yield res
 
-    def _for_each_wave(self, tokens, consume, *, collect=None) -> None:
+    def _for_each_wave(self, tokens, consume, *, collect=None,
+                       submit=None) -> None:
         """Run ``consume(collected wave)`` for every wave, in wave order.
 
         ``collect`` maps a submitted wave to the object ``consume`` sees
         (default :meth:`_collect_wave` -> ``NGramStats``; the fold paths
         pass :meth:`_collect_wave_segment` -> :class:`WavePartial`); both
         route mesh waves to their sharded twins via the pend dict.
+        ``submit`` dispatches a window (default :meth:`_submit_wave`; the
+        split's tail pass dispatches through :meth:`_submit_tail`).
 
         The wave-level parallel fold: the main thread stays a pure *feeder*
         -- it slices host token slabs and dispatches one fused program per
@@ -1108,13 +1233,14 @@ class WaveExecutor:
         wave never stalls the feeder.  ``overlap=False`` serializes.
         """
         collect = collect or self._collect_wave
+        submit = submit or self._submit_wave
         tokens = np.asarray(tokens, np.int32)
         self.cfg.validate_tokens(tokens)
         to_device = not self._use_mesh
         if not self.overlap:
             for w, (tok_ext, n_live) in enumerate(
                     self._windows(tokens, to_device=to_device)):
-                consume(collect(self._submit_wave(tok_ext, n_live, w)))
+                consume(collect(submit(tok_ext, n_live, w)))
             return
         import queue
         import threading
@@ -1143,7 +1269,7 @@ class WaveExecutor:
                     self._windows(tokens, to_device=to_device)):
                 if failure:
                     break
-                pend = self._submit_wave(tok_ext, n_live, w)
+                pend = submit(tok_ext, n_live, w)
                 # waits only while the fold thread is behind
                 with obs_trace.span("wave.feed.wait") as sp:
                     if sp:
@@ -1161,12 +1287,11 @@ class WaveExecutor:
         """Execute the job over waves -> ``NGramStats`` (canonical order),
         bit-identical to the monolithic single-job run.  ``fold_rows`` in the
         counters is the total segment rows fed through ``merge_segments`` --
-        the accumulator's measured merge work."""
+        the accumulator's measured merge work.  A single-device job whose
+        records are wider than ``SPLIT_HEAD_LANES`` lanes runs as a head/tail
+        split (:meth:`_run_split`)."""
         from repro.core.stats import NGramStats
-        from repro.index.merge import (DeferredSegmentAccumulator,
-                                       PairwiseSegmentAccumulator,
-                                       TieredSegmentAccumulator,
-                                       load_block_programs, segment_to_stats)
+        from repro.index.merge import segment_to_stats
 
         with obs_trace.span("wave.run") as root:
             tokens = np.asarray(tokens, np.int32)
@@ -1175,50 +1300,214 @@ class WaveExecutor:
                          method=self.cfg.method,
                          accumulator=self.accumulator)
             # full canonical counter set (obs.metrics.COUNTER_DOC): identical
-            # keys to the monolithic run_plan, plus the wave-only
-            # waves/fold_rows/finalize_blocks/d2h_bytes
+            # keys to the monolithic run_plan, plus the wave-only ones
             counters = dict.fromkeys(
                 ("jobs", "map_records", "shuffle_records", "shuffle_bytes",
                  "retries", "overflow", "waves", "fold_rows",
-                 "finalize_blocks", "d2h_bytes"), 0)
+                 "finalize_blocks", "d2h_bytes", "head_dict_rows",
+                 "tail_positions", "tail_retries"), 0)
             counters["shuffle_skew"] = 0.0
-            acc_cls = {"defer": DeferredSegmentAccumulator,
-                       "tiered": TieredSegmentAccumulator,
-                       "pairwise": PairwiseSegmentAccumulator}[self.accumulator]
-            acc = acc_cls(route=self.merge_route,
-                          use_kernels=self.cfg.use_kernels)
-            loading = None
-            if self.merge_route == "device":
-                loading = load_block_programs(
-                    1 + packing.n_lanes(self.cfg.sigma, self.cfg.vocab_size))
+            if self._head_ex is not None:
+                out = self._run_split(tokens, counters)
+            else:
+                acc, loading = self._accumulator()
+                self._fold_waves(tokens, acc, counters)
+                with obs_trace.span("wave.finalize") as sp:
+                    # tau filters inside the deferred fold (on the "device"
+                    # route before rows leave the chip) and again,
+                    # idempotently, before the term unpack, so only the
+                    # survivor set pays the unpack
+                    out = segment_to_stats(
+                        self._finalize(acc, loading, counters),
+                        min_count=self.cfg.tau)
+                    if sp:
+                        sp.set(rows=len(out), fold_rows=acc.fold_rows)
+            return NGramStats(out.grams, out.lengths, out.counts,
+                              obs_metrics.normalize_counters(counters))
+
+    def _accumulator(self):
+        """A fresh accumulator for one pass's wave segments, and, on the
+        ``"device"`` route, the load of its key width's block programs."""
+        from repro.index.merge import (DeferredSegmentAccumulator,
+                                       PairwiseSegmentAccumulator,
+                                       TieredSegmentAccumulator,
+                                       load_block_programs)
+        acc_cls = {"defer": DeferredSegmentAccumulator,
+                   "tiered": TieredSegmentAccumulator,
+                   "pairwise": PairwiseSegmentAccumulator}[self.accumulator]
+        acc = acc_cls(route=self.merge_route, use_kernels=self.cfg.use_kernels)
+        loading = None
+        if self.merge_route == "device":
+            loading = load_block_programs(
+                1 + packing.n_lanes(self.cfg.sigma, self.cfg.vocab_size))
+        return acc, loading
+
+    def _fold_waves(self, tokens, acc, counters: dict) -> None:
+        """Every wave of ``tokens`` through this executor's wave program into
+        ``acc``, the waves' counters into ``counters``."""
+        def fold(part: WavePartial):
+            # runs on the fold thread: overlaps the next wave's dispatch
+            counters["waves"] += 1
+            _merge_wave_counters(counters, part.counters)
+            with obs_trace.span("wave.fold") as sp:
+                if sp:
+                    sp.set(wave=counters["waves"] - 1, rows=part.n_rows)
+                acc.push(part.segment, n_rows=part.n_rows)
+
+        self._for_each_wave(tokens, fold, collect=self._collect_wave_segment)
+
+    def _finalize(self, acc, loading, counters: dict):
+        """The accumulator's fold at the job's tau -> one sorted segment (a
+        lone pushed segment comes back unfiltered)."""
+        if loading is not None:
+            loading.result()
+        merged = (acc.result(min_count=self.cfg.tau)
+                  if self.accumulator == "defer" else acc.result())
+        counters["fold_rows"] += acc.fold_rows
+        counters["finalize_blocks"] += acc.finalize_blocks
+        return merged
+
+    # --- head/tail split ------------------------------------------------------ #
+
+    def _run_split(self, tokens, counters: dict):
+        """A job of wide records as two passes over the same waves.
+
+        Pass A runs the method's ordinary waves at the head width (sigma =
+        ``SPLIT_HEAD_LANES`` lanes' worth of terms) and finalizes them: every
+        gram of at most ``head`` terms with cf >= tau.  A longer gram can
+        reach tau only if its head does, and each of its occurrences starts
+        at an occurrence of that head.  So the frequent full-length heads go
+        to the device as a hash table (span ``wave.heads``, counter
+        ``head_dict_rows``), and pass B runs every wave through the tail
+        program (:func:`_build_tail_program`), which builds sigma-wide
+        records only at the positions whose head is in the table (counter
+        ``tail_positions``) and keeps the lengths past the head.  Its wave
+        partials fold like pass A's.  The two passes' rows are disjoint by
+        length, so pass A's rows, zero-padded to sigma, then pass B's are
+        the job's output in canonical order.  Returns ``NGramStats``
+        without counters (:meth:`run` adds them).
+        """
+        from repro.core.stats import NGramStats
+        from repro.index.merge import segment_to_stats
+
+        cfg, head_ex = self.cfg, self._head_ex
+        head = head_ex.cfg.sigma
+        acc_a, loading_a = head_ex._accumulator()
+        acc_b, loading_b = self._accumulator()
+        head_ex._fold_waves(tokens, acc_a, counters)
+        with obs_trace.span("wave.finalize") as sp:
+            seg_a = self._finalize(acc_a, loading_a, counters)
+            if sp:
+                sp.set(fold_rows=acc_a.fold_rows)
+        with obs_trace.span("wave.heads") as sp:
+            keys = np.asarray(seg_a.keys)[:seg_a.n_rows]
+            frequent = ((keys[:, 0] == head)
+                        & (np.asarray(seg_a.counts)[:seg_a.n_rows] >= cfg.tau))
+            lanes = keys[frequent, 1:]
+            counters["head_dict_rows"] = len(lanes)
+            table = _head_table(lanes) if len(lanes) else None
+            if sp:
+                sp.set(rows=len(lanes))
+        if table is not None:
+            base = max(8, -(-self._wave_len(int(tokens.shape[0]))
+                            // _TAIL_SHARE))
+            n_tail = [0]
 
             def fold(part: WavePartial):
-                # runs on the fold thread: overlaps the next wave's dispatch
-                counters["waves"] += 1
                 _merge_wave_counters(counters, part.counters)
                 with obs_trace.span("wave.fold") as sp:
                     if sp:
-                        sp.set(wave=counters["waves"] - 1, rows=part.n_rows)
-                    acc.push(part.segment, n_rows=part.n_rows)
+                        sp.set(wave=n_tail[0], rows=part.n_rows, tail=True)
+                    n_tail[0] += 1
+                    acc_b.push(part.segment, n_rows=part.n_rows)
 
-            self._for_each_wave(tokens, fold,
-                                collect=self._collect_wave_segment)
-            with obs_trace.span("wave.finalize") as sp:
-                if loading is not None:
-                    loading.result()
-                # tau filters inside the deferred fold (on the "device" route
-                # before rows leave the chip) and again, idempotently, before
-                # the term unpack, so only the survivor set pays the unpack
-                merged = (acc.result(min_count=self.cfg.tau)
-                          if self.accumulator == "defer" else acc.result())
-                out = segment_to_stats(merged, min_count=self.cfg.tau)
-                counters["fold_rows"] = acc.fold_rows
-                counters["finalize_blocks"] = acc.finalize_blocks
-                out = NGramStats(out.grams, out.lengths, out.counts,
-                                 obs_metrics.normalize_counters(counters))
-                if sp:
-                    sp.set(rows=len(out), fold_rows=acc.fold_rows)
-            return out
+            self._for_each_wave(tokens, fold, collect=self._collect_tail,
+                                submit=partial(self._submit_tail,
+                                               table=table, base=base))
+        with obs_trace.span("wave.finalize") as sp:
+            a = segment_to_stats(seg_a, min_count=cfg.tau)
+            if table is not None:
+                b = segment_to_stats(self._finalize(acc_b, loading_b,
+                                                    counters),
+                                     min_count=cfg.tau)
+            else:
+                # no tail, but later runs find the wide programs ready
+                if loading_b is not None:
+                    loading_b.result()
+                b = NGramStats(np.zeros((0, cfg.sigma), np.int32),
+                               np.zeros((0,), np.int32),
+                               np.zeros((0,), np.int64))
+            grams = np.zeros((len(a) + len(b), cfg.sigma), np.int32)
+            grams[:len(a), :head] = a.grams
+            grams[len(a):] = b.grams
+            out = NGramStats(grams, np.concatenate([a.lengths, b.lengths]),
+                             np.concatenate([a.counts, b.counts]))
+            if sp:
+                sp.set(rows=len(out), fold_rows=acc_b.fold_rows)
+        return out
+
+    def _submit_tail(self, tok_ext, n_live: int, wave: int = 0, *, table,
+                     base: int) -> dict:
+        """Dispatch one wave's tail pass; nothing materializes here.  The
+        buffer holds ``base`` rows times the sticky doubling, and the pend
+        dict keeps the window for a rerun."""
+        cap = base * self._tail_scale
+        with obs_trace.span("wave.submit") as sp:
+            if sp:
+                sp.set(wave=wave, n_live=n_live, tail=True, capacity=cap)
+            outs = _tail_core(self.cfg, self._head_ex.cfg.sigma, tok_ext,
+                              n_live, table, cap)
+        return {"outs": outs, "tok_ext": tok_ext, "n_live": n_live,
+                "table": table, "base": base, "capacity": cap, "wave": wave}
+
+    def _collect_tail(self, pend: dict) -> WavePartial:
+        """Materialize one wave's tail pass into a sorted host segment of its
+        grams longer than the head (span ``wave.tail``: ``wave``,
+        ``positions``, ``rows``).  Only the survivors' rows come back.  A
+        wave with more survivors than its buffer reruns with the buffer
+        doubled; the doubling sticks for later waves (``tail_retries``)."""
+        from repro.index._layout import row_bytes_view
+        from repro.index.build import IndexSegment
+
+        cfg, w = self.cfg, pend["wave"]
+        head = self._head_ex.cfg.sigma
+        with obs_trace.span("wave.tail") as sp:
+            if sp:
+                sp.set(wave=w)
+                _wait_for_device(pend["outs"], w)
+            outs, cap, retries, d2h = pend["outs"], pend["capacity"], 0, 0
+            while True:
+                with obs_trace.span("wave.collect.d2h") as sp_d:
+                    (counts, lanes, n_ok), nbytes = _to_host(*outs)
+                    if sp_d:
+                        sp_d.set(wave=w, bytes=nbytes)
+                d2h += nbytes
+                n_ok = int(n_ok)
+                if n_ok <= cap:
+                    break
+                retries += 1
+                while cap < n_ok:
+                    cap *= 2
+                self._tail_scale = max(self._tail_scale, cap // pend["base"])
+                outs = _tail_core(cfg, head, pend["tok_ext"], pend["n_live"],
+                                  pend["table"], cap)
+            with obs_trace.span("wave.collect.rows") as sp_r:
+                if sp_r:
+                    sp_r.set(wave=w)
+                keys, cnts = _prefix_rows(counts >= 1, counts, lanes,
+                                          self._prefix_masks(),
+                                          first_len=head + 1)
+            with obs_trace.span("wave.collect.sort") as sp_s:
+                if sp_s:
+                    sp_s.set(wave=w, rows=int(keys.shape[0]))
+                order = np.argsort(row_bytes_view(keys), kind="stable")
+                seg = IndexSegment(keys=keys[order], counts=cnts[order],
+                                   sigma=cfg.sigma, vocab_size=cfg.vocab_size)
+            if sp:
+                sp.set(positions=n_ok, rows=int(keys.shape[0]))
+        return WavePartial(seg, int(keys.shape[0]),
+                           {"tail_positions": n_ok, "tail_retries": retries,
+                            "d2h_bytes": d2h})
 
     def run_streaming(self, tokens, *, gen=None, compress: bool = False,
                       block_size: int = 4, **gen_kw):

@@ -85,6 +85,30 @@ def test_packed_sort_is_lexicographic(rows):
     assert [tuple(r) for r in np.asarray(back).tolist()] == py
 
 
+@pytest.mark.parametrize("width", [5, 15, 17, 40, 101])
+def test_wide_sorts_are_lexicographic(width):
+    """Records wider than one ``lax.sort`` takes sort through the permutation
+    of stable passes: the same rows in the same order as numpy's lexsort,
+    with the payload lane riding along, at every width."""
+    import jax.numpy as jnp
+    from repro.mapreduce import sort
+    rng = np.random.default_rng(width)
+    keys = rng.integers(0, 3, (300, width)).astype(np.uint32)
+    keys[::7] = keys[3]                    # runs of equal rows
+    payload = np.arange(300, dtype=np.uint32)
+    rec = np.concatenate([keys, payload[:, None]], axis=1)
+    want = np.lexsort(tuple(keys[:, j] for j in range(width - 1, -1, -1)))
+    out = np.asarray(sort.sort_records(jnp.asarray(rec), n_keys=width))
+    np.testing.assert_array_equal(out[:, :width], keys[want])
+    np.testing.assert_array_equal(np.sort(out[:, width]), payload)
+    cols = sort.sort_columns([jnp.asarray(keys[:, j]) for j in range(width)]
+                             + [jnp.asarray(payload)], num_keys=width)
+    np.testing.assert_array_equal(
+        np.stack([np.asarray(c) for c in cols[:width]], axis=1), keys[want])
+    if width + 1 > sort._SORT_OPERANDS:    # the permutation path is stable
+        np.testing.assert_array_equal(np.asarray(cols[-1]), want)
+
+
 @settings(max_examples=15, deadline=None)
 @given(toks=corpora, n_buckets=st.integers(1, 5))
 def test_series_sums_to_counts(toks, n_buckets):

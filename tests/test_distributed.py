@@ -575,18 +575,28 @@ def test_shard_generational_incremental_reuse():
     assert all(a is not b for a in sh3.shards for b in sh2.shards)
 
 
-def test_sigma_split_exact():
-    """Two-phase sigma split (SSPerf H3) is exact vs the single job."""
-    import numpy as np
+def test_sigma_split_exact(monkeypatch):
+    """The wave engine's head/tail split is exact vs the single job, at head
+    widths 6 and 4, and an undersized survivor buffer recovers by a rerun."""
     from repro.core import suffix_sigma
     from repro.core.stats import NGramConfig
     from repro.data import corpus as corpus_mod
+    from repro.mapreduce import pack as packing
+    from repro.pipeline import WaveExecutor, executor
     toks = corpus_mod.zipf_corpus(3000, corpus_mod.NYT, seed=5, duplicate_frac=0.3)
     cfg = NGramConfig(sigma=20, tau=2, vocab_size=corpus_mod.NYT.vocab_size)
     full = suffix_sigma.run(toks, cfg).to_dict()
-    assert suffix_sigma.sigma_split(toks, cfg, 6, 1 / 8).to_dict() == full
-    # undersized survivor buffer recovers via retry
-    assert suffix_sigma.sigma_split(toks, cfg, 4, 1 / 512).to_dict() == full
+    per_lane = packing.terms_per_lane(cfg.vocab_size)
+    for head, tail_share in ((6, 1), (4, 512)):
+        monkeypatch.setattr(executor, "SPLIT_HEAD_LANES", head // per_lane)
+        monkeypatch.setattr(executor, "_TAIL_SHARE", tail_share)
+        ex = WaveExecutor(cfg, wave_tokens=1024)
+        assert ex._head_ex is not None and ex._head_ex.cfg.sigma == head
+        out = ex.run(toks)
+        assert out.to_dict() == full
+        # a buffer of the whole wave never reruns; an undersized one (head 4)
+        # recovers via retry
+        assert (out.counters["tail_retries"] > 0) == (tail_share == 512)
 
 
 def test_moe_sort_dispatch_under_mesh():

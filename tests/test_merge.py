@@ -200,6 +200,52 @@ def test_device_merge_route_many_blocks(monkeypatch):
     assert acc.fold_rows == total
 
 
+def test_device_block_rows_follow_the_key_width(monkeypatch):
+    """On an accelerator a block of six-column keys (the sigma-5 job's)
+    holds exactly 2**24 rows whatever the input's size; wider keys get
+    fewer rows under the same byte budget, one shape per width."""
+    from repro.index import merge as merge_mod
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert merge_mod._block_rows(10, 2, 6) == 1 << 24
+    assert merge_mod._block_rows(1 << 30, 9, 6) == 1 << 24
+    rows = [merge_mod._block_rows(10, 2, c) for c in (6, 9, 17, 18, 101)]
+    assert rows == [1 << 24, 1 << 23, 1 << 22, 1 << 22, 1 << 19]
+    for c, r in zip((6, 9, 17, 18, 101), rows):
+        assert r * 4 * c <= merge_mod.DEVICE_BLOCK_BYTES < 2 * r * 4 * c
+
+
+def test_device_fold_of_wide_keys_matches_kway(monkeypatch):
+    """Keys 18 columns wide (sigma 17, one term a lane), with the byte
+    budget shrunk to 64 rows of them: the blocked fold spans many blocks
+    and equals the host k-way route at every ``min_count``."""
+    from repro.index import merge as merge_mod
+    from repro.index.merge import DeferredSegmentAccumulator
+    vocab = 1 << 17
+    rng = np.random.default_rng(17)
+    quote = rng.integers(1, vocab, 30).astype(np.int32)
+    cfg = NGramConfig(sigma=17, tau=1, vocab_size=vocab)
+    segs = []
+    for s in range(3):
+        toks = np.concatenate([make_corpus(300, 40, "zipf", s), [0], quote,
+                               [0]]).astype(np.int32)
+        segs.append(segment_from_stats(run_job(toks, cfg), vocab_size=vocab))
+    assert np.asarray(segs[0].keys).shape[1] == 18
+    monkeypatch.setattr(merge_mod, "DEVICE_BLOCK_BYTES", 4 * 18 * 64)
+    assert merge_mod._block_rows(10 ** 6, 3, 18) == 64
+    for min_count in (None, 2, 3):
+        want = merge_segments(segs, route="kway", min_count=min_count)
+        got = merge_segments(segs, route="device", min_count=min_count)
+        np.testing.assert_array_equal(np.asarray(got.keys),
+                                      np.asarray(want.keys))
+        np.testing.assert_array_equal(np.asarray(got.counts),
+                                      np.asarray(want.counts))
+    acc = DeferredSegmentAccumulator(route="device")
+    for seg in segs:
+        acc.push(seg)
+    acc.result(min_count=3)
+    assert acc.finalize_blocks > 1
+
+
 def test_generational_query_overflow_guard_trips():
     """Counts split across live segments must not silently wrap at query time
     (the lookup-side mirror of the merge fold's guard)."""

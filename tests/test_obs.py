@@ -172,6 +172,47 @@ def test_device_finalize_block_spans_nest_in_finalize(monkeypatch):
     assert sum(b["args"]["kept"] for b in blocks) == len(stats)
 
 
+def test_split_job_spans_and_counters(monkeypatch):
+    """A head/tail split job (head width shrunk so it engages): one
+    ``wave.heads`` span, one ``wave.tail`` span a wave carrying ``wave``,
+    ``positions`` and ``rows``, whose sums are the job's
+    ``tail_positions`` counter and the tail segment rows folded; the split's
+    counters reach the metrics registry like every job counter."""
+    from repro.pipeline import executor
+    monkeypatch.setattr(executor, "SPLIT_HEAD_LANES", 2)
+    rng = np.random.default_rng(3)
+    quote = rng.integers(1, 200_000, 9).astype(np.int32)
+    toks = np.concatenate(
+        [np.concatenate([make_corpus(60, 50, "zipf", i), quote, [0]])
+         for i in range(12)]).astype(np.int32)
+    cfg = NGramConfig(sigma=7, tau=3, vocab_size=200_000)
+    tracer = obs_trace.enable_tracing()
+    try:
+        stats = WaveExecutor(cfg, wave_tokens=-(-len(toks) // 3)).run(toks)
+    finally:
+        obs_trace.disable_tracing()
+    evs = tracer.export()["traceEvents"]
+    assert obs_report.validate_trace(tracer.export()) == []
+    heads = [e for e in evs if e["name"] == "wave.heads"]
+    tails = [e for e in evs if e["name"] == "wave.tail"]
+    assert len(heads) == 1
+    assert heads[0]["args"]["rows"] == stats.counters["head_dict_rows"] > 0
+    assert sorted(e["args"]["wave"] for e in tails) == [0, 1, 2]
+    assert sum(e["args"]["positions"] for e in tails) == \
+        stats.counters["tail_positions"] > 0
+    tail_folds = [e for e in evs if e["name"] == "wave.fold"
+                  and e["args"].get("tail")]
+    assert sum(e["args"]["rows"] for e in tails) == \
+        sum(e["args"]["rows"] for e in tail_folds) > 0
+    for t in tails:
+        assert any(_inside(d, t) for d in evs
+                   if d["name"] == "wave.collect.d2h")
+    reg = obs_metrics.MetricsRegistry()
+    reg.merge_job_counters(stats.counters)
+    for k in ("head_dict_rows", "tail_positions", "tail_retries"):
+        assert reg.counters["job." + k] == stats.counters[k]
+
+
 def test_collect_and_finalize_spans_nest_and_join_by_wave(eight_wave_trace):
     obj, stats = eight_wave_trace
     evs = obj["traceEvents"]
@@ -261,7 +302,8 @@ def test_counters_parity_monolithic_vs_wave(method):
     wavy = WaveExecutor(cfg, wave_tokens=-(-len(toks) // 4)).run(toks)
     wave_only = {k for k, doc in obs_metrics.COUNTER_DOC.items()
                  if doc.endswith("(wave-only)")}
-    assert wave_only == {"waves", "fold_rows", "finalize_blocks", "d2h_bytes"}
+    assert wave_only == {"waves", "fold_rows", "finalize_blocks", "d2h_bytes",
+                         "head_dict_rows", "tail_positions", "tail_retries"}
     assert set(wavy.counters) - wave_only == set(mono.counters)
     assert wave_only <= set(wavy.counters)
     # every emitted key is documented in the one canonical glossary

@@ -62,6 +62,20 @@ def test_ngram_cli_runs():
     assert "n-grams in" in r.stdout and "counters" in r.stdout
 
 
+def test_ngram_cli_splits_a_wide_wave_job():
+    """The analytics setting through the CLI's wave path (sigma 100 on the
+    ClueWeb profile: 50 lanes) runs as the head/tail split, by itself."""
+    import ast
+    r = _run_cli(["--method", "suffix_sigma", "--sigma", "100", "--tau", "5",
+                  "--tokens", "40000", "--profile", "cw",
+                  "--wave-tokens", "10000"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = [l for l in r.stdout.splitlines() if l.startswith("counters:")][0]
+    counters = ast.literal_eval(line.split(":", 1)[1].strip())
+    assert counters["waves"] >= 3
+    assert counters["head_dict_rows"] > 0 and counters["tail_positions"] > 0
+
+
 def test_methods_cli_agree():
     """All four methods via the CLI produce the same number of frequent n-grams."""
     counts = {}
