@@ -65,8 +65,13 @@ COUNTER_DOC: dict[str, str] = {
                        "through the device fold program (merge_route "
                        "\"device\"); 0 on the host routes (wave-only)",
     "d2h_bytes": "bytes materialized device to host when the waves are "
-                 "collected: the reducer outputs, key lanes, skew histogram "
-                 "and counter scalars (wave-only)",
+                 "collected: the segment rows (or, on the stats and mesh "
+                 "routes, the reducer outputs), skew histogram and counter "
+                 "scalars (wave-only)",
+    "collect_host_sorts": "single-device waves whose device-built segment "
+                          "rows were not in segment order across rounds, "
+                          "so the collect sorted them on the host; 0 where "
+                          "a plan's rounds emit rising lengths (wave-only)",
     "head_dict_rows": "frequent full-length heads in the device hash table "
                       "of a head/tail split job; 0 without the split "
                       "(wave-only)",

@@ -16,6 +16,11 @@ map -> combine -> shuffle -> sort -> reduce *across machines*.
     carry updates feeding the next round all trace into a single donated XLA
     program, compiled once per plan and reused by every wave -- a wave is a
     single dispatch, not a per-stage (or per-round) chain of them;
+  * where the caller collects each wave into a segment (the fold paths of
+    :meth:`WaveExecutor.run` and :meth:`WaveExecutor.run_streaming`), the
+    program's **segment form** also builds the wave's segment rows on the
+    device, compacted in segment order (``stages.segment_table``), so the
+    host copies only kept rows and sorts nothing;
   * the wave loop is **device-resident with an overlapped fold**
     (``_for_each_wave``): the main thread only slices host token slabs and
     dispatches fused wave programs, while a background fold thread
@@ -44,8 +49,8 @@ map -> combine -> shuffle -> sort -> reduce *across machines*.
     device-resident between rounds -- traces into ONE jitted ``shard_map``
     program per wave (``_build_mesh_wave_program``), cached per
     ``(n_local, capacity scale, skew?)``.  Reduced lanes fold **on device**
-    into packed segment-candidate rows (``stages.segment_candidates`` -- the
-    prefix-lane-mask collect of the single-device path), so the host never
+    into packed segment-candidate rows (``stages.segment_candidates``, the
+    key formula of the single-device segment form), so the host never
     rebuilds dense ``NGramStats`` per round/shard; shuffle overflow
     accumulates as a device scalar and is checked ONCE per wave at collect
     (the rare trip reruns the whole wave at doubled capacity), which is what
@@ -72,7 +77,9 @@ monolithic code -- just one shared implementation of the stage plumbing.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 
 import jax
@@ -118,6 +125,13 @@ _TAIL_SHARE = 8
 # the frequent-head hash table is padded to a power of two at least this
 # long, so jobs of one size run one compiled tail program
 _HEAD_TABLE_MIN = 1 << 16
+# rows of one chunk of the segment form's table (``stages.segment_table``):
+# a collect copies only the chunks that hold kept rows
+SEGMENT_CHUNK_ROWS = 1 << 22
+# lays out a wave's segment rows a chunk a task: numpy's copies release the
+# interpreter lock, and the first touch of the fresh rows faults in parallel
+_ROW_LAYOUT = ThreadPoolExecutor(min(8, os.cpu_count() or 1),
+                                 thread_name_prefix="segment-rows")
 
 
 def reset_stage_cache() -> None:
@@ -189,7 +203,7 @@ def _stage_core_impl(records, valid, *, n_lanes: int, has_bucket: bool,
     return dense, map_rec, shuffled, hist, rec[:, :n_lanes]
 
 
-def _build_wave_program(cfg, plan: JobPlan):
+def _build_wave_program(cfg, plan: JobPlan, segment: bool = False):
     """Trace one wave's FULL round chain into a single jitted program.
 
     Every round's map emit, the fused stage core, and the tau=1 carry update
@@ -201,14 +215,27 @@ def _build_wave_program(cfg, plan: JobPlan):
     are skipped (``with_positions=False``): only tau>1 carries consume them,
     which the wave regime never takes.
 
+    Each round returns ``(reduced, map-record count, post-combine
+    live-record count, skew histogram)``.  The dense form's ``reduced`` is
+    the reducer's (terms, flags, counts) and the sorted key lanes, for the
+    stats collect; the ``segment`` form's is the round's finished segment
+    rows, compacted on the device in segment order
+    (``stages.segment_table``: per-length counts and the chunks of the
+    lane-major table), for the collect of a wave into a segment.  That
+    form needs the record lanes in the segment layout
+    (``WaveExecutor._direct``).
+
     Each stage traces under a ``jax.named_scope`` (emit, combine, partition,
-    sort, reduce), so a profile's op metadata names the stage of every
-    device op.  The function keeps its name: the benchmark's readers find
-    the program as the XLA module ``jit_wave_fn``.
+    sort, reduce, segment_table), so a profile's op metadata names the
+    stage of every device op.  Both forms keep the function's name: the
+    benchmark's readers find the program as the XLA module ``jit_wave_fn``.
     """
     lane_vocab = plan.effective_lane_vocab(cfg)
     n_l = packing.n_lanes(cfg.sigma, lane_vocab)
     combine_route = plan.combine.route if plan.combine is not None else None
+    if segment:
+        masks = jnp.asarray(packing.prefix_lane_masks(cfg.sigma, lane_vocab))
+        chunk = SEGMENT_CHUNK_ROWS
 
     def wave_fn(tok_ext, n_live):
         carry = None
@@ -223,7 +250,14 @@ def _build_wave_program(cfg, plan: JobPlan):
                 sigma=cfg.sigma, lane_vocab=lane_vocab,
                 shuffle_key=plan.shuffle.key, reduce_kind=plan.reduce.kind,
                 with_positions=False, n_buckets=0)
-            rounds.append((dense[:3], map_rec, shuffled, hist, lanes))
+            if segment:
+                with jax.named_scope("segment_table"):
+                    reduced = stages.segment_table(
+                        dense[1], dense[2], lanes, masks, sigma=cfg.sigma,
+                        reduce_kind=plan.reduce.kind, chunk=chunk)
+            else:
+                reduced = (dense[:3], lanes)
+            rounds.append((reduced, map_rec, shuffled, hist))
             if k < plan.rounds and plan.update_carry is not None:
                 carry = plan.update_carry(cfg, 1, k, tok_ext, None, {},
                                           emit_extras, carry)
@@ -233,12 +267,13 @@ def _build_wave_program(cfg, plan: JobPlan):
     return jax.jit(wave_fn, donate_argnums=donate)
 
 
-def _wave_core(cfg, plan: JobPlan, tok_ext, n_live: int):
+def _wave_core(cfg, plan: JobPlan, tok_ext, n_live: int,
+               segment: bool = False):
     """Dispatch one wave through the cached fused program (one dispatch)."""
-    key = (jax.default_backend(), plan, cfg)
+    key = (jax.default_backend(), plan, cfg, segment and SEGMENT_CHUNK_ROWS)
     fn = _WAVE_PROGRAMS.get(key)
     if fn is None:
-        fn = _WAVE_PROGRAMS[key] = _build_wave_program(cfg, plan)
+        fn = _WAVE_PROGRAMS[key] = _build_wave_program(cfg, plan, segment)
     return fn(tok_ext, n_live)
 
 
@@ -325,6 +360,58 @@ def _prefix_rows(keep, counts, lanes, masks, first_len: int = 1):
     pref = lanes[rows] & masks[lengths]
     return (np.concatenate([lengths[:, None], pref], axis=1).astype(np.uint32),
             counts[rows, lens0].astype(np.uint32))
+
+
+def _table_chunks(chunks, n_rows: int, n_cols: int):
+    """The chunks of a segment table (``stages.segment_table``, ``n_cols``
+    columns) that hold its first ``n_rows`` rows."""
+    rows = chunks[0].shape[0] // n_cols
+    return chunks[:-(-n_rows // rows)]
+
+
+def _table_rows(per_lens, tables, n_l: int):
+    """Segment rows (length | key lanes) and counts of the kept rows of
+    each round's segment table, the rounds one after another, and the row
+    at which each round starts.  ``per_lens[k]`` counts round ``k``'s kept
+    rows by length, which gives the length column; ``tables[k]`` holds the
+    host copies of the chunks that hold them."""
+    starts = np.cumsum([0] + [int(p.sum()) for p in per_lens])
+    keys = np.empty((int(starts[-1]), 1 + n_l), np.uint32)
+    cnts = np.empty((int(starts[-1]),), np.uint32)
+    tasks = []
+    for start, end, per_len, chunks in zip(starts, starts[1:], per_lens,
+                                           tables):
+        bounds = (start + np.cumsum([0] + per_len.tolist())).tolist()
+        off = start
+        for c in chunks:
+            cols = c.reshape(n_l + 1, -1)
+            tasks.append((cols, off, min(off + cols.shape[1], end), bounds))
+            off += cols.shape[1]
+
+    def lay_out(task):
+        cols, lo, hi, bounds = task
+        keys[lo:hi, 1:] = cols[:n_l, :hi - lo].T
+        cnts[lo:hi] = cols[n_l, :hi - lo]
+        for length, (a, b) in enumerate(zip(bounds, bounds[1:]), 1):
+            if max(a, lo) < min(b, hi):
+                keys[max(a, lo):min(b, hi), 0] = length
+
+    for _ in _ROW_LAYOUT.map(lay_out, tasks):
+        pass
+    return keys, cnts, starts[:-1]
+
+
+def _segment_order(keys: np.ndarray, cnts: np.ndarray, starts):
+    """Rows made of runs that are each in segment order, in segment order:
+    unchanged where each run's last key is below the next run's first, else
+    stable-sorted on the byte view.  Returns (keys, counts, sorted?)."""
+    from repro.index._layout import row_bytes_view
+    bounds = [int(b) for b in starts[1:] if 0 < b < len(keys)]
+    if all(tuple(keys[b - 1].tolist()) < tuple(keys[b].tolist())
+           for b in bounds):
+        return keys, cnts, False
+    order = np.argsort(row_bytes_view(keys), kind="stable")
+    return keys[order], cnts[order], True
 
 
 def _run_rounds(tok_ext, aux_ext, n_live: int, cfg, plan: JobPlan,
@@ -679,7 +766,8 @@ class WaveExecutor:
 
     # --- single-device async wave dispatch ----------------------------------- #
 
-    def _submit_wave(self, tok_ext, n_live: int, wave: int = 0) -> dict:
+    def _submit_wave(self, tok_ext, n_live: int, wave: int = 0, *,
+                     segment: bool = False) -> dict:
         """Dispatch one wave as ONE fused program; nothing materializes here.
 
         The wave regime always runs at ``tau_eff = 1``, where carries are a
@@ -691,7 +779,8 @@ class WaveExecutor:
         round chain emits empty partials that fold to nothing.  With a mesh,
         the wave dispatches through the fused sharded program instead
         (:meth:`_submit_wave_mesh`) -- same async contract.  ``wave`` is the
-        wave's index, carried to the collect's spans.
+        wave's index, carried to the collect's spans.  ``segment`` runs the
+        program's segment form, for :meth:`_collect_wave_segment`.
         """
         if self._use_mesh:
             return self._submit_wave_mesh(tok_ext, n_live, wave)
@@ -704,12 +793,20 @@ class WaveExecutor:
             with obs_trace.span("round.stages") as sp_s:
                 if sp_s:
                     sp_s.set(fused_rounds=plan.rounds)
-                rounds = _wave_core(cfg, plan, tok_ext, n_live)
+                rounds = _wave_core(cfg, plan, tok_ext, n_live, segment)
             rec_bytes = packing.record_bytes(
                 cfg.sigma, plan.effective_lane_vocab(cfg),
                 n_meta=plan.map.n_meta)
             return {"rounds": list(rounds), "rec_bytes": rec_bytes,
-                    "wave": wave}
+                    "wave": wave, "segment": segment}
+
+    def _submit_segment_wave(self, tok_ext, n_live: int,
+                             wave: int = 0) -> dict:
+        """Dispatch one wave for :meth:`_collect_wave_segment`: the wave
+        program's segment form where the record lanes are the segment
+        layout (``self._direct``), else as :meth:`_submit_wave`."""
+        return self._submit_wave(tok_ext, n_live, wave,
+                                 segment=self._direct)
 
     def _collect_wave(self, pend: dict):
         """Materialize a submitted wave -> exact ``NGramStats`` partial.
@@ -730,7 +827,7 @@ class WaveExecutor:
                 _wait_for_device(pend["rounds"], w)
             counters: dict = {}
             out = None
-            for dense, map_rec, shuffled, hist, _lanes in pend["rounds"]:
+            for (dense, _lanes), map_rec, shuffled, hist in pend["rounds"]:
                 with obs_trace.span("wave.collect.d2h") as sp_d:
                     (terms, flags, counts, hist, map_rec, shuffled), nbytes = \
                         _to_host(*dense, hist, map_rec, shuffled)
@@ -766,65 +863,68 @@ class WaveExecutor:
     def _collect_wave_segment(self, pend: dict) -> WavePartial:
         """Materialize a submitted wave straight into a sorted host segment.
 
-        The fold-path twin of :meth:`_collect_wave` that never leaves packed
-        space: the reducer already walked the *sorted* record block, so its
-        key lanes ARE the packed gram lanes in lex order, and a kept row of
-        length ``l`` has segment key ``(l | lanes & prefix_mask[l])``
-        (zeroing a term slot's bits == packing PAD there).  Rows come out of
-        ``nonzero(keep.T)`` in (length, lane-rank) order -- segment order --
-        so the closing stable byte-view argsort is a linear verification
-        pass for single-round plans and a galloping merge of the per-round
-        sorted runs otherwise.  Skips the stats detour entirely: no term
-        unpack, no gram re-pack, no ``terms`` d2h.  Bit-identical to
-        ``segment_from_wave_stats(_collect_wave(pend))`` because both
-        reduce to the same (key, count) row set in the same canonical
-        order; requires the lane/segment pack layouts to coincide
-        (``self._direct``) -- other configs take exactly that stats route.
+        The wave program's segment form (:meth:`_submit_segment_wave`)
+        already built each round's rows on the device, compacted in segment
+        order (``stages.segment_table``).  The first sync reads each round's
+        per-length counts; then only the table chunks that hold kept rows
+        come over (``wave.collect.d2h``), and the host lays them out as
+        (length | key lanes) rows, the length column rebuilt from the
+        counts (``wave.collect.rows``).  Each round's rows are in segment
+        order by construction, so ``wave.collect.sort`` only checks that
+        each round's last key is below the next round's first, and sorts
+        (stable, on the byte view) only where that fails: counter
+        ``collect_host_sorts``.  Bit-identical to
+        ``segment_from_wave_stats(_collect_wave(pend))``: both are the same
+        (key, count) row set in the same canonical order.  A wave
+        dispatched in the dense form (lanes not in the segment layout)
+        takes exactly that stats route; mesh waves their sharded twin.
         """
         if pend.get("mesh"):
             return self._collect_wave_segment_mesh(pend)
-        if not self._direct:
+        if not pend["segment"]:
             return self._partial_from_stats(self._collect_wave(pend))
-        from repro.index._layout import row_bytes_view
         from repro.index.build import IndexSegment
 
         cfg = self.cfg
         w = pend["wave"]
+        rounds = pend["rounds"]
+        n_l = packing.n_lanes(cfg.sigma, cfg.vocab_size)
         with obs_trace.span("wave.collect") as sp:
             if sp:
                 sp.set(wave=w)
-                _wait_for_device(pend["rounds"], w)
-            counters: dict = {}
-            masks = self._prefix_masks()
-            key_parts, cnt_parts = [], []
-            for dense, map_rec, shuffled, hist, lanes in pend["rounds"]:
-                with obs_trace.span("wave.collect.d2h") as sp_d:
-                    (flags, counts, lanes, hist, map_rec, shuffled), nbytes = \
-                        _to_host(dense[1], dense[2], lanes, hist, map_rec,
-                                 shuffled)
-                    if sp_d:
-                        sp_d.set(wave=w, bytes=nbytes)
-                with obs_trace.span("wave.collect.rows") as sp_r:
-                    if sp_r:
-                        sp_r.set(wave=w)
-                    _add_round_counters(counters, map_rec, shuffled, hist,
-                                        pend["rec_bytes"], nbytes)
-                    # from_dense's keep at the wave regime's tau = 1
-                    keys, cnts = _prefix_rows((flags != 0) & (counts >= 1),
-                                              counts, lanes, masks)
-                    key_parts.append(keys)
-                    cnt_parts.append(cnts)
+                _wait_for_device(rounds, w)
+            with obs_trace.span("wave.collect.d2h") as sp_d:
+                small, nbytes = _to_host(*(
+                    x for (per_len, _), map_rec, shuffled, hist in rounds
+                    for x in (per_len, map_rec, shuffled, hist)))
+                per_lens = small[0::4]
+                need = [_table_chunks(chunks, int(p.sum()), n_l + 1)
+                        for p, ((_, chunks), *_) in zip(per_lens, rounds)]
+                for c in (c for cs in need for c in cs):
+                    c.copy_to_host_async()
+                tables = []
+                for cs in need:
+                    host, b = _to_host(*cs)
+                    tables.append(host)
+                    nbytes += b
+                if sp_d:
+                    sp_d.set(wave=w, bytes=nbytes)
             with obs_trace.span("wave.collect.rows") as sp_r:
                 if sp_r:
                     sp_r.set(wave=w)
-                keys = np.concatenate(key_parts, axis=0)
-                cnts = np.concatenate(cnt_parts, axis=0)
+                counters: dict = {}
+                for k in range(len(rounds)):
+                    _add_round_counters(counters, *small[4 * k + 1:4 * k + 4],
+                                        pend["rec_bytes"],
+                                        nbytes if k == 0 else 0)
+                keys, cnts, starts = _table_rows(per_lens, tables, n_l)
             with obs_trace.span("wave.collect.sort") as sp_s:
                 if sp_s:
                     sp_s.set(wave=w, rows=int(keys.shape[0]))
-                order = np.argsort(row_bytes_view(keys), kind="stable")
-                seg = IndexSegment(keys=keys[order], counts=cnts[order],
-                                   sigma=cfg.sigma, vocab_size=cfg.vocab_size)
+                keys, cnts, sorted_ = _segment_order(keys, cnts, starts)
+                counters["collect_host_sorts"] = int(sorted_)
+                seg = IndexSegment(keys=keys, counts=cnts, sigma=cfg.sigma,
+                                   vocab_size=cfg.vocab_size)
             if sp:
                 sp.set(rows=int(keys.shape[0]), shuffle_records=counters.get(
                     "shuffle_records", 0))
@@ -1213,8 +1313,10 @@ class WaveExecutor:
         (default :meth:`_collect_wave` -> ``NGramStats``; the fold paths
         pass :meth:`_collect_wave_segment` -> :class:`WavePartial`); both
         route mesh waves to their sharded twins via the pend dict.
-        ``submit`` dispatches a window (default :meth:`_submit_wave`; the
-        split's tail pass dispatches through :meth:`_submit_tail`).
+        ``submit`` dispatches a window the way ``collect`` reads it (default
+        :meth:`_submit_wave`; the fold paths pass
+        :meth:`_submit_segment_wave`, the split's tail pass
+        :meth:`_submit_tail`).
 
         The wave-level parallel fold: the main thread stays a pure *feeder*
         -- it slices host token slabs and dispatches one fused program per
@@ -1263,7 +1365,10 @@ class WaveExecutor:
 
         folder = threading.Thread(target=fold_loop, name="wave-fold",
                                   daemon=True)
-        folder.start()
+        # ``start`` returns once the OS has run the new thread: milliseconds
+        # on a loaded host
+        with obs_trace.span("wave.feed.start"):
+            folder.start()
         try:
             for w, (tok_ext, n_live) in enumerate(
                     self._windows(tokens, to_device=to_device)):
@@ -1276,8 +1381,10 @@ class WaveExecutor:
                         sp.set(wave=w)
                     work.put(pend)
         finally:
-            work.put(None)
-            folder.join()
+            # the feeder waits for the fold thread to finish the last waves
+            with obs_trace.span("wave.feed.drain"):
+                work.put(None)
+                folder.join()
         if failure:
             raise failure[0]
 
@@ -1304,8 +1411,8 @@ class WaveExecutor:
             counters = dict.fromkeys(
                 ("jobs", "map_records", "shuffle_records", "shuffle_bytes",
                  "retries", "overflow", "waves", "fold_rows",
-                 "finalize_blocks", "d2h_bytes", "head_dict_rows",
-                 "tail_positions", "tail_retries"), 0)
+                 "finalize_blocks", "d2h_bytes", "collect_host_sorts",
+                 "head_dict_rows", "tail_positions", "tail_retries"), 0)
             counters["shuffle_skew"] = 0.0
             if self._head_ex is not None:
                 out = self._run_split(tokens, counters)
@@ -1354,7 +1461,8 @@ class WaveExecutor:
                     sp.set(wave=counters["waves"] - 1, rows=part.n_rows)
                 acc.push(part.segment, n_rows=part.n_rows)
 
-        self._for_each_wave(tokens, fold, collect=self._collect_wave_segment)
+        self._for_each_wave(tokens, fold, collect=self._collect_wave_segment,
+                            submit=self._submit_segment_wave)
 
     def _finalize(self, acc, loading, counters: dict):
         """The accumulator's fold at the job's tau -> one sorted segment (a
@@ -1538,5 +1646,6 @@ class WaveExecutor:
                 part.segment if part.n_rows else None, n_rows=part.n_rows))
 
         self._for_each_wave(tokens, ingest,
-                            collect=self._collect_wave_segment)
+                            collect=self._collect_wave_segment,
+                            submit=self._submit_segment_wave)
         return gen, reports

@@ -184,49 +184,112 @@ def reduce_exact(rec: jax.Array, *, sigma: int, vocab_size: int,
 
 
 # ------------------------------------------------- device-side segment collect
+def segment_cells(flags: jax.Array, counts: jax.Array, lanes: jax.Array,
+                  masks: jax.Array, *, sigma: int, reduce_kind: str):
+    """The cells of a reducer's dense output as segment rows.
+
+    A kept cell of length ``l`` has segment key ``(l | lanes & masks[l])``
+    (zeroing a term slot's bit field == packing PAD there -- see
+    ``mapreduce.pack.prefix_lane_masks``) and its run's count; a cell is
+    kept where it is flagged with a count of at least 1 (``from_dense`` at
+    the wave regime's tau = 1).  ``"suffix"`` reducers may keep several
+    lengths per row, so their cells are the [N, sigma] grid (cell (r, l) is
+    the length-(l+1) prefix run of row r); ``"exact"`` reducers keep at most
+    one, the row's own gram length, so their cells are the [N] rows.
+    Returns (keep, length uint32, key lanes [..., n_l], count), each with
+    the cells' shape; a row that keeps nothing has length 0 and key lanes 0.
+    """
+    n = lanes.shape[0]
+    keep = (flags != 0) & (counts >= 1)
+    if reduce_kind == "suffix":
+        length = jnp.broadcast_to(jnp.arange(1, sigma + 1, dtype=jnp.uint32),
+                                  keep.shape)
+        pref = jnp.stack([lanes & masks[l] for l in range(1, sigma + 1)],
+                         axis=1)                              # [N, sigma, n_l]
+        return keep, length, pref, counts
+    len_idx = jnp.argmax(keep, axis=1)                        # 0 when dead
+    keep_row = jnp.any(keep, axis=1)
+    length = jnp.where(keep_row, (len_idx + 1).astype(jnp.uint32),
+                       jnp.uint32(0))
+    return keep_row, length, lanes & masks[length], \
+        counts[jnp.arange(n), len_idx]
+
+
 def segment_candidates(flags: jax.Array, counts: jax.Array, lanes: jax.Array,
                        masks: jax.Array, *, sigma: int, reduce_kind: str):
     """Packed segment-candidate rows straight off a reducer's dense output.
 
-    The traceable twin of the host collect in
-    ``WaveExecutor._collect_wave_segment``: a kept row of length ``l`` has
-    segment key ``(l | lanes & masks[l])`` (zeroing a term slot's bit field
-    == packing PAD there -- see ``mapreduce.pack.prefix_lane_masks``), so the
-    candidate table is a pure elementwise function of (flags, counts, sorted
-    key lanes) and folds into the fused wave program -- the host never sees
-    dense reducer output, only flat ``(length | prefix lanes, count)`` rows
-    with dead rows zeroed (length 0, count 0).  Shapes are static:
-    ``"suffix"`` reducers may keep several lengths per row (one candidate per
-    (row, length) cell), ``"exact"`` reducers keep at most one (the row's own
-    gram length), so the table is [N * sigma] or [N] rows respectively.
+    The mesh wave program's collect: the candidate table is a pure
+    elementwise function of (flags, counts, sorted key lanes)
+    (:func:`segment_cells`) and folds into the fused program -- the host
+    never sees dense reducer output, only flat ``(length | prefix lanes,
+    count)`` rows with dead rows zeroed (length 0, count 0).  Shapes are
+    static: [N * sigma] rows for ``"suffix"`` reducers, [N] for ``"exact"``.
 
     Candidate *order* is deliberately unspecified: within one wave every kept
     gram key is unique across rounds (rounds emit disjoint lengths; a sorted
     reducer block flags each run once), so the collector's closing stable
     byte-view sort is a pure function of the row set.
     """
+    keep, length, pref, cnt = segment_cells(flags, counts, lanes, masks,
+                                            sigma=sigma,
+                                            reduce_kind=reduce_kind)
+    keys = jnp.concatenate(
+        [jnp.where(keep, length, jnp.uint32(0))[..., None],
+         jnp.where(keep[..., None], pref, jnp.uint32(0))], axis=-1)
+    cnts = jnp.where(keep, cnt, 0).astype(jnp.uint32)
+    n_cols = keys.shape[-1]
+    return keys.reshape(-1, n_cols), cnts.reshape(-1)
+
+
+def segment_table(flags: jax.Array, counts: jax.Array, lanes: jax.Array,
+                  masks: jax.Array, *, sigma: int, reduce_kind: str,
+                  chunk: int):
+    """A reducer's kept cells as finished segment rows, compacted on the
+    device in segment order: the single-device wave program's collect.
+
+    Cells (:func:`segment_cells`) are ranked by (length, row rank): for a
+    ``"suffix"`` reducer the column-major walk of its grid, for an
+    ``"exact"`` one its rows grouped by gram length.  The reducer walked a
+    sorted record block, so within one length the kept keys ascend: that
+    rank order is segment order.  One sort keyed by the rank (dead cells
+    last) compacts them.  The table is lane-major, ``n_l`` key-lane columns
+    then the count column, and comes as a tuple of flat chunks of ``chunk``
+    rows each (the last may be shorter): chunk ``c`` holds rows ``[c *
+    chunk, c * chunk + chunk)``, column ``j`` at ``[j * rows, (j + 1) *
+    rows)``.  The capacity is every cell, so nothing can overflow; a caller
+    copies only the chunks that hold kept rows.  The length column is left
+    off: a caller rebuilds it from ``per_len`` [sigma] int32, the kept cells
+    of each length.
+    """
+    keep, length, pref, cnt = segment_cells(flags, counts, lanes, masks,
+                                            sigma=sigma,
+                                            reduce_kind=reduce_kind)
     n, n_l = lanes.shape
-    keep = (flags != 0) & (counts >= 1)
     if reduce_kind == "suffix":
-        # [N, sigma] grid: candidate (r, l) is the length-(l+1) prefix run
-        pref = jnp.stack([lanes & masks[l] for l in range(1, sigma + 1)],
-                         axis=1)                              # [N, sigma, n_l]
-        lens = jnp.where(keep, jnp.arange(1, sigma + 1, dtype=jnp.uint32),
-                         jnp.uint32(0))
-        keys = jnp.concatenate(
-            [lens[..., None],
-             jnp.where(keep[..., None], pref, jnp.uint32(0))], axis=-1)
-        cnts = jnp.where(keep, counts, 0).astype(jnp.uint32)
-        return keys.reshape(n * sigma, 1 + n_l), cnts.reshape(n * sigma)
-    # exact: at most one flagged length per row -- no sigma blowup
-    len_idx = jnp.argmax(keep, axis=1)                        # 0 when dead
-    keep_row = jnp.any(keep, axis=1)
-    length = jnp.where(keep_row, (len_idx + 1).astype(jnp.uint32),
-                       jnp.uint32(0))
-    pref = jnp.where(keep_row[:, None], lanes & masks[length], jnp.uint32(0))
-    cnt = jnp.where(keep_row, counts[jnp.arange(n), len_idx],
-                    0).astype(jnp.uint32)
-    return jnp.concatenate([length[:, None], pref], axis=1), cnt
+        keep_c = keep.T.reshape(-1)
+        cols = [pref[..., j].T.reshape(-1) for j in range(n_l)] \
+            + [cnt.T.reshape(-1)]
+        rank = jnp.arange(n * sigma, dtype=jnp.uint32)
+        per_len = jnp.sum(keep, axis=0, dtype=jnp.int32)
+    else:
+        keep_c = keep
+        cols = [pref[:, j] for j in range(n_l)] + [cnt]
+        rank = (length - 1) * jnp.uint32(n) + jnp.arange(n, dtype=jnp.uint32)
+        per_len = jnp.sum(keep[:, None] & (length[:, None] == jnp.arange(
+            1, sigma + 1, dtype=jnp.uint32)), axis=0, dtype=jnp.int32)
+    if n * sigma >= 0xFFFFFFFF:
+        raise ValueError(f"{n} rows x sigma {sigma} cells overflow the "
+                         "uint32 rank of the segment table")
+    # a kept cell's rank is below n * sigma: the dead cells sort after it
+    key = jnp.where(keep_c, rank, jnp.uint32(0xFFFFFFFF))
+    cols = sort.sort_columns([key] + [c.astype(jnp.uint32) for c in cols],
+                             num_keys=1)[1:]
+    cap = cols[0].shape[0]
+    chunks = tuple(
+        jnp.concatenate([c[start:start + chunk] for c in cols])
+        for start in range(0, cap, chunk))
+    return per_len, chunks
 
 
 # ----------------------------------------------------------- canonical output

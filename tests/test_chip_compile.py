@@ -109,29 +109,51 @@ def test_refused_kernels_fail_loudly(one_chip, no_compile_cache):
             fn.lower(*args, **kw, interpret=False).compile()
 
 
-def test_fused_wave_program_compiles_for_v5e(one_chip, no_compile_cache):
-    """The suffix-sigma wave program (NYT profile, sigma 5) compiles for one
-    chip, and its per-token footprint keeps ``chip_smoke``'s wave size --
-    one wave running and two in flight -- under half of the 16 GB HBM."""
+WAVE = 1 << 16
+
+
+def _wave_memory(one_chip, segment: bool):
+    """``memory_analysis()`` of the suffix-sigma wave program (NYT profile,
+    sigma 5) compiled for one chip at a ``WAVE``-token wave."""
     import jax
     import jax.numpy as jnp
     from repro.core import NGramConfig
     from repro.data.corpus import NYT
     from repro.pipeline import executor
     from repro.pipeline.plan import plan_for
+
+    cfg = NGramConfig(sigma=5, tau=10, vocab_size=NYT.vocab_size)
+    fn = jax.jit(executor._build_wave_program(cfg, plan_for(cfg), segment),
+                 donate_argnums=(0,))
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct((WAVE + cfg.sigma - 1,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    return compiled.memory_analysis()
+
+
+def test_fused_wave_program_compiles_for_v5e(one_chip, no_compile_cache):
+    """The suffix-sigma wave program (NYT profile, sigma 5) compiles for one
+    chip, and its per-token footprint keeps ``chip_smoke``'s wave size --
+    one wave running and two in flight -- under half of the 16 GB HBM."""
     sys.path.insert(0, str(ROOT))
     import chip_smoke
 
-    cfg = NGramConfig(sigma=5, tau=10, vocab_size=NYT.vocab_size)
-    wave = 1 << 16
-    fn = jax.jit(executor._build_wave_program(cfg, plan_for(cfg)),
-                 donate_argnums=(0,))
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct((wave + cfg.sigma - 1,), jnp.int32,
-                             sharding=one_chip),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
-    mem = compiled.memory_analysis()
-    per_token = (mem.temp_size_in_bytes + mem.output_size_in_bytes) / wave
+    mem = _wave_memory(one_chip, segment=False)
+    per_token = (mem.temp_size_in_bytes + mem.output_size_in_bytes) / WAVE
+    assert 0 < per_token * chip_smoke.WAVE_TOKENS * 3 < 8e9
+
+
+def test_segment_wave_program_compiles_for_v5e(one_chip, no_compile_cache):
+    """The wave program's segment form (each wave's segment rows built and
+    compacted on the device: ``stages.segment_table``) compiles for one
+    chip, and its footprint keeps ``chip_smoke``'s wave size under half of
+    the 16 GB HBM as the dense form's does."""
+    sys.path.insert(0, str(ROOT))
+    import chip_smoke
+
+    mem = _wave_memory(one_chip, segment=True)
+    per_token = (mem.temp_size_in_bytes + mem.output_size_in_bytes) / WAVE
     assert 0 < per_token * chip_smoke.WAVE_TOKENS * 3 < 8e9
 
 

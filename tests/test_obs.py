@@ -303,7 +303,8 @@ def test_counters_parity_monolithic_vs_wave(method):
     wave_only = {k for k, doc in obs_metrics.COUNTER_DOC.items()
                  if doc.endswith("(wave-only)")}
     assert wave_only == {"waves", "fold_rows", "finalize_blocks", "d2h_bytes",
-                         "head_dict_rows", "tail_positions", "tail_retries"}
+                         "collect_host_sorts", "head_dict_rows",
+                         "tail_positions", "tail_retries"}
     assert set(wavy.counters) - wave_only == set(mono.counters)
     assert wave_only <= set(wavy.counters)
     # every emitted key is documented in the one canonical glossary

@@ -536,11 +536,11 @@ def test_fused_wave_one_stage_dispatch_per_wave():
 
 
 def test_direct_segment_collect_matches_stats_route():
-    """The packed-lane collect (``_collect_wave_segment``: keys built as
-    ``lanes & prefix_mask[len]`` straight off the sorted records) must
-    produce the exact segment of the stats detour
-    (``segment_from_wave_stats(_collect_wave(...))``) -- per wave, every
-    method."""
+    """The segment collect (``_collect_wave_segment`` over the wave
+    program's segment form: rows built on the device as ``length | lanes &
+    prefix_mask[len]``) must produce the exact segment of the stats detour
+    (``segment_from_wave_stats(_collect_wave(...))`` over the dense form)
+    -- per wave, every method.  Each form gets the wave once."""
     from repro.index.build import segment_from_wave_stats
 
     toks = make_corpus(300, 23, "zipf", seed=9)
@@ -550,15 +550,126 @@ def test_direct_segment_collect_matches_stats_route():
         ex = WaveExecutor(cfg, wave_tokens=61)
         assert ex._direct
         for tok_ext, n_live in ex._windows(np.asarray(toks, np.int32)):
-            pend = ex._submit_wave(tok_ext, n_live)
-            part = ex._collect_wave_segment(pend)
-            want = segment_from_wave_stats(ex._collect_wave(pend),
-                                           vocab_size=cfg.vocab_size)
+            part = ex._collect_wave_segment(
+                ex._submit_segment_wave(tok_ext, n_live))
+            want = segment_from_wave_stats(
+                ex._collect_wave(ex._submit_wave(tok_ext, n_live)),
+                vocab_size=cfg.vocab_size)
             assert part.n_rows == want.n_rows, method
             np.testing.assert_array_equal(np.asarray(part.segment.keys),
                                           np.asarray(want.keys))
             np.testing.assert_array_equal(np.asarray(part.segment.counts),
                                           np.asarray(want.counts))
+
+
+def host_segment_rows(ex, tok_ext, n_live):
+    """The reference: a wave's segment rows built on the host from the
+    dense program's reducer output -- each round's kept cells through
+    ``_prefix_rows``, the rounds concatenated, then a stable byte-view
+    argsort."""
+    from repro.index._layout import row_bytes_view
+    from repro.pipeline.executor import _prefix_rows
+
+    masks = ex._prefix_masks()
+    keys, cnts = [], []
+    for ((_, flags, counts), lanes), *_ in ex._submit_wave(
+            tok_ext, n_live)["rounds"]:
+        flags, counts, lanes = (np.asarray(x) for x in (flags, counts, lanes))
+        k, c = _prefix_rows((flags != 0) & (counts >= 1), counts, lanes,
+                            masks)
+        keys.append(k)
+        cnts.append(c)
+    keys, cnts = np.concatenate(keys), np.concatenate(cnts)
+    order = np.argsort(row_bytes_view(keys), kind="stable")
+    return keys[order], cnts[order]
+
+
+@pytest.mark.parametrize("chunk", [16, 1 << 22])
+@pytest.mark.parametrize("method", sorted(METHODS))
+def test_device_segment_rows_match_host_reference(method, chunk,
+                                                  monkeypatch):
+    """The rows the wave program builds on the device, collected, equal
+    the host path's bit for bit (single- and multi-round plans; with
+    16-row chunks a wave's rows cross many chunk boundaries), and no wave
+    needed a host sort: every plan's rounds emit rising lengths."""
+    from repro.pipeline import executor
+    monkeypatch.setattr(executor, "SEGMENT_CHUNK_ROWS", chunk)
+    toks = make_corpus(400, 29, "zipf", seed=21)
+    cfg = NGramConfig(sigma=4, tau=2, vocab_size=29, method=method,
+                      apriori_index_k=2)
+    ex = WaveExecutor(cfg, wave_tokens=97)
+    rows = []
+    for w, (tok_ext, n_live) in enumerate(
+            ex._windows(np.asarray(toks, np.int32))):
+        part = ex._collect_wave_segment(
+            ex._submit_segment_wave(tok_ext, n_live, w))
+        keys, cnts = host_segment_rows(ex, tok_ext, n_live)
+        np.testing.assert_array_equal(part.segment.keys, keys)
+        np.testing.assert_array_equal(part.segment.counts, cnts)
+        assert part.counters["collect_host_sorts"] == 0
+        assert part.n_rows == len(keys)
+        rows.append(part.n_rows)
+    assert max(rows) > 4 * 16      # 16-row chunks: several a wave
+    got = ex.run(toks)
+    assert got.counters["collect_host_sorts"] == 0
+    assert_stats_equal(got, run_job(toks, cfg))
+
+
+@pytest.mark.parametrize("chunk", [7, 16, 1 << 22])
+def test_segment_table_fills_its_capacity(chunk):
+    """A block of all-distinct full-length grams keeps every cell of the
+    suffix reducer's [N, sigma] grid, so the device table is full to the
+    last row of its last chunk; its rows equal the host reference's."""
+    import jax.numpy as jnp
+    from repro.mapreduce import pack as packing
+    from repro.pipeline import stages
+    from repro.pipeline.executor import _prefix_rows, _table_chunks, \
+        _table_rows
+
+    sigma, vocab, n = 4, 200, 48
+    rng = np.random.default_rng(4)
+    terms = rng.integers(1, vocab + 1, size=(n, sigma)).astype(np.int32)
+    terms[:, 0] = rng.permutation(np.arange(1, vocab + 1))[:n]
+    lanes = packing.pack_terms_np(terms, vocab_size=vocab)
+    rec = np.concatenate([lanes, np.ones((n, 1), np.uint32)], axis=1)
+    rec = rec[np.lexsort(lanes.T[::-1])]
+    masks = packing.prefix_lane_masks(sigma, vocab)
+    _, flags, counts = stages.reduce_suffix(jnp.asarray(rec), sigma=sigma,
+                                            vocab_size=vocab)
+    n_l = lanes.shape[1]
+    per_len, chunks = stages.segment_table(
+        flags, counts, jnp.asarray(rec[:, :n_l]), jnp.asarray(masks),
+        sigma=sigma, reduce_kind="suffix", chunk=chunk)
+    per_len = np.asarray(per_len)
+    assert per_len.tolist() == [n] * sigma
+    need = _table_chunks(chunks, n * sigma, n_l + 1)
+    assert len(need) == len(chunks) == -(-n * sigma // chunk)
+    keys, cnts, starts = _table_rows([per_len],
+                                     [[np.asarray(c) for c in need]], n_l)
+    want_keys, want_cnts = _prefix_rows(
+        (np.asarray(flags) != 0) & (np.asarray(counts) >= 1),
+        np.asarray(counts), rec[:, :n_l], masks)
+    np.testing.assert_array_equal(keys, want_keys)
+    np.testing.assert_array_equal(cnts, want_cnts)
+    assert starts.tolist() == [0]
+
+
+def test_segment_collect_sorts_rounds_out_of_order():
+    """Where a round's rows do not follow the previous round's, the collect
+    falls back to the stable host sort, still equal to the reference, and
+    counts the wave in ``collect_host_sorts``."""
+    toks = make_corpus(300, 23, "zipf", seed=9)
+    cfg = NGramConfig(sigma=4, tau=2, vocab_size=23, method="apriori_scan")
+    ex = WaveExecutor(cfg)
+    (tok_ext, n_live), = ex._windows(np.asarray(toks, np.int32))
+    pend = ex._submit_segment_wave(tok_ext, n_live)
+    assert len(pend["rounds"]) > 1
+    pend["rounds"].reverse()
+    part = ex._collect_wave_segment(pend)
+    keys, cnts = host_segment_rows(ex, tok_ext, n_live)
+    np.testing.assert_array_equal(part.segment.keys, keys)
+    np.testing.assert_array_equal(part.segment.counts, cnts)
+    assert part.counters["collect_host_sorts"] == 1
 
 
 def test_wave_parity_unpacked_lane_fallback():
