@@ -45,11 +45,9 @@ def main() -> None:
           f"of <= {DEVICE_BUDGET_TOKENS} -> {len(stats)} frequent grams "
           f"in {dt:.1f}s ({c['map_records']:.0f} map records)")
 
-    # the wave fold is size-tiered (LSM rungs, like the serving index), so
-    # merge work amortizes to O(total log waves) instead of re-merging the
-    # whole running segment every wave; benchmarks/waves.py measures the
-    # pairwise-vs-tiered gap at 16+ waves
-    print(f"segment fold work (tiered accumulator): "
+    # the wave fold is deferred: wave segments stack and merge once, k-way,
+    # at the end, so the fold work is exactly the waves' total rows
+    print(f"segment fold work (deferred fold): "
           f"{int(c['fold_rows'])} rows through merge_segments")
 
     # exactness receipt: the monolithic job (which *can* still run at this

@@ -16,8 +16,7 @@ from .build import (IndexSegment, NGramIndex, build_index, index_from_segment,
                     segment_from_stats)
 from .compress import (CompressedNGramIndex, EliasFano, build_compressed_index,
                        compress_index, decode_segment)
-from .merge import (GenerationalIndex, PairwiseSegmentAccumulator,
-                    TieredSegmentAccumulator, generational_from_stats,
+from .merge import (GenerationalIndex, generational_from_stats,
                     merge_indexes, merge_segments, segment_to_stats,
                     stats_union)
 from .query import continuations, lookup
@@ -31,8 +30,7 @@ __all__ = ["build", "compress", "merge", "query", "serve",
            "segment_from_stats",
            "CompressedNGramIndex", "EliasFano", "build_compressed_index",
            "compress_index", "decode_segment",
-           "GenerationalIndex", "TieredSegmentAccumulator",
-           "PairwiseSegmentAccumulator", "generational_from_stats",
+           "GenerationalIndex", "generational_from_stats",
            "merge_indexes", "merge_segments", "segment_to_stats",
            "stats_union",
            "lookup", "continuations",

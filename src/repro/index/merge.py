@@ -8,33 +8,28 @@ classic log-structured-merge discipline instead:
 
   * :func:`merge_segments` -- k-way merge of sorted segments into one new
     segment with duplicate grams' counts *summed*, optionally dropping rows
-    whose summed count is under ``min_count``.  The routes:
+    whose summed count is under ``min_count``.  Two routes, both
+    host-resident end to end and bit-identical (the output order is
+    ascending (length | packed lanes), a pure function of the row set):
     ``"kway"`` exploits the inputs' sortedness on the host -- a stable sort
     of the concatenated big-endian row bytes is a galloping k-way merge
     (timsort detects the k presorted runs), an order of magnitude cheaper
     than re-sorting blind -- and folds duplicate counts exactly in int64 via
-    ``np.add.reduceat``; ``"device"`` is the blocked fold on the chip: the
-    host cuts the sorted inputs into key-range blocks of at most
-    :func:`device_block_rows` rows, fewer the wider the keys (a gram never
-    straddles two; on an accelerator every block of one key width is
-    padded to that one shape, whose programs
-    :func:`load_block_programs` readies up front), and one jitted
-    program of fixed shape sorts each block, sums its duplicate counts
-    exactly in 8-bit limbs, drops rows under ``min_count`` and compacts the
-    survivors to the front, so only they come back to the host; blocks are
-    dispatched asynchronously, the next one assembled while the device
-    folds the last, and there is no size cap; ``"merge"`` runs the jitted
-    pairwise merge-path (``kernels/merge_path.py`` Pallas kernel, or its jnp
-    ref) over a balanced pairing tree; ``"sort"`` re-sorts the concatenation
-    through ``mapreduce.sort``.  On the ``"merge"`` and ``"sort"`` routes,
-    run boundaries come from ``mapreduce.segment``'s lcp primitive and the
-    dedup-summed count fold runs through the reducer's segmented-sum path in
-    two uint32 limbs.  A device fold is exact below ``_MAX_DEVICE_RUN``
-    duplicates per gram; longer runs replay on the host in int64.  Every
-    route refuses loudly if a merged cf overflows the uint32 device lanes
-    (mirroring the continuation-mass guard in ``build.py``), and all routes
-    produce bit-identical segments: the output order is ascending
-    (length | packed lanes), a pure function of the row set.
+    ``np.add.reduceat``; it is the CPU backend's fold and the LSM index's.
+    ``"device"`` is the blocked fold on the chip, the wave job's fold off
+    the CPU: the host cuts the sorted inputs into key-range blocks of at
+    most :func:`device_block_rows` rows, fewer the wider the keys (a gram
+    never straddles two; on an accelerator every block of one key width is
+    padded to that one shape, whose programs :func:`load_block_programs`
+    readies up front), and one jitted program of fixed shape sorts each
+    block, sums its duplicate counts exactly in 8-bit limbs, drops rows
+    under ``min_count`` and compacts the survivors to the front, so only
+    they come back to the host; blocks are dispatched asynchronously, the
+    next one assembled while the device folds the last, and there is no
+    size cap.  The device fold is exact below ``_MAX_DEVICE_RUN``
+    duplicates per gram; a block with a longer run replays on the host in
+    int64.  Both routes refuse loudly if a merged cf overflows the uint32
+    device lanes (mirroring the continuation-mass guard in ``build.py``).
   * :func:`merge_indexes` -- segments in, finished artifact out:
     ``index_from_segment`` rebuilds fanout/continuation/cumsum structures from
     the merged rows *without re-running the job*, and re-compresses when the
@@ -51,7 +46,6 @@ classic log-structured-merge discipline instead:
 """
 from __future__ import annotations
 
-import dataclasses
 import threading
 from concurrent.futures import Future, ThreadPoolExecutor
 from functools import partial, reduce
@@ -63,61 +57,19 @@ import numpy as np
 
 from repro.core.stats import NGramStats
 from repro.mapreduce import pack as packing
-from repro.mapreduce import segment as mr_segment
 from repro.mapreduce import sort as mr_sort
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from ._layout import SENTINEL, pad_rows, round_capacity, row_bytes_view
-from .build import IndexSegment, NGramIndex, build_index, index_from_segment
+from .build import IndexSegment, index_from_segment
 from .compress import CompressedNGramIndex, compress_index, decode_segment
 
 DEFAULT_SIZE_RATIO = 4
 _U32_MAX = np.iinfo(np.uint32).max
 
-AnyIndex = "NGramIndex | CompressedNGramIndex"
-
-
-def _merged_run(segs: list[IndexSegment], *, route: str,
-                use_kernels: bool) -> tuple[jax.Array, jax.Array]:
-    """One sorted run (duplicates kept, sentinels at the tail) over all rows."""
-    if route == "sort":
-        # fallback: re-sort the concatenation (mapreduce.sort, the job's own
-        # multi-key lexicographic sort; sentinel rows sort to the tail)
-        keys = jnp.concatenate([s.keys for s in segs], axis=0)
-        counts = jnp.concatenate([s.counts for s in segs], axis=0)
-        keys, (counts,) = mr_sort.sort_with_payload(keys, [counts])
-    elif route == "merge":
-        if use_kernels:
-            from repro.kernels import ops as kops
-            merge2 = kops.merge_path
-        else:
-            from repro.kernels import ref as kref
-            merge2 = kref.merge_path_ref
-        # balanced pairing tree in segment-index order: every row rides
-        # O(log k) pairwise merges instead of the linear chain's O(k), and
-        # adjacent pairing + the merge-path's A-first tie rule keep global
-        # duplicate order (moot anyway: the dedup fold sums duplicates, and
-        # output order is a pure function of the row set)
-        # wave-fold segments arrive host-resident; the merge tree's traced
-        # binary search needs device operands, so lift once up front
-        runs = [(jnp.asarray(s.keys, jnp.uint32),
-                 jnp.asarray(s.counts, jnp.uint32)) for s in segs]
-        while len(runs) > 1:
-            paired = [merge2(runs[i][0], runs[i + 1][0],
-                             runs[i][1], runs[i + 1][1])
-                      for i in range(0, len(runs) - 1, 2)]
-            if len(runs) % 2:
-                paired.append(runs[-1])
-            runs = paired
-        keys, counts = runs[0]
-    else:
-        raise ValueError(f"unknown merge route {route!r}")
-    return jnp.asarray(keys, jnp.uint32), jnp.asarray(counts, jnp.uint32)
-
-
-# The device folds' limbed uint32 segment sums stay exact while every run is
+# The device fold's limbed count sums are trusted only while every run is
 # shorter than this; a merge of k segments with distinct rows each has runs
-# of length <= k, so the device folds cover everything but adversarial
+# of length <= k, so the device fold covers everything but adversarial
 # duplicate floods.
 _MAX_DEVICE_RUN = 1 << 16
 
@@ -245,65 +197,6 @@ def _splice(base: IndexSegment, nb: int, d_keys, d_tot, d_bytes):
     out_keys[new_at] = d_keys[~dup]
     out_tot[new_at] = d_tot[~dup]
     return out_keys, _check_u32(out_tot)
-
-
-@partial(jax.jit, static_argnames=("sigma",))
-def _fold_runs_device(keys: jax.Array, counts: jax.Array, *, sigma: int):
-    """Dedup-fold a sorted run on device: the reducer's segmented-sum path.
-
-    Device count lanes are uint32 and x64 may be off, so the fold runs in two
-    uint32 limbs (lo/hi 16 bits of each count, segment-summed separately and
-    recombined) -- exact while runs stay under ``_MAX_DEVICE_RUN`` rows, with
-    the recombine carry doubling as the loud cf-overflow guard.  Run starts
-    are compacted to the front with a stable argsort (order preserved), the
-    tail refilled with sentinels.  Returns
-    (keys [N, C], totals [N], n_runs, overflow?, max_run_len).
-    """
-    n, n_cols = keys.shape
-    lcp = mr_segment.lcp_lengths(keys.astype(jnp.int32))
-    new_run = lcp < n_cols                     # row 0 has lcp 0 -> always True
-    seg = jnp.maximum(jnp.cumsum(new_run.astype(jnp.int32)) - 1, 0)
-    run_len = jax.ops.segment_sum(jnp.ones((n,), jnp.uint32), seg,
-                                  num_segments=n)
-    slo = jax.ops.segment_sum(counts & jnp.uint32(0xFFFF), seg, num_segments=n)
-    shi = jax.ops.segment_sum(counts >> 16, seg, num_segments=n)
-    hi = shi + (slo >> 16)                     # carry; > 0xFFFF == cf overflow
-    totals = (hi << 16) | (slo & jnp.uint32(0xFFFF))
-    real = new_run & (keys[:, 0] <= jnp.uint32(sigma))  # sentinels sort last
-    order = jnp.argsort(~real, stable=True)    # real run starts first, in order
-    n_runs = jnp.sum(real.astype(jnp.int32))
-    in_range = jnp.arange(n) < n_runs
-    out_keys = jnp.where(in_range[:, None], keys[order], SENTINEL)
-    out_counts = jnp.where(in_range, totals[seg][order], 0)
-    overflow = jnp.any(in_range & ((hi[seg][order] >> 16) != 0))
-    return out_keys, out_counts, n_runs, overflow, jnp.max(run_len)
-
-
-def _fold_runs_host(keys: np.ndarray, counts: np.ndarray, *,
-                    sigma: int) -> tuple[np.ndarray, np.ndarray]:
-    """Host int64 fold -- fallback for runs too long for the two-limb device
-    path, and the bearer of the detailed overflow diagnostic."""
-    lcp = np.asarray(mr_segment.lcp_lengths(
-        jnp.asarray(keys).astype(jnp.int32)))
-    new_run = lcp < keys.shape[1]
-    starts = np.flatnonzero(new_run)
-    cs = np.concatenate([[0], np.cumsum(counts, dtype=np.int64)])
-    ends = np.append(starts[1:], keys.shape[0])
-    totals = cs[ends] - cs[starts]                      # int64: exact fold
-    run_keys = keys[starts]
-    real = run_keys[:, 0] <= np.uint32(sigma)           # sentinel length sorts last
-    r_keys = run_keys[real]
-    r_tot = totals[real]
-    # mirror of build.py's continuation-mass guard: a silently wrapped cf would
-    # serve plausible-looking garbage, so refuse loudly instead (raise tau, or
-    # shard the corpus so per-shard counts stay in range)
-    if r_tot.size and int(r_tot.max()) > _U32_MAX:
-        bad = int(np.argmax(r_tot))
-        raise ValueError(
-            f"merged count {int(r_tot[bad])} of gram row {bad} overflows the "
-            "uint32 device count lane; raise tau or shard the corpus before "
-            "merging")
-    return r_keys, r_tot.astype(np.uint32)
 
 
 @jax.jit
@@ -540,7 +433,7 @@ def _fold_blocks_device(segs: list[IndexSegment], *, min_count: int | None):
     return np.concatenate(out_keys), np.concatenate(out_tot), len(blocks)
 
 
-def merge_segments(segments, *, route: str = "merge", use_kernels: bool = False,
+def merge_segments(segments, *, route: str = "kway",
                    pad_to: int | None = None, min_count: int | None = None,
                    n_compressed: int | None = None) -> IndexSegment:
     """Merge sorted segments into one, summing counts of duplicate grams.
@@ -549,31 +442,29 @@ def merge_segments(segments, *, route: str = "merge", use_kernels: bool = False,
     (stable sort of concatenated big-endian row bytes == galloping k-way
     merge; int64 ``reduceat`` count fold); ``route="device"`` is the
     blocked fold on the chip (key-range blocks of a fixed byte budget on
-    an accelerator, survivors only back to the host, no size
-    cap); ``route="merge"`` runs the jitted pairwise merge-path (Pallas
-    kernel when ``use_kernels``, jnp ref otherwise) over a balanced pairing
-    tree; ``route="sort"`` re-sorts the concatenation (the
-    ``mapreduce.sort`` fallback).  All routes are bit-identical.  Raises
-    ``ValueError`` if any merged count overflows the uint32 device lanes.
+    an accelerator, survivors only back to the host, no size cap).  Both
+    routes are bit-identical and return a host-resident segment.  Raises
+    ``ValueError`` on any other route, or if any merged count overflows the
+    uint32 device lanes.
 
     ``min_count`` drops merged rows whose summed count is below it before
     the result is padded: on the ``"device"`` route before they leave the
-    chip.  ``None`` keeps every row.  ``"kway"`` and ``"device"`` return a
-    host-resident segment, the other routes a device-resident one.
+    chip.  ``None`` keeps every row.
 
     ``n_compressed`` is purely observational: callers that decoded some
     inputs from the compressed layout record the flat/compressed mix on the
     ``merge.segments`` span.
     """
-    return _merge(segments, route=route, use_kernels=use_kernels,
-                  pad_to=pad_to, min_count=min_count,
+    return _merge(segments, route=route, pad_to=pad_to, min_count=min_count,
                   n_compressed=n_compressed)[0]
 
 
-def _merge(segments, *, route: str, use_kernels: bool = False,
-           pad_to: int | None = None, min_count: int | None = None,
+def _merge(segments, *, route: str, pad_to: int | None = None,
+           min_count: int | None = None,
            n_compressed: int | None = None) -> tuple[IndexSegment, int]:
     """:func:`merge_segments`, and the number of device blocks it ran."""
+    if route not in ("kway", "device"):
+        raise ValueError(f"unknown merge route {route!r}")
     segs = list(segments)
     if not segs:
         raise ValueError("cannot merge zero segments")
@@ -591,40 +482,19 @@ def _merge(segments, *, route: str, use_kernels: bool = False,
                 sp.set(n_compressed=n_compressed,
                        n_flat=len(segs) - n_compressed)
         return _merge_segments_body(segs, sigma, vocab, route=route,
-                                    use_kernels=use_kernels, pad_to=pad_to,
-                                    min_count=min_count)
+                                    pad_to=pad_to, min_count=min_count)
 
 
-def _merge_segments_body(segs, sigma, vocab, *, route, use_kernels, pad_to,
-                         min_count):
+def _merge_segments_body(segs, sigma, vocab, *, route, pad_to, min_count):
     blocks = 0
-    if route == "kway":
-        r_keys, r_tot = _kway_fold_host(segs, sigma=sigma)
-    elif route == "device":
+    if route == "device":
         r_keys, r_tot, blocks = _fold_blocks_device(segs, min_count=min_count)
     else:
-        keys, counts = _merged_run(segs, route=route, use_kernels=use_kernels)
-
-        # run boundaries (a row starts a run iff it differs from its
-        # predecessor, via mapreduce.segment's lcp primitive) and the
-        # dedup-summed totals all fold on device through the reducer's
-        # segmented-sum path; the host only learns (n_runs, overflow?,
-        # max_run) to size and validate the result
-        out_keys, out_counts, n_runs, overflow, max_run = _fold_runs_device(
-            keys, counts, sigma=sigma)
-        n_runs, overflow, max_run = int(n_runs), bool(overflow), int(max_run)
-        if overflow or max_run >= _MAX_DEVICE_RUN:
-            # rare: replay on host for the int64 fold / detailed diagnostic
-            r_keys, r_tot = _fold_runs_host(np.asarray(keys, np.uint32),
-                                            np.asarray(counts, np.uint32),
-                                            sigma=sigma)
-        else:
-            r_keys = np.asarray(out_keys[:n_runs], np.uint32)
-            r_tot = np.asarray(out_counts[:n_runs], np.uint32)
-    if min_count is not None and route != "device":   # the chip filtered
-        with obs_trace.span("merge.filter"):
-            keep = r_tot >= min_count
-            r_keys, r_tot = r_keys[keep], r_tot[keep]
+        r_keys, r_tot = _kway_fold_host(segs, sigma=sigma)
+        if min_count is not None:
+            with obs_trace.span("merge.filter"):
+                keep = r_tot >= min_count
+                r_keys, r_tot = r_keys[keep], r_tot[keep]
     r = int(r_keys.shape[0])
     size = pad_to if pad_to is not None else round_capacity(r)
     if size < r + 1:
@@ -632,35 +502,25 @@ def _merge_segments_body(segs, sigma, vocab, *, route, use_kernels, pad_to,
     with obs_trace.span("merge.pad"):
         keys_p = pad_rows(r_keys, size, SENTINEL)
         cnts_p = pad_rows(r_tot, size, 0)
-    if route not in ("kway", "device"):
-        # the tree and re-sort routes hand device arrays back; the host and
-        # blocked folds stay host-resident end to end -- an LSM cascade of
-        # kway merges would otherwise pay an h2d/d2h round trip per
-        # compaction for data the next merge reads right back on the host
-        keys_p, cnts_p = jnp.asarray(keys_p), jnp.asarray(cnts_p)
     return IndexSegment(keys=keys_p, counts=cnts_p, sigma=sigma,
                         vocab_size=vocab), blocks
 
 
-def _merge_input_segment(entry, *, route: str) -> IndexSegment:
-    """Segment view of one merge input, compressed-native when needed.
+def _merge_input_segment(entry) -> IndexSegment:
+    """Host segment view of one merge input, compressed-native when needed.
 
     Flat entries pass through (``to_segment`` on an :class:`NGramIndex` is a
     field read); compressed entries stream-decode block chunks through
     :func:`~repro.index.compress.decode_segment` -- O(chunk) peak decoded
-    working set, never a whole decoded table.  The host ``"kway"`` route (the
-    LSM default) and the blocked ``"device"`` fold, which cuts host rows,
-    take the unpadded host segment straight in; the tree and re-sort routes
-    get the capacity-padded device form their search kernels expect.
+    working set, never a whole decoded table.  Both routes take the
+    unpadded host segment straight in.
     """
     if isinstance(entry, CompressedNGramIndex):
-        return (decode_segment(entry) if route in ("kway", "device")
-                else entry.to_segment())
+        return decode_segment(entry)
     return entry if isinstance(entry, IndexSegment) else entry.to_segment()
 
 
-def merge_indexes(indexes, *, route: str = "merge", use_kernels: bool = False,
-                  pad_to: int | None = None):
+def merge_indexes(indexes, *, route: str = "kway", pad_to: int | None = None):
     """Merge finished indexes into one of the same layout, job-free.
 
     All inputs must share (sigma, vocab_size) and layout; compressed inputs must
@@ -675,9 +535,8 @@ def merge_indexes(indexes, *, route: str = "merge", use_kernels: bool = False,
     for ix in ixs[1:]:
         if isinstance(ix, CompressedNGramIndex) != compressed:
             raise ValueError("cannot merge mixed flat/compressed layouts")
-    seg = merge_segments([_merge_input_segment(ix, route=route) for ix in ixs],
-                         route=route, use_kernels=use_kernels,
-                         n_compressed=sum(
+    seg = merge_segments([_merge_input_segment(ix) for ix in ixs],
+                         route=route, n_compressed=sum(
                              isinstance(ix, CompressedNGramIndex)
                              for ix in ixs))
     idx = index_from_segment(seg, pad_to=pad_to)
@@ -788,95 +647,31 @@ def merge_continuation_results(per_seg, *, k: int):
     return nd, total.astype(np.uint32), topk_t, topk_c
 
 
-class TieredSegmentAccumulator:
-    """Size-tiered fold of a stream of sorted segments (the wave accumulator).
-
-    The wave engine's naive fold -- ``acc = merge_segments([acc, seg])`` per
-    wave -- re-merges the whole running segment every wave: O(waves x total)
-    rows through the merge path.  This accumulator applies the same LSM
-    discipline as :class:`GenerationalIndex` to raw segments: ``push`` stacks
-    the new segment as the newest rung and merges only while the newest rung
-    has grown to within ``size_ratio`` of its elder, so equal-sized waves
-    amortize to O(total log waves) merge rows; ``result`` folds the surviving
-    rungs once.  Because dedup-summed segment merges are associative and the
-    output order is a pure function of the row set, the final segment is
-    bit-identical to the pairwise fold's.
-
-    ``fold_rows`` counts every input row fed through :func:`merge_segments`
-    -- the measured merge work the benchmarks compare across strategies --
-    and ``finalize_blocks`` the blocks the ``"device"`` route folded.
-    """
-
-    def __init__(self, *, size_ratio: int = DEFAULT_SIZE_RATIO,
-                 route: str = "sort", use_kernels: bool = False):
-        if size_ratio < 1:
-            raise ValueError("size_ratio must be >= 1")
-        self.size_ratio = size_ratio
-        self.route = route
-        self.use_kernels = use_kernels
-        self.rungs: list[tuple[IndexSegment, int]] = []   # newest first
-        self.fold_rows = 0
-        self.finalize_blocks = 0
-
-    def _merge_front(self, n: int) -> None:
-        segs = [s for s, _ in reversed(self.rungs[:n])]   # elder first
-        self.fold_rows += sum(r for _, r in self.rungs[:n])
-        merged, blocks = _merge(segs, route=self.route,
-                                use_kernels=self.use_kernels)
-        self.finalize_blocks += blocks
-        self.rungs[:n] = [(merged, merged.n_rows)]
-
-    def push(self, seg: IndexSegment, *, n_rows: int | None = None) -> None:
-        """Stack one segment, then compact rungs under the size-ratio policy.
-
-        ``n_rows`` (when the caller already knows it, e.g. from the stats the
-        segment was frozen from) skips the segment's own host-side row count.
-        """
-        self.rungs.insert(0, (seg, seg.n_rows if n_rows is None else n_rows))
-        while (len(self.rungs) >= 2 and
-               self.rungs[0][1] * self.size_ratio >= self.rungs[1][1]):
-            self._merge_front(2)
-
-    def result(self) -> IndexSegment:
-        """Fold the remaining rungs into the one final sorted segment."""
-        if not self.rungs:
-            raise ValueError("no segments accumulated")
-        if len(self.rungs) > 1:
-            self._merge_front(len(self.rungs))
-        return self.rungs[0][0]
-
-
 class DeferredSegmentAccumulator:
     """Stack every wave segment; fold once, k-way, at :meth:`result`.
 
-    The wave engine's default fold.  Incremental compaction (tiered or
-    pairwise) re-merges rows it has merged before -- O(total log waves) and
-    O(waves x total) rows respectively -- but a :meth:`run` fold does not
-    need intermediate merged state at all: only ``result`` is ever read.
-    Deferring makes the total fold work exactly *one* k-way merge over the
-    raw wave partials (O(total) rows through :func:`merge_segments`, which
-    the ``"kway"`` route turns into a single galloping host merge and the
-    ``"device"`` route into fixed-shape blocks folded on the chip).
+    The wave engine's fold.  A :meth:`run` fold never reads intermediate
+    merged state -- only ``result`` -- so the total fold work is exactly
+    *one* k-way merge over the raw wave partials (O(total) rows through
+    :func:`merge_segments`, which the ``"kway"`` route turns into a single
+    galloping host merge and the ``"device"`` route into fixed-shape blocks
+    folded on the chip).  Memory: all wave partials stay live until
+    ``result`` -- O(total tau=1 rows), the same order as the merged segment.
 
-    Memory: all wave partials stay live until ``result`` -- O(total tau=1
-    rows), the same order as the merged segment every accumulator must
-    produce anyway.  When waves must release their partials eagerly (truly
-    bounded-memory streaming), use :class:`TieredSegmentAccumulator`
-    (log-many live rungs) or :class:`PairwiseSegmentAccumulator` (one).
-    Same interface, bit-identical result: dedup-summed merges are
-    associative and the output order is a pure function of the row set.
+    ``fold_rows`` counts every input row fed through the fold, and
+    ``finalize_blocks`` the blocks the ``"device"`` route folded.
     """
 
-    def __init__(self, *, route: str = "kway", use_kernels: bool = False,
-                 **_ignored):
+    def __init__(self, *, route: str = "kway"):
         self.route = route
-        self.use_kernels = use_kernels
         self.segs: list[IndexSegment] = []
         self._rows: list[int] = []
         self.fold_rows = 0
         self.finalize_blocks = 0
 
     def push(self, seg: IndexSegment, *, n_rows: int | None = None) -> None:
+        """Stack one segment; ``n_rows`` (when the caller already knows it)
+        skips the segment's own host-side row count."""
         self.segs.append(seg)
         self._rows.append(seg.n_rows if n_rows is None else n_rows)
 
@@ -894,47 +689,12 @@ class DeferredSegmentAccumulator:
             return self.segs[0]
         self.fold_rows += sum(self._rows)
         merged, blocks = _merge(self.segs, route=self.route,
-                                use_kernels=self.use_kernels,
                                 min_count=min_count)
         self.finalize_blocks += blocks
         if min_count is None:
             self.segs = [merged]
             self._rows = [merged.n_rows]
         return merged
-
-
-class PairwiseSegmentAccumulator:
-    """The legacy fold-every-wave-into-one-segment baseline (O(waves x total)).
-
-    Same interface and bit-identical result as
-    :class:`TieredSegmentAccumulator`; kept for the benchmark comparison and
-    as the degenerate-memory option (exactly one live segment at all times).
-    """
-
-    def __init__(self, *, route: str = "sort", use_kernels: bool = False,
-                 **_ignored):
-        self.route = route
-        self.use_kernels = use_kernels
-        self._seg: IndexSegment | None = None
-        self._rows = 0
-        self.fold_rows = 0
-        self.finalize_blocks = 0
-
-    def push(self, seg: IndexSegment, *, n_rows: int | None = None) -> None:
-        rows = seg.n_rows if n_rows is None else n_rows
-        if self._seg is None:
-            self._seg, self._rows = seg, rows
-            return
-        self.fold_rows += self._rows + rows
-        self._seg, blocks = _merge([self._seg, seg], route=self.route,
-                                   use_kernels=self.use_kernels)
-        self.finalize_blocks += blocks
-        self._rows = self._seg.n_rows
-
-    def result(self) -> IndexSegment:
-        if self._seg is None:
-            raise ValueError("no segments accumulated")
-        return self._seg
 
 
 class GenerationalIndex:
@@ -971,8 +731,7 @@ class GenerationalIndex:
     """
 
     def __init__(self, *, sigma: int, vocab_size: int, compress: bool = False,
-                 block_size: int = 4, size_ratio: int = DEFAULT_SIZE_RATIO,
-                 route: str = "kway", use_kernels: bool = False):
+                 block_size: int = 4, size_ratio: int = DEFAULT_SIZE_RATIO):
         if size_ratio < 1:
             raise ValueError("size_ratio must be >= 1")
         self.sigma = sigma
@@ -980,8 +739,6 @@ class GenerationalIndex:
         self.compress = compress
         self.block_size = block_size
         self.size_ratio = size_ratio
-        self.route = route
-        self.use_kernels = use_kernels
         self._next_id = 0
         # newest (L0) first; an entry is a bare IndexSegment until a reader
         # materializes it (in place) into a built index artifact
@@ -996,7 +753,7 @@ class GenerationalIndex:
     @property
     def levels(self) -> list:
         """Live level entries, newest first.  Assign a full list to replace
-        the stack (tests/benchmarks bootstrap with pre-built artifacts);
+        the stack (tests bootstrap with pre-built artifacts);
         in-place mutation is reserved for the index itself, which keeps the
         per-level provenance and identity books in sync."""
         return self._levels
@@ -1122,16 +879,15 @@ class GenerationalIndex:
                 "segment_rows": [ix.n_rows for ix in self.levels]}
 
     def _merge_front(self, n: int) -> None:
-        # elder segments first: merge-path ties keep generation order stable;
-        # compaction works on segment views (any cached artifact of a merged
-        # level dies with it -- the merged level rebuilds lazily if read);
+        # elder segments first (the merged order is a pure function of the
+        # row set, so any input order gives the same segment); compaction
+        # works on segment views (any cached artifact of a merged level
+        # dies with it -- the merged level rebuilds lazily if read);
         # compressed rungs stream-decode chunk by chunk, never a full table
         with obs_trace.span("gen.compact") as sp:
             rows_in = sum(ix.n_rows for ix in self._levels[:n])
             merged = merge_segments(
-                [_merge_input_segment(e, route=self.route)
-                 for e in reversed(self._levels[:n])],
-                route=self.route, use_kernels=self.use_kernels,
+                [_merge_input_segment(e) for e in reversed(self._levels[:n])],
                 n_compressed=sum(isinstance(e, CompressedNGramIndex)
                                  for e in self._levels[:n]))
             self._levels[:n] = [merged]
@@ -1183,7 +939,7 @@ class GenerationalIndex:
             c.add(v - c.value)          # counters mirror the lifetime totals
 
     def compact_all(self) -> None:
-        """Force-merge every live segment into one (maintenance/benchmarks)."""
+        """Force-merge every live segment into one (maintenance)."""
         if len(self.levels) >= 2:
             self._merge_front(len(self.levels))
             self.generation += 1

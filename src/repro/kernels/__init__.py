@@ -8,7 +8,7 @@ docstring for the VMEM tiling rationale):
   bsearch        -- index serving inner loop (batched lexicographic bounds)
   block_decode   -- compressed-index in-block decode + rank
   block_expand   -- compressed-index batched block decode
-  merge_path     -- stable two-way merge of sorted segments (LSM compaction)
+  merge_path     -- stable two-way merge of sorted segments (no caller outside tests)
 
 ``ops`` holds the public wrappers (compiled on a TPU, interpreted elsewhere),
 ``ref`` the pure-jnp oracles.  The package imports none of them eagerly, so

@@ -183,7 +183,7 @@ def merge_path_ref(a_keys: jax.Array, b_keys: jax.Array, a_vals: jax.Array,
 
     Rows compare lexicographically (uint32 lanes); on ties every A row precedes
     every B row.  Semantics match ``repro.kernels.merge_path.merge_path`` (its
-    allclose target and the ``use_kernels=False`` merge route).  The ref takes
+    allclose target; no merge route calls either).  The ref takes
     the rank-and-scatter route -- each A row's output slot is its index plus the
     count of strictly-smaller B rows, each B row's its index plus the count of
     less-or-equal A rows -- deliberately a different algorithm from the kernel's

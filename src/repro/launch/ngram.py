@@ -34,21 +34,6 @@ def main() -> None:
                     help="out-of-core: run the job in fixed-size token waves "
                          "(repro.pipeline.WaveExecutor); output is "
                          "bit-identical to the monolithic run")
-    ap.add_argument("--accumulator", default="defer",
-                    choices=["defer", "tiered", "pairwise"],
-                    help="wave-partial fold policy: defer = stack wave "
-                         "segments and fold once, k-way, at the end (O(total) "
-                         "merge rows, the default); tiered = size-tiered LSM "
-                         "rungs (bounded live memory, amortized O(total log "
-                         "waves)); pairwise = the one-segment baseline")
-    ap.add_argument("--merge-route", default=None,
-                    choices=["kway", "merge", "sort", "device"],
-                    help="segment-fold route (default: device for the "
-                         "defer accumulator on an accelerator, else kway): "
-                         "device = blocked merge, fold and tau filter on "
-                         "the chip; kway = galloping host merge; merge = "
-                         "balanced-tree pairwise merge-path; sort = fused "
-                         "re-sort")
     ap.add_argument("--no-overlap", action="store_true",
                     help="serialize the per-wave fold with wave dispatch "
                          "instead of overlapping it on the fold thread "
@@ -102,8 +87,6 @@ def main() -> None:
             raise SystemExit("--wave-tokens does not support --series "
                              "(bucketed counts need a single-wave job)")
         stats = WaveExecutor(cfg, wave_tokens=args.wave_tokens,
-                             accumulator=args.accumulator,
-                             merge_route=args.merge_route,
                              overlap=not args.no_overlap,
                              mesh=mesh).run(tokens)
     else:

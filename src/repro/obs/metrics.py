@@ -60,10 +60,10 @@ COUNTER_DOC: dict[str, str] = {
                 "triggers a retry instead; kept as the loud invariant)",
     "waves": "token waves executed (wave-only)",
     "fold_rows": "segment rows fed through merge_segments by the wave "
-                 "accumulator -- the measured fold work (wave-only)",
-    "finalize_blocks": "key-range blocks the wave accumulator's merges ran "
-                       "through the device fold program (merge_route "
-                       "\"device\"); 0 on the host routes (wave-only)",
+                 "fold -- the measured fold work (wave-only)",
+    "finalize_blocks": "key-range blocks the wave fold ran through the "
+                       "device fold program (merge_route \"device\"); 0 "
+                       "on the host route (wave-only)",
     "d2h_bytes": "bytes materialized device to host when the waves are "
                  "collected: the segment rows (or, on the stats and mesh "
                  "routes, the reducer outputs), skew histogram and counter "

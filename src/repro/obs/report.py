@@ -4,7 +4,7 @@ The executor and serving drivers hand a :class:`~repro.obs.metrics
 .MetricsRegistry` snapshot (plus an optional trace) to this module, which
 
   * appends JSON-lines records (:func:`write_jsonl`) -- one self-contained
-    snapshot per line, greppable and diffable like the ``BENCH_*.json`` files;
+    snapshot per line, greppable and diffable;
   * renders the human summary (:func:`summary_table`) the CLIs print;
   * stamps :func:`environment_metadata` (jax version, backend, device count)
     so every recorded number says what hardware produced it;

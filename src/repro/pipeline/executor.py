@@ -24,7 +24,7 @@ map -> combine -> shuffle -> sort -> reduce *across machines*.
   * the wave loop is **device-resident with an overlapped fold**
     (``_for_each_wave``): the main thread only slices host token slabs and
     dispatches fused wave programs, while a background fold thread
-    materializes each wave and folds it (accumulator merge / generational
+    materializes each wave and folds it (segment stack / generational
     ingest) -- so host-side fold work overlaps the next waves' device work
     instead of serializing with it, with a bounded in-flight queue keeping
     the memory model.  No per-wave host syncs ride the feeder's hot path --
@@ -32,15 +32,12 @@ map -> combine -> shuffle -> sort -> reduce *across machines*.
   * per-wave partials are produced at ``tau = 1`` -- a gram below tau in every
     wave can still be frequent globally, so nothing may be dropped early --
     and folded through the *segment merge* path (``index/merge.py``).  The
-    default fold **defers**: wave segments stack and merge once, k-way, at
-    the end (:class:`~repro.index.merge.DeferredSegmentAccumulator` -- one
-    stable host sort over O(total) rows, with a skewed searchsorted-splice
-    fast path when one segment dominates); ``accumulator="tiered"`` keeps
-    the LSM rung stack of ``GenerationalIndex`` for bounded live memory,
-    ``"pairwise"`` is the re-merge-every-wave baseline.  Every accumulator
-    yields the same sorted segment, so the final output stays bit-identical
-    to the monolithic job (canonical order; the global tau filter runs once
-    at the end);
+    fold **defers**: wave segments stack and merge once, k-way, at the end
+    (:class:`~repro.index.merge.DeferredSegmentAccumulator`), with the
+    global tau filter inside that one merge -- on the chip the blocked
+    device fold, on the CPU backend one stable host sort over O(total)
+    rows.  Both routes yield the same sorted segment, so the final output
+    stays bit-identical to the monolithic job (canonical order);
   * with a ``mesh``, every wave is **distributed and just as fused**: the
     wave's extended window shards contiguously over the mesh axis and the
     *entire round chain* -- one ppermute sigma-1 halo pull, then every
@@ -106,7 +103,7 @@ _STAGE_CORE: dict[str, object] = {}
 # updates traced into ONE jitted program, so a wave is a single dispatch.
 # Both plan (frozen JobPlan of function refs) and cfg (frozen NGramConfig)
 # hash by value, so distinct WaveExecutor instances over the same job share
-# the compiled program (the benchmarks build a fresh executor per rep).
+# the compiled program (e.g. a fresh executor per job).
 _WAVE_PROGRAMS: dict[tuple, object] = {}
 
 # in-flight single-device waves beyond the one being folded: bounds the
@@ -593,7 +590,7 @@ def _wait_for_device(outs, wave: int) -> None:
 class WavePartial:
     """One collected wave: its host-frozen sorted segment + job counters.
 
-    The unit the fold consumes (accumulator push in :meth:`WaveExecutor.run`,
+    The unit the fold consumes (segment push in :meth:`WaveExecutor.run`,
     generational ingest in :meth:`WaveExecutor.run_streaming`): ``segment``
     is an unpadded host-resident :class:`~repro.index.build.IndexSegment`
     holding the wave's exact tau=1 rows in (length | packed lanes) order,
@@ -614,28 +611,21 @@ class WaveExecutor:
 
     ``wave_tokens`` bounds the device-resident working set; ``None`` (or a
     wave at least the corpus size) degenerates to one wave.  Waves execute at
-    ``tau = 1`` and fold through ``index/merge.py`` segments under the
-    ``accumulator`` policy (``"defer"`` = stack wave partials and fold once,
-    k-way, at finalize -- O(total) merge rows, the default; ``"tiered"`` =
-    size-tiered LSM rung stack, amortized O(total log waves) merge work with
-    log-many live rungs; ``"pairwise"`` = the legacy
-    fold-every-wave-into-one-segment baseline, O(waves x total));
-    ``merge_route``: ``"device"`` = the blocked fold on the chip: the
-    sorted wave segments are cut into fixed-shape key-range blocks that one
-    jitted program merges, dedup-folds and tau-filters, so only rows with
-    cf >= tau come back to the host; ``"kway"`` = galloping host merge of
-    the presorted segments; ``"sort"`` = one fused re-sort per fold;
-    ``"merge"`` = balanced-tree pairwise merge-path.  ``None`` (the
-    default) picks ``"device"`` for the ``"defer"`` accumulator where the
-    default backend is an accelerator, and ``"kway"`` otherwise: on the CPU
-    backend, and for the tiered and pairwise accumulators, whose per-wave
-    merges must bring every row back.  On the ``"device"`` route the first
-    run readies the one block program (``index.merge.load_block_programs``)
-    beside its waves and returns only when it is ready, so no later run
-    compiles it, even if this one folded nothing.  :meth:`run` applies the
-    global tau once at the end (pushed into the ``"defer"`` fold as its
-    ``min_count``), so for any wave size (and any accumulator/route) the
-    output is bit-identical to the monolithic job.
+    ``tau = 1``; :meth:`run` stacks their segments and folds them once,
+    k-way, at finalize (``index.merge.DeferredSegmentAccumulator``, O(total)
+    merge rows), with the global tau pushed into that fold as its
+    ``min_count``, so for any wave size and either route the output is
+    bit-identical to the monolithic job.  ``merge_route``: ``"device"`` =
+    the blocked fold on the chip: the sorted wave segments are cut into
+    fixed-shape key-range blocks that one jitted program merges,
+    dedup-folds and tau-filters, so only rows with cf >= tau come back to
+    the host; ``"kway"`` = galloping host merge of the presorted segments.
+    ``None`` (the default) picks ``"device"`` where the default backend is
+    an accelerator and ``"kway"`` on the CPU backend.  On the ``"device"``
+    route the first run readies the one block program
+    (``index.merge.load_block_programs``) beside its waves and returns only
+    when it is ready, so no later run compiles it, even if this one folded
+    nothing.
 
     With a ``mesh`` (size > 1), each wave runs as ONE fused ``shard_map``
     dispatch over ``axis_name``: contiguous token slices per shard, the
@@ -662,17 +652,16 @@ class WaveExecutor:
 
     def __init__(self, cfg, *, wave_tokens: int | None = None,
                  plan: JobPlan | None = None, merge_route: str | None = None,
-                 accumulator: str = "defer", mesh=None,
-                 axis_name: str = "data", overlap: bool = True):
+                 mesh=None, axis_name: str = "data", overlap: bool = True):
         if wave_tokens is not None and wave_tokens < 1:
             raise ValueError("wave_tokens must be >= 1")
         if cfg.n_buckets:
             raise ValueError("wave execution does not support n_buckets "
                              "(bucketed series need the bucket-carrying "
                              "single job -- run_job / run_plan)")
-        if accumulator not in ("defer", "tiered", "pairwise"):
-            raise ValueError(f"unknown accumulator {accumulator!r} "
-                             "(options: 'defer', 'tiered', 'pairwise')")
+        if merge_route not in (None, "kway", "device"):
+            raise ValueError(f"unknown merge route {merge_route!r} "
+                             "(options: 'kway', 'device')")
         self.cfg = cfg
         self.wave_tokens = wave_tokens
         self.plan = plan or plan_for(cfg)
@@ -680,12 +669,10 @@ class WaveExecutor:
         # idle by then; the CPU backend's "device" would only be slower host
         # code
         self.merge_route = merge_route or (
-            "device" if accumulator == "defer"
-            and jax.default_backend() != "cpu" else "kway")
-        self.accumulator = accumulator
+            "device" if jax.default_backend() != "cpu" else "kway")
         self.mesh = mesh
         self.axis_name = axis_name
-        # overlap: run the per-wave fold (collect + accumulator merge /
+        # overlap: run the per-wave fold (collect + segment stack /
         # generational ingest) on a background thread so it overlaps the next
         # wave's device work; False serializes fold and dispatch on the main
         # thread (debugging / environments where threads are unwelcome)
@@ -720,8 +707,7 @@ class WaveExecutor:
             head = SPLIT_HEAD_LANES * packing.terms_per_lane(cfg.vocab_size)
             self._head_ex = WaveExecutor(
                 dataclasses.replace(cfg, sigma=head), wave_tokens=wave_tokens,
-                merge_route=self.merge_route, accumulator=accumulator,
-                overlap=overlap)
+                merge_route=self.merge_route, overlap=overlap)
 
     # --- wave iteration ------------------------------------------------------ #
 
@@ -1321,7 +1307,7 @@ class WaveExecutor:
         The wave-level parallel fold: the main thread stays a pure *feeder*
         -- it slices host token slabs and dispatches one fused program per
         wave (single-device or sharded) -- while a background fold thread
-        materializes each wave and runs ``consume`` (the accumulator merge
+        materializes each wave and runs ``consume`` (the segment push
         of :meth:`run`, the generational ingest of :meth:`run_streaming`).
         Host-side fold work therefore overlaps the next waves' device work
         instead of serializing with it; a bounded queue
@@ -1394,7 +1380,7 @@ class WaveExecutor:
         """Execute the job over waves -> ``NGramStats`` (canonical order),
         bit-identical to the monolithic single-job run.  ``fold_rows`` in the
         counters is the total segment rows fed through ``merge_segments`` --
-        the accumulator's measured merge work.  A single-device job whose
+        the fold's measured merge work.  A single-device job whose
         records are wider than ``SPLIT_HEAD_LANES`` lanes runs as a head/tail
         split (:meth:`_run_split`)."""
         from repro.core.stats import NGramStats
@@ -1404,8 +1390,7 @@ class WaveExecutor:
             tokens = np.asarray(tokens, np.int32)
             if root:
                 root.set(n_tokens=int(tokens.shape[0]),
-                         method=self.cfg.method,
-                         accumulator=self.accumulator)
+                         method=self.cfg.method)
             # full canonical counter set (obs.metrics.COUNTER_DOC): identical
             # keys to the monolithic run_plan, plus the wave-only ones
             counters = dict.fromkeys(
@@ -1420,7 +1405,7 @@ class WaveExecutor:
                 acc, loading = self._accumulator()
                 self._fold_waves(tokens, acc, counters)
                 with obs_trace.span("wave.finalize") as sp:
-                    # tau filters inside the deferred fold (on the "device"
+                    # tau filters inside the fold (on the "device"
                     # route before rows leave the chip) and again,
                     # idempotently, before the term unpack, so only the
                     # survivor set pays the unpack
@@ -1436,13 +1421,8 @@ class WaveExecutor:
         """A fresh accumulator for one pass's wave segments, and, on the
         ``"device"`` route, the load of its key width's block programs."""
         from repro.index.merge import (DeferredSegmentAccumulator,
-                                       PairwiseSegmentAccumulator,
-                                       TieredSegmentAccumulator,
                                        load_block_programs)
-        acc_cls = {"defer": DeferredSegmentAccumulator,
-                   "tiered": TieredSegmentAccumulator,
-                   "pairwise": PairwiseSegmentAccumulator}[self.accumulator]
-        acc = acc_cls(route=self.merge_route, use_kernels=self.cfg.use_kernels)
+        acc = DeferredSegmentAccumulator(route=self.merge_route)
         loading = None
         if self.merge_route == "device":
             loading = load_block_programs(
@@ -1469,8 +1449,7 @@ class WaveExecutor:
         lone pushed segment comes back unfiltered)."""
         if loading is not None:
             loading.result()
-        merged = (acc.result(min_count=self.cfg.tau)
-                  if self.accumulator == "defer" else acc.result())
+        merged = acc.result(min_count=self.cfg.tau)
         counters["fold_rows"] += acc.fold_rows
         counters["finalize_blocks"] += acc.finalize_blocks
         return merged
@@ -1635,7 +1614,7 @@ class WaveExecutor:
             gen = GenerationalIndex(sigma=self.cfg.sigma,
                                     vocab_size=self.cfg.vocab_size,
                                     compress=compress, block_size=block_size,
-                                    use_kernels=self.cfg.use_kernels, **gen_kw)
+                                    **gen_kw)
         reports = []
 
         def ingest(part: WavePartial):

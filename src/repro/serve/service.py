@@ -79,8 +79,7 @@ class StreamingNGramService:
 
     def __init__(self, cfg, *, compress: bool = False, block_size: int = 4,
                  use_kernels: bool = False, cache_capacity: int = 65536,
-                 size_ratio: int = 4, route: str = "kway",
-                 wave_tokens: int | None = None, mesh=None,
+                 size_ratio: int = 4, wave_tokens: int | None = None, mesh=None,
                  axis_name: str = "data", overlap: bool = True):
         from repro.index import GenerationalIndex
         self.cfg = cfg
@@ -91,8 +90,7 @@ class StreamingNGramService:
         self.overlap = overlap
         self.gen = GenerationalIndex(
             sigma=cfg.sigma, vocab_size=cfg.vocab_size, compress=compress,
-            block_size=block_size, size_ratio=size_ratio, route=route,
-            use_kernels=use_kernels)
+            block_size=block_size, size_ratio=size_ratio)
         self.cache = LRUQueryCache(cache_capacity)
         self._wave_ex = None
 

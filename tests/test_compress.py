@@ -204,6 +204,39 @@ def test_huge_counts_need_full_width():
     assert_index_parity({(5,): int(big), (6,): 7}, idx, cidx)
 
 
+def test_compressed_lookup_decodes_one_block_per_query(monkeypatch):
+    """A compressed lookup batch decodes exactly one block of
+    ``block_size`` rows per query -- never the whole table -- and answers
+    like the flat index.  Every front-coded decode (the lookup's rank and
+    the table-wide ``decode_segment`` alike) runs through
+    ``block_expand_ref``, which is counted here with jit off."""
+    import jax
+    from repro.kernels import ref as kref
+
+    cfg = NGramConfig(sigma=3, tau=1, vocab_size=60)
+    stats = run_job(make_corpus(3000, 60, "zipf", 17), cfg)
+    idx = build_index(stats, vocab_size=60)
+    cidx = compress_index(idx, block_size=8)
+    pick = np.arange(64) * (len(stats) // 64)  # 64 hits across the table
+    g, ln = np.asarray(stats.grams)[pick], np.asarray(stats.lengths)[pick]
+    q = g.shape[0]
+    assert q == 64 and q * cidx.block_size < cidx.n_rows
+
+    decoded = []
+    expand = kref.block_expand_ref
+
+    def counted(lcps, payload, block_base, sec, blk, *, block_size, **kw):
+        decoded.append(int(blk.shape[0]) * block_size)
+        return expand(lcps, payload, block_base, sec, blk,
+                      block_size=block_size, **kw)
+
+    monkeypatch.setattr(kref, "block_expand_ref", counted)
+    with jax.disable_jit():
+        got = np.asarray(lookup(cidx, g, ln))
+    assert decoded == [q * cidx.block_size]
+    np.testing.assert_array_equal(got, np.asarray(stats.counts)[pick])
+
+
 def test_elias_fano_adversarial_sequences():
     rng = np.random.default_rng(0)
     seqs = [

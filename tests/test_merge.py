@@ -49,10 +49,9 @@ def job_pair(vocab, dist, sigma, tau, seed, n=2500):
 
 def check_merge_parity(sa, sb, vocab, *, block=4):
     union = stats_union(sa, sb)
-    # flat: both routes, ref and kernel merge-path
+    # flat: both routes
     want = build_index(union, vocab_size=vocab)
-    for kw in (dict(route="merge"), dict(route="merge", use_kernels=True),
-               dict(route="sort"), dict(route="device")):
+    for kw in (dict(route="kway"), dict(route="device")):
         got = merge_indexes([build_index(sa, vocab_size=vocab),
                              build_index(sb, vocab_size=vocab)], **kw)
         assert_trees_equal(got, want)
@@ -89,7 +88,7 @@ def test_kway_merge_and_edge_segments():
     ixs = [build_index(s, vocab_size=vocab) for s in stats]
     ixs.append(build_index(empty, vocab_size=vocab))
     want = build_index(stats_union(*stats), vocab_size=vocab)
-    for kw in (dict(route="merge"), dict(route="sort"), dict(route="device")):
+    for kw in (dict(route="kway"), dict(route="device")):
         assert_trees_equal(merge_indexes(ixs, **kw), want)
 
 
@@ -120,7 +119,7 @@ def test_merged_count_overflow_guard_trips():
                             np.array([1], np.int32),
                             np.array([big], np.int64))
     segs = [segment_from_stats(mk(), vocab_size=9) for _ in range(2)]
-    for kw in (dict(route="merge"), dict(route="sort"), dict(route="device")):
+    for kw in (dict(route="kway"), dict(route="device")):
         with pytest.raises(ValueError, match="overflow"):
             merge_segments(segs, **kw)
     # just-below-the-edge sums must still merge exactly
@@ -130,12 +129,11 @@ def test_merged_count_overflow_guard_trips():
     assert np.asarray(seg.counts)[0] == np.uint32(big + 10)
 
 
-@pytest.mark.parametrize("route", ["merge", "device"])
+@pytest.mark.parametrize("route", ["device"])
 def test_device_fold_host_fallback_parity(monkeypatch, route):
     """Runs longer than the limbed device budget must replay on the host
     with identical output: force the fallback by shrinking the threshold and
-    compare whole segments against the device fold (the merge-path tree's,
-    and every block's of the blocked fold)."""
+    compare whole segments against every block's device fold."""
     from repro.index import merge as merge_mod
 
     sa, sb = job_pair(40, "zipf", 4, 2, seed=3, n=1500)
@@ -298,7 +296,7 @@ def test_compressed_native_merge_edge_segments():
     for parts in ([empty, big], [single, big], [empty, single, big]):
         want = build_compressed_index(stats_union(*parts), vocab_size=vocab,
                                       block_size=block)
-        for route in ("kway", "merge"):
+        for route in ("kway", "device"):
             got = merge_indexes(
                 [build_compressed_index(s, vocab_size=vocab, block_size=block)
                  for s in parts], route=route)
@@ -312,7 +310,7 @@ def test_compressed_native_merge_overflow_guard():
                             np.array([1], np.int32),
                             np.array([big], np.int64))
     cixs = [build_compressed_index(mk(), vocab_size=9) for _ in range(2)]
-    for route in ("kway", "merge"):
+    for route in ("kway", "device"):
         with pytest.raises(ValueError, match="overflow"):
             merge_indexes(cixs, route=route)
 

@@ -126,40 +126,37 @@ def test_wave_empty_corpus():
     assert np.asarray(lookup(gen, g, np.asarray([2], np.int32)))[0] == 0
 
 
-# ------------------------------------------------------------ wave accumulator
+# ------------------------------------------------------------ wave fold
 def test_accumulator_parity_and_fold_work_win():
-    """Both fold policies are bit-identical to the monolithic job; the tiered
-    rung stack does measurably less merge work at >= 16 waves."""
+    """The deferred fold is bit-identical to the monolithic job at 16 waves,
+    and its merge work is exactly one pass over the wave partials:
+    ``fold_rows`` is the sum of the rows the 16 ``wave.fold`` spans pushed."""
+    from repro.obs import trace as obs_trace
     toks = make_corpus(2500, 50, "zipf", seed=31)
     cfg = NGramConfig(sigma=4, tau=2, vocab_size=50)
     wave = -(-len(toks) // 16)
     mono = run_job(toks, cfg)
-    tiered = WaveExecutor(cfg, wave_tokens=wave).run(toks)
-    pairwise = WaveExecutor(cfg, wave_tokens=wave,
-                            accumulator="pairwise").run(toks)
-    assert_stats_equal(tiered, mono)
-    assert_stats_equal(pairwise, mono)
-    assert tiered.counters["fold_rows"] < pairwise.counters["fold_rows"]
-
-
-def test_accumulator_rejects_unknown_policy():
-    cfg = NGramConfig(sigma=3, tau=1, vocab_size=9)
-    with pytest.raises(ValueError, match="accumulator"):
-        WaveExecutor(cfg, wave_tokens=8, accumulator="nope")
+    tracer = obs_trace.enable_tracing()
+    try:
+        got = WaveExecutor(cfg, wave_tokens=wave).run(toks)
+    finally:
+        obs_trace.disable_tracing()
+    assert_stats_equal(got, mono)
+    folds = [e for e in tracer.export()["traceEvents"]
+             if e["name"] == "wave.fold"]
+    assert len(folds) == got.counters["waves"] == 16
+    assert got.counters["fold_rows"] == \
+        sum(e["args"]["rows"] for e in folds) > 0
 
 
 def test_merge_route_device_matches_monolithic():
     """The blocked device fold route (``merge_route="device"``, the
-    accelerator's default) is bit-identical to the monolithic job across
-    both fold policies."""
+    accelerator's default) is bit-identical to the monolithic job."""
     toks = make_corpus(2000, 40, "zipf", seed=41)
     cfg = NGramConfig(sigma=4, tau=2, vocab_size=40)
     wave = -(-len(toks) // 8)
-    mono = run_job(toks, cfg)
-    for acc in ("defer", "tiered"):
-        got = WaveExecutor(cfg, wave_tokens=wave, accumulator=acc,
-                           merge_route="device").run(toks)
-        assert_stats_equal(got, mono)
+    got = WaveExecutor(cfg, wave_tokens=wave, merge_route="device").run(toks)
+    assert_stats_equal(got, run_job(toks, cfg))
 
 
 @pytest.mark.parametrize("tau", [1, 2, 10])
@@ -202,19 +199,18 @@ def test_device_finalize_of_an_empty_job():
 
 def test_default_merge_route_follows_backend(monkeypatch):
     """``merge_route=None`` picks the host k-way fold on the CPU backend and
-    the blocked device fold for the deferred merge on an accelerator; the
-    tiered and pairwise accumulators keep the host fold; an explicit route
-    wins."""
+    the blocked device fold on an accelerator; an explicit route wins, and
+    any other route is refused before a wave runs."""
     import jax
     cfg = NGramConfig(sigma=3, tau=2, vocab_size=9)
     assert WaveExecutor(cfg, wave_tokens=8).merge_route == "kway"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert WaveExecutor(cfg, wave_tokens=8).merge_route == "device"
-    for acc in ("tiered", "pairwise"):
-        assert WaveExecutor(cfg, wave_tokens=8,
-                            accumulator=acc).merge_route == "kway"
     assert WaveExecutor(cfg, wave_tokens=8,
                         merge_route="kway").merge_route == "kway"
+    for route in ("sort", "merge"):
+        with pytest.raises(ValueError, match="merge route"):
+            WaveExecutor(cfg, wave_tokens=8, merge_route=route)
 
 
 def test_device_route_readies_one_block_program_on_first_run(monkeypatch,
@@ -248,27 +244,26 @@ def test_device_route_readies_one_block_program_on_first_run(monkeypatch,
 
 
 def test_segment_accumulators_match_merge_oracle():
-    """Unit level: pushing per-wave segments through either accumulator gives
-    the segment a one-shot merge of everything would."""
-    from repro.index import (PairwiseSegmentAccumulator,
-                             TieredSegmentAccumulator, merge_segments,
-                             segment_from_stats, segment_to_stats)
+    """Unit level: pushing per-wave segments through the deferred fold, on
+    either route, gives the segment a one-shot merge of everything would."""
+    from repro.index import merge_segments, segment_from_stats, segment_to_stats
+    from repro.index.merge import DeferredSegmentAccumulator
 
     cfg = NGramConfig(sigma=3, tau=1, vocab_size=15)
     segs = []
     for seed in range(6):
         stats = run_job(make_corpus(150, 15, "zipf", seed=seed), cfg)
         segs.append(segment_from_stats(stats, vocab_size=15))
-    want = segment_to_stats(merge_segments(segs, route="sort"))
-    for acc in (TieredSegmentAccumulator(route="sort", size_ratio=2),
-                PairwiseSegmentAccumulator(route="sort")):
+    want = segment_to_stats(merge_segments(segs, route="kway"))
+    for route in ("kway", "device"):
+        acc = DeferredSegmentAccumulator(route=route)
         for s in segs:
             acc.push(s)
         got = segment_to_stats(acc.result())
         assert_stats_equal(got, want)
-        assert acc.fold_rows > 0
+        assert acc.fold_rows == sum(s.n_rows for s in segs)
     with pytest.raises(ValueError):
-        TieredSegmentAccumulator().result()
+        DeferredSegmentAccumulator().result()
 
 
 # -------------------------------------------------------- reserved-id-0 guard
